@@ -288,8 +288,6 @@ def check_wire_symmetry(src: SourceFile) -> list[Finding]:
 
 def _jit_static_argnames(dec: ast.AST) -> tuple[bool, set[str]]:
     """(is_jit_decorator, static_argnames) for one decorator node."""
-    if isinstance(dec, ast.IfExp):      # `... if HAVE_JAX else (f->f)`
-        return _jit_static_argnames(dec.body)
     target = dec
     statics: set[str] = set()
     if isinstance(dec, ast.Call):

@@ -12,7 +12,7 @@ Extra, TPU-first: ``--batch`` objects are encoded per kernel launch
 (device-side stripe batching — the per-object loop of the reference becomes
 one big lane dimension), and ``--device-resident`` keeps buffers in HBM
 between iterations the way the OSD stripe accumulator does, so the number
-measures the kernel, not the PCIe/tunnel.
+measures the kernel, not the host link.
 """
 
 from __future__ import annotations

@@ -74,14 +74,6 @@ import numpy as np
 from ceph_tpu.ops import bitmatrix, gf256
 
 
-def _tpu_compiler_params(pltpu, **kw):
-    """pltpu.CompilerParams across the jax version skew (older
-    runtimes spell it TPUCompilerParams)."""
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
 # -- static trace ------------------------------------------------------
 
 @dataclass
@@ -1136,8 +1128,7 @@ def build_transform_kernel(codec, erased: frozenset[int],
                 pltpu.VMEM((Rp, tile), jnp.int32),      # u
                 pltpu.VMEM((ssc * E8, tile), jnp.int32),  # rec
             ],
-            compiler_params=_tpu_compiler_params(
-                pltpu,
+            compiler_params=pltpu.CompilerParams(
                 # the default scoped-vmem budget (16 MiB) is below
                 # this kernel's resident set (multi-level unroll +
                 # ~8 MiB of routing constants); raise toward the

@@ -15,7 +15,10 @@ one kernel contract —
 - ``pallas``: fused unpack->MXU->pack kernel (ops/gf_pallas.py; TPU only,
   several times faster than the plain-XLA path).
 
-``auto`` prefers pallas, then jax, then native, then numpy.
+``auto`` prefers pallas, then jax, then native, then numpy. On a TPU
+the Pallas backend is not optional: a ``gf_pallas`` that fails to
+import there raises from the first backend lookup, with the cause,
+instead of leaving ``auto`` on the plain-XLA path without a word.
 All paths are bit-identical (enforced by tests/test_gf_jax.py and
 tests/test_native.py — the corpus gate of
 src/test/erasure-code/ceph_erasure_code_non_regression.cc applied across
@@ -55,22 +58,21 @@ def _load_lazy() -> None:
     global _lazy_done
     if _lazy_done:
         return
-    _lazy_done = True
-    try:
-        from ceph_tpu.ops import gf_jax  # noqa: F401  (self-registers)
-    except Exception:  # pragma: no cover - jax always present in this image
-        pass
-    try:
-        import jax
-        if jax.default_backend() == "tpu":
+    import jax
+    from ceph_tpu.ops import gf_jax  # noqa: F401  (self-registers)
+    if jax.default_backend() == "tpu":
+        try:
             from ceph_tpu.ops import gf_pallas
-            register_backend("pallas", gf_pallas.matvec)
-    except Exception:
-        pass
-    try:
-        from ceph_tpu.ops import native  # noqa: F401  (self-registers)
-    except Exception:
-        pass
+        except Exception as exc:
+            raise RuntimeError(
+                "on a TPU the pallas backend must load; refusing to "
+                f"serve from the plain-XLA path instead: {exc!r}"
+            ) from exc
+        register_backend("pallas", gf_pallas.matvec)
+    # registers "native" when the library built; native_loader logs
+    # the cause once when it did not
+    from ceph_tpu.ops import native  # noqa: F401
+    _lazy_done = True
 
 
 def resolve(name: str = "auto"):

@@ -12,55 +12,47 @@ pipelining argument (PAPERS.md) only holds where the host, not the
 device, bottlenecks — the roofline check is how a signature proves
 which side it is on.
 
-Everything degrades to ``None``/``{}``: cost analysis is an XLA
-introspection (``compiled.cost_analysis()``) whose availability and
-key set vary by backend and jax version, and a bench line must never
-die for a missing estimate.
-
-Peaks default per backend (order-of-magnitude numbers for the
-roofline RATIO, not marketing claims) and are overridable via
-``CEPH_TPU_PEAK_HBM_GBPS`` / ``CEPH_TPU_PEAK_TFLOPS`` when the real
-chip generation is known.
+The cost analysis itself degrades to ``None``/``{}`` (it is an XLA
+introspection whose key set varies by backend, and a bench line must
+never die for a missing estimate). The peaks do not degrade: they come
+from ONE table keyed by the device's ``device_kind``, a device that is
+not in it is an error, and a CPU run has no roofline share of a device
+to give, so on the CPU platform there are no peaks at all.
 """
 
 from __future__ import annotations
 
-import os
-
-#: backend -> (HBM/memory GB/s, peak TFLOP/s): deliberately coarse
-#: defaults — the roofline is a sanity ratio, and the env overrides
-#: pin it to a real part when precision matters
-_PEAKS = {
-    "tpu": (1200.0, 275.0),
-    "gpu": (900.0, 60.0),
-    "cpu": (25.0, 0.5),
+#: device_kind -> (HBM GB/s, peak bf16 TFLOP/s) of one chip.
+#: Source: Google Cloud documentation, "TPU v5e" (system architecture
+#: table): 16 GB HBM2e at 819 GB/s, 197 TFLOP/s bf16 per chip.
+PEAKS = {
+    "TPU v5 lite": (819.0, 197.0),
 }
 
 
-def peaks() -> tuple[float, float]:
-    """(peak_GBps, peak_TFLOPs) for the active backend, env-
-    overridable."""
+def peaks() -> tuple[float, float] | None:
+    """(peak_GBps, peak_TFLOPs) of the device JAX runs on, or None on
+    the CPU platform. An accelerator whose ``device_kind`` is not in
+    :data:`PEAKS` raises: a guessed peak would make every roofline
+    share wrong without a word."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
     try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
-    bw, tf = _PEAKS.get(backend, _PEAKS["cpu"])
-    bw = float(os.environ.get("CEPH_TPU_PEAK_HBM_GBPS", bw))
-    tf = float(os.environ.get("CEPH_TPU_PEAK_TFLOPS", tf))
-    return bw, tf
+        return PEAKS[dev.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no peaks recorded for device kind {dev.device_kind!r}; "
+            "add it to ceph_tpu.ops.cost_model.PEAKS with its source"
+        ) from None
 
 
-def _extract(ca) -> dict | None:
-    """Normalize cost_analysis() output across jax versions: a dict,
-    or a one-element list of dicts, keyed 'flops' / 'bytes accessed'
-    (utilization keys ignored)."""
+def _extract(ca: dict | None) -> dict | None:
+    """The 'flops' / 'bytes accessed' entries of a compiled
+    program's cost_analysis() dict (utilization keys ignored)."""
     if ca is None:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-        if ca is None:
-            return None
     flops = ca.get("flops")
     nbytes = ca.get("bytes accessed")
     if flops is None and nbytes is None:
@@ -104,8 +96,11 @@ def roofline_gbps(flops: float | None, bytes_accessed: float | None,
                   traffic_bytes: float) -> float | None:
     """Best-case GB/s for a program serving ``traffic_bytes`` of
     logical traffic per execution: execution time is bounded below by
-    max(bytes/peak_bw, flops/peak_flops)."""
-    bw_gbps, tflops = peaks()
+    max(bytes/peak_bw, flops/peak_flops). None on the CPU platform."""
+    device_peaks = peaks()
+    if device_peaks is None:
+        return None
+    bw_gbps, tflops = device_peaks
     t = 0.0
     if bytes_accessed:
         t = max(t, bytes_accessed / (bw_gbps * 1e9))

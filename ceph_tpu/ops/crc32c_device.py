@@ -225,11 +225,12 @@ def _B_matrix_planar(c_bytes: int) -> np.ndarray:
 
 
 def _pallas_available() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    """The Pallas row kernel serves on a TPU. No fallback is decided
+    here: a failure to ask is raised, because the answer is frozen
+    into the jitted program below and the plain-XLA branch (8x bit
+    expansion in HBM) would then ride every write unseen."""
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 @functools.lru_cache(maxsize=1)

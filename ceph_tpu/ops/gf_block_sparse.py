@@ -239,9 +239,6 @@ def _build_runner(plan: BlockPlan, tile: int, sig: str = ""):
     whole = lambda shape: pl.BlockSpec(
         shape, lambda i: (0, 0), memory_space=pltpu.VMEM)
 
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-
     @functools.partial(jax.jit, static_argnames=("n",))
     def run_padded(data, *mat_args, n):
         grid = (n // tile,)
@@ -254,7 +251,7 @@ def _build_runner(plan: BlockPlan, tile: int, sig: str = ""):
             out_specs=pl.BlockSpec((mp, tile), lambda i: (0, i),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((mp, n), jnp.uint8),
-            compiler_params=params_cls(
+            compiler_params=pltpu.CompilerParams(
                 # gathered bit tiles + per-group compacted matrices
                 # exceed the 16 MiB default scoped budget at larger
                 # lane tiles; same headroom raise as the clay kernels

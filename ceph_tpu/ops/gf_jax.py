@@ -28,16 +28,10 @@ recompiles.
 
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
-try:
-    import jax
-    import jax.numpy as jnp
-    HAVE_JAX = True
-except Exception:  # pragma: no cover
-    HAVE_JAX = False
+import jax
+import jax.numpy as jnp
 
 from ceph_tpu.ops import backend as backend_mod
 from ceph_tpu.ops import bitmatrix
@@ -45,7 +39,7 @@ from ceph_tpu.ops import bitmatrix
 _SHIFTS = np.arange(8, dtype=np.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=()) if HAVE_JAX else (lambda f: f)
+@jax.jit
 def _bitsliced_matvec_device(bmat: "jax.Array", data: "jax.Array") -> "jax.Array":
     """bmat [R, 8k] int8 (0/1), data [k, N] uint8 -> [R//8, N] uint8."""
     k, n = data.shape
@@ -107,10 +101,8 @@ import warnings as _warnings  # noqa: E402
 _warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
-_bitsliced_matvec_device_donated = (
-    jax.jit(_bitsliced_matvec_device.__wrapped__, donate_argnums=(1,))
-    if HAVE_JAX and hasattr(_bitsliced_matvec_device, "__wrapped__")
-    else _bitsliced_matvec_device)
+_bitsliced_matvec_device_donated = jax.jit(
+    _bitsliced_matvec_device.__wrapped__, donate_argnums=(1,))
 
 
 def matvec_device(mat: np.ndarray, data) -> "jax.Array":
@@ -169,5 +161,4 @@ def matvec(mat: np.ndarray, data: np.ndarray) -> np.ndarray:
     return out[:, :n] if nb != n else out
 
 
-if HAVE_JAX:
-    backend_mod.register_backend("jax", matvec)
+backend_mod.register_backend("jax", matvec)

@@ -10,25 +10,10 @@ def tracing_active() -> bool:
     leaked tracer); eagerly they reuse a device-resident copy (a numpy
     constant there would re-upload the matrix every call).
 
-    Probes the known jax APIs in order and falls back to True
-    (conservative: correct everywhere, merely slower eagerly).
     tests/test_gf_jax.py pins the BEHAVIOR — eager vs traced must
-    differ — so a jax rename that lands us on the fallback fails CI
-    instead of silently degrading the hot path.
+    differ — so a jax rename of the probe fails CI instead of silently
+    degrading the hot path.
     """
     import jax
 
-    core = jax.core
-    fn = getattr(core, "trace_state_clean", None)
-    if fn is not None:
-        try:
-            return not fn()
-        except Exception:
-            pass
-    ctx = getattr(core, "trace_ctx", None)
-    if ctx is not None and hasattr(ctx, "is_top_level"):
-        try:
-            return not ctx.is_top_level()
-        except Exception:
-            pass
-    return True
+    return not jax.core.trace_ctx.is_top_level()

@@ -1,9 +1,10 @@
 """ctypes loader for the native C++ kernel library (lazy build via make).
 
 Python<->native binding uses ctypes (no pybind11 in this image). The library
-is built on first use into ops/native/_build/ and cached; if the toolchain
-is unavailable the loader degrades gracefully and callers fall back to
-numpy paths (ops/backend.py resolution order).
+is built on first use into ops/native/_build/ (ignored by git, so a fresh
+checkout builds it from the committed .cc files) and cached; if the
+toolchain is unavailable the loader logs the cause once and callers fall
+back to numpy paths (ops/backend.py resolution order).
 """
 
 from __future__ import annotations
@@ -15,6 +16,10 @@ import threading
 from pathlib import Path
 
 import numpy as np
+
+from ceph_tpu.utils.dout import Dout
+
+log = Dout("native")
 
 _DIR = Path(__file__).parent / "native"
 _SO = _DIR / "_build" / "libceph_tpu_native.so"
@@ -44,8 +49,14 @@ def get_lib():
             _bind(lib)
             lib.gf256_init()
             _lib = lib
-        except Exception:
+        except Exception as exc:
             _failed = True
+            # a failed make carries the compiler's words in stderr
+            detail = getattr(exc, "stderr", None) or b""
+            log(0, f"native library unavailable ({exc!r}"
+                + (f": {detail.decode(errors='replace').strip()}"
+                   if detail else "")
+                + "); host kernels fall back to the numpy twin")
         return _lib
 
 

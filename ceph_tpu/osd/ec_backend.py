@@ -96,8 +96,9 @@ class ECBackend(PGBackend):
         avail = backend_mod.available_backends()
         want = profile.get("backend")
         if want == "auto_device":
-            # best available device path (pallas on a TPU, plain-XLA
-            # bit-sliced elsewhere)
+            # best available device path: pallas on a TPU (where its
+            # absence is an error raised by available_backends, never
+            # a quiet downgrade), plain-XLA bit-sliced elsewhere
             want = profile["backend"] = \
                 "pallas" if "pallas" in avail else "jax"
         host_backend = "native" if "native" in avail else "numpy"

@@ -1677,7 +1677,7 @@ class OSD:
             racing = False
             with self._op_cache_lock:
                 cached = self._op_cache.get(cache_key)
-                if cached is None and msg.op == M.OSD_OP_APPEND:
+                if cached is None:
                     t0_adm = self._op_inflight.get(cache_key)
                     racing = (t0_adm is not None
                               and time.monotonic() - t0_adm
@@ -1702,10 +1702,17 @@ class OSD:
                 conn.send_message(cached)
                 return
             if racing:
-                # a resend raced the ORIGINAL append's still-running
-                # execution (the double-apply class): drop it — the
-                # original's reply answers this tid, and a later
-                # resend hits the dup cache
+                # a resend raced the ORIGINAL op's still-running
+                # execution: drop it — the original's reply answers
+                # this tid, and a later resend hits the dup cache.
+                # For append this is the double-apply class; for an
+                # idempotent write_full the re-execution commits the
+                # same bytes under a NEW version after the client
+                # already holds the first reply, and an interval
+                # change cutting that orphan fan-out short leaves the
+                # object split between two versions, neither on k
+                # shards (unreadable; recovery loops) — so every
+                # mutating op is admitted once
                 track.mark_event("dup_op_in_flight_dropped")
                 track.finish()
                 span.event("dup_op_in_flight_dropped")

@@ -12,12 +12,12 @@ Two pieces every mesh step is built from:
   (whole-array math; XLA's SPMD partitioner inserts the collectives)
   and a per-shard ``shard_fn`` (explicit ``ppermute``/``psum``/
   ``all_gather``). The seam prefers ``jax.jit`` with ``in_shardings``/
-  ``out_shardings`` over the raw shard_map wrap when the runtime
-  supports it — the pjit route gives the compiler the whole dataflow
-  (it can fuse the placement shift into the parity store, overlap the
-  csum all-reduce, and skip the per-shard reshape choreography) —
-  and falls back through the :func:`_shard_map` version-skew shim
-  otherwise, or when ``mesh_compile_mode`` forces it.
+  ``out_shardings`` over the raw ``jax.shard_map`` wrap — the pjit
+  route gives the compiler the whole dataflow (it can fuse the
+  placement shift into the parity store, overlap the csum
+  all-reduce, and skip the per-shard reshape choreography) — and
+  takes the shard_map spelling for a step that has no global one, or
+  when ``mesh_compile_mode`` forces it.
 
 Both spellings take the coding matrix as an ARGUMENT (spec'd in the
 layout table) rather than a closure capture, so a fresh matrix
@@ -28,7 +28,6 @@ walks shard_map/in_shardings-wrapped callees exactly like plain jit).
 
 from __future__ import annotations
 
-import inspect
 import os
 from dataclasses import dataclass
 
@@ -98,37 +97,6 @@ def compile_mode() -> str:
         return "auto"
 
 
-_supports: bool | None = None
-
-
-def supports_shardings() -> bool:
-    """Does this runtime's ``jax.jit`` take in_shardings/out_shardings?
-    (The pjit merge landed in 0.4.x; older runtimes fall back to the
-    shard_map shim the same way `_shard_map` handles check_vma skew.)"""
-    global _supports
-    if _supports is None:
-        try:
-            params = inspect.signature(jax.jit).parameters
-            _supports = "in_shardings" in params and \
-                "out_shardings" in params
-        except (TypeError, ValueError):
-            _supports = False
-    return _supports
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map across the jax version skew: the public
-    ``jax.shard_map`` (with ``check_vma``) landed after 0.4.3x; older
-    runtimes carry it as ``jax.experimental.shard_map`` with the
-    replication check spelled ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def _named(mesh: Mesh, specs):
     # PartitionSpec subclasses tuple: test it FIRST or a single spec
     # would be iterated as a tuple of axis names
@@ -145,19 +113,18 @@ def compile_step(mesh: Mesh, *, global_fn=None, shard_fn=None,
     ``path`` is ``"pjit"`` or ``"shard_map"``.
 
     ``global_fn`` is the whole-array spelling (compiled with
-    ``jax.jit`` + in/out shardings when the runtime supports it);
-    ``shard_fn`` is the per-shard spelling with explicit collectives
-    (wrapped through :func:`_shard_map`). Both receive the same
+    ``jax.jit`` + in/out shardings); ``shard_fn`` is the per-shard
+    spelling with explicit collectives (wrapped in
+    ``jax.shard_map``). Both receive the same
     argument list; out_specs is a spec (or tuple of specs) matching
     the output pytree. ``mesh_compile_mode`` / the
     ``CEPH_TPU_MESH_COMPILE_MODE`` env pin one route for A/B runs."""
     mode = compile_mode()
-    want_pjit = mode in ("auto", "pjit") and global_fn is not None \
-        and supports_shardings()
+    want_pjit = mode in ("auto", "pjit") and global_fn is not None
     if mode == "pjit" and not want_pjit:
         raise RuntimeError(
-            "mesh_compile_mode=pjit but this runtime's jax.jit has no "
-            "in_shardings (or the step has no global spelling)")
+            "mesh_compile_mode=pjit but the step has no global "
+            "spelling")
     if want_pjit:
         compiled = jax.jit(global_fn,
                            in_shardings=_named(mesh, in_specs),
@@ -167,9 +134,9 @@ def compile_step(mesh: Mesh, *, global_fn=None, shard_fn=None,
         if shard_fn is None:
             raise RuntimeError("step has no shard_map spelling and "
                                f"mode={mode} rules out pjit")
-        compiled = jax.jit(_shard_map(shard_fn, mesh,
-                                      in_specs=in_specs,
-                                      out_specs=out_specs))
+        compiled = jax.jit(jax.shard_map(
+            shard_fn, mesh=mesh, in_specs=in_specs,
+            out_specs=out_specs, check_vma=False))
         path = "shard_map"
     try:
         from ceph_tpu.utils.device_telemetry import telemetry

@@ -20,9 +20,9 @@ Since ISSUE 12 every step is built on the layout/compile seam
 (parallel/mesh_compile.py): the per-stage PartitionSpecs live in ONE
 ``SpecLayout`` table, and each step carries two spellings — a
 global-view body (``jax.jit`` + ``in_shardings``/``out_shardings``;
-XLA's SPMD partitioner inserts the collectives) preferred when the
-runtime supports it, and the per-shard ``shard_map`` body with
-explicit ``ppermute``/``psum``/``all_gather`` as the fallback. The
+XLA's SPMD partitioner inserts the collectives), the default, and the
+per-shard ``shard_map`` body with explicit ``ppermute``/``psum``/
+``all_gather`` that ``mesh_compile_mode=shard_map`` selects. The
 global bodies are AXIS-PRESERVING on purpose: folding the sharded
 stripe axis into the byte axis (the local spelling's trick) would
 make the partitioner reshard the whole batch — measured ~10x
@@ -39,9 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ceph_tpu.ops import bitmatrix
 from ceph_tpu.parallel import mesh_compile
-from ceph_tpu.parallel.mesh_compile import LAYOUT, _shard_map  # noqa: F401
-# (_shard_map re-exported: pre-ISSUE-12 callers import the skew shim
-# from here)
+from ceph_tpu.parallel.mesh_compile import LAYOUT
 
 
 def _instrumented(step, sig: str):

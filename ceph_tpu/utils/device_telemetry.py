@@ -149,7 +149,7 @@ class DeviceTelemetry:
                              "jit+in_shardings (pjit) seam")
         perf.add_u64_counter("mesh_compile_shard_map",
                              "mesh steps compiled through the "
-                             "shard_map fallback shim")
+                             "explicit-collectives shard_map spelling")
         # pipelined engine (osd/device_engine.py): launch-window
         # accounting — depth proves batches overlap, overlap-pct is
         # the share of a batch's device lifetime hidden behind other

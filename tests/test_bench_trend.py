@@ -1,13 +1,11 @@
 """ISSUE 10 satellite: tools/bench_trend.py — cross-round bench
-comparison with a >10% regression flag, runnable in tier-1 on the
-checked-in BENCH_r*.json files."""
+comparison with a >10% regression flag, runnable in tier-1 on round
+files built under tmp_path in the checked-in format."""
 
 import json
 import os
 
 from ceph_tpu.tools import bench_trend
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _round_file(tmp_path, name, metrics, rc=0):
@@ -22,12 +20,22 @@ def _round_file(tmp_path, name, metrics, rc=0):
     return str(path)
 
 
-def test_runs_on_checked_in_rounds(capsys):
-    """The real repo files: parse every round (incl. the rc=124
-    timeout round with zero metrics), print the table + one JSON
-    line."""
-    files = bench_trend.default_files(REPO_ROOT)
-    assert len(files) >= 2, "checked-in BENCH_r*.json missing"
+def test_runs_on_checked_in_rounds(capsys, tmp_path):
+    """Round files as the driver checks them in, found by
+    default_files: parse every round (incl. an rc=124 timeout round
+    with zero metrics, whose tail is a bare warning), print the table
+    + one JSON line."""
+    _round_file(tmp_path, "BENCH_r01.json",
+                {"ec_encode_rs_k8m3_device_GBps": 100.0})
+    _round_file(tmp_path, "BENCH_r02.json",
+                {"ec_encode_rs_k8m3_device_GBps": 104.0,
+                 "ec_decode_rs_k8m3_device_GBps": 40.0})
+    (tmp_path / "BENCH_r03.json").write_text(json.dumps(
+        {"n": 3, "cmd": "bench", "rc": 124, "parsed": None,
+         "tail": "WARNING: platform is experimental\n"}))
+    files = bench_trend.default_files(str(tmp_path))
+    assert [os.path.basename(f) for f in files] == [
+        "BENCH_r01.json", "BENCH_r02.json", "BENCH_r03.json"]
     assert bench_trend.main(files) == 0
     out = capsys.readouterr().out
     json_line = [ln for ln in out.splitlines()
@@ -42,8 +50,8 @@ def test_runs_on_checked_in_rounds(capsys):
     assert "delta_vs_best_pct" in row
     # a timeout round parses to zero metrics without crashing
     by_round = {r["round"]: r for r in report["rounds"]}
-    assert by_round["BENCH_r05"]["metrics"] == 0
-    assert by_round["BENCH_r05"]["rc"] == 124
+    assert by_round["BENCH_r03"]["metrics"] == 0
+    assert by_round["BENCH_r03"]["rc"] == 124
 
 
 def test_regression_flag_direction_aware(tmp_path):

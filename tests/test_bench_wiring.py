@@ -136,8 +136,9 @@ def test_stage_breakdown_degrades_to_empty(monkeypatch):
 
 def test_cost_fields_roofline_next_to_measured(capsys, monkeypatch):
     """ISSUE 7 satellite: device metric lines carry cost_flops /
-    cost_bytes / roofline_GBps from the compiled cost analysis of
-    the exact step — and the whole line still round-trips json."""
+    cost_bytes from the compiled cost analysis of the exact step —
+    and the whole line still round-trips json. A CPU run has no
+    roofline share of a device to give, so no roofline_GBps here."""
     import time
 
     import jax.numpy as jnp
@@ -157,7 +158,7 @@ def test_cost_fields_roofline_next_to_measured(capsys, monkeypatch):
     if fields:
         assert fields["cost_flops"] > 0
         assert fields["cost_bytes"] > 0
-        assert fields["roofline_GBps"] > 0
+        assert "roofline_GBps" not in fields
         # the signature landed in the device cost table
         from ceph_tpu.utils.device_telemetry import telemetry
         snap = telemetry().snapshot()
@@ -170,7 +171,7 @@ def test_cost_fields_roofline_next_to_measured(capsys, monkeypatch):
     rec = json.loads(out[-1])
     assert rec["metric"] == "cost_smoke"
     if fields:
-        assert rec["roofline_GBps"] == fields["roofline_GBps"]
+        assert rec["cost_bytes"] == fields["cost_bytes"]
     bench._RESULTS.pop("cost_smoke", None)
 
 

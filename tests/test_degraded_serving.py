@@ -377,3 +377,45 @@ def test_sustained_thrash_qos_and_durability(fast_death):
         # registry contract pinned in test_faults)
         kinds = {e["kind"] for e in reg.fired()}
         assert "msgr_drop" in kinds
+
+
+def test_resend_racing_an_inflight_write_is_not_reexecuted(fast_death):
+    """A client resend that races the ORIGINAL write's still-running
+    execution must be dropped, for every mutating op and not only for
+    append. Re-executing an idempotent write_full looks harmless, but
+    it commits the same bytes under a NEW version after the client
+    already holds the first reply; an interval change then cuts that
+    orphan fan-out short and leaves the object split between two
+    versions, neither on k shards — unreadable, and recovery loops
+    forever (seen on the chip: chip_smoke.py's recovery phase, a
+    5-object PG at v8). Here the shard commit acks are delayed past
+    several resend periods; the PG's version must advance by exactly
+    one write."""
+    from ceph_tpu.parallel import messages as M
+    conf = g_conf()
+    old_resend = conf["objecter_resend_interval"]
+    conf.set("objecter_resend_interval", 0.2)
+    try:
+        with MiniCluster(n_osds=3) as cluster:
+            reg = cluster.faults
+            cluster.create_ec_pool("dup", k=2, m=1, pg_num=1)
+            io = cluster.client().open_ioctx("dup")
+            io.op_timeout = 60.0
+            io.write_full("warm", b"w" * 8192)
+            osdmap = cluster.mon.osdmap
+            pool_id = osdmap.pool_by_name["dup"]
+            _, _, primary = osdmap.pg_to_up_acting(pool_id, 0)
+            pg = cluster.osds[primary].pgs[(pool_id, 0)]
+            v0 = pg.log.last_version
+            rule = reg.add("msgr_delay", entity="osd.*",
+                           msg_type=M.MECSubWriteReply.MSG_TYPE,
+                           delay_s=1.2)
+            payload = payload_for("dup0", 0, 16384)
+            io.write_full("dup0", payload)
+            rule.remove()
+            assert rule.fires >= 1
+            assert pg.log.last_version == v0 + 1, \
+                "a racing resend was executed as a second write"
+            assert io.read("dup0") == payload
+    finally:
+        conf.set("objecter_resend_interval", old_resend)

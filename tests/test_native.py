@@ -159,3 +159,27 @@ def test_blockstore_native_python_engines_interoperate(tmp_path):
     assert s3.read("c", "o") == payload
     assert s3.read("c", "o2") == b"py-written"
     s3.umount()
+
+
+def test_failed_build_names_its_cause_once(monkeypatch, tmp_path):
+    """A toolchain fault must not silently select the numpy twin: the
+    loader logs the compiler's words, once, and stays unavailable."""
+    import subprocess
+
+    from ceph_tpu.ops import native_loader as nl
+
+    def no_make(*a, **kw):
+        raise subprocess.CalledProcessError(
+            2, "make", stderr=b"g++: command not found")
+
+    logged = []
+    monkeypatch.setattr(nl, "_lib", None)
+    monkeypatch.setattr(nl, "_failed", False)
+    monkeypatch.setattr(nl, "_SO", tmp_path / "absent.so")
+    monkeypatch.setattr(nl.subprocess, "run", no_make)
+    monkeypatch.setattr(nl, "log", lambda lvl, msg: logged.append(msg))
+    assert nl.get_lib() is None
+    assert nl.get_lib() is None           # remembered, not retried
+    assert len(logged) == 1
+    assert "g++: command not found" in logged[0]
+    assert "numpy twin" in logged[0]
