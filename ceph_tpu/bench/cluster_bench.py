@@ -226,9 +226,6 @@ def run_curve(seconds: float, n_osds: int, obj_size: int,
                 "ops": d.get("ops", 0),
                 "MB_per_launch": round(
                     d.get("bytes", 0) / launches / 1e6, 2),
-                "engine_busy_s": round(d.get("busy_s", 0.0), 2),
-                "busy_ms_per_launch": round(
-                    d.get("busy_s", 0.0) * 1000 / launches, 1),
             }
             attach_stage_breakdown(row)
             rows.append(row)

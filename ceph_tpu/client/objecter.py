@@ -278,7 +278,8 @@ class Objecter:
         # The profiler stage join brackets the same interval: a
         # sample of this thread until the send hand-off is
         # objecter_encode work.
-        _pstage = _profiler.push_stage("objecter_encode")
+        _pstage = _profiler.push_stage("objecter_encode",
+                                       role="client")
         clock = stage_clock.StageClock()
         with self._lock:
             tid = self._next_tid
@@ -337,7 +338,8 @@ class Objecter:
             # blocked on the cluster: a sample of this thread here is
             # client wait, not encode work (the classifier would
             # otherwise charge the park to objecter_encode)
-            _pwait = _profiler.push_stage("client_wait")
+            _pwait = _profiler.push_stage("client_wait",
+                                          role="client")
             try:
                 committed = rec.event.wait(timeout)
             finally:

@@ -74,7 +74,7 @@ _PAGE = """<!doctype html>
 <th>sparse_s</th></tr>{device_rows}</table>
 <h3>engine pipeline</h3>
 <table><tr><th>in-flight depth &ge;2 launches</th>
-<th>overlap &ge;50% batches</th><th>mesh dispatches</th>
+<th>mesh dispatches</th>
 <th>compile cache hits</th></tr>{pipeline_row}</table>
 <h3>deep scrub</h3>
 <table><tr><th>batches</th><th>bytes verified</th><th>mismatches</th>
@@ -372,12 +372,10 @@ class Module(MgrModule):
         mc = _mt().perf.dump()
         counters = tel.snapshot()["counters"]
         depth = counters.get("engine_inflight_depth", [])
-        overlap = counters.get("engine_overlap_pct", [])
         # histogram bucket b holds [2^(b-1), 2^b): depth >= 2 lives in
-        # buckets[2:], overlap >= 50% in buckets[7:] (64..)
+        # buckets[2:]
         pipeline_row = (
             f"<tr><td>{sum(depth[2:])}</td>"
-            f"<td>{sum(overlap[7:])}</td>"
             f"<td>{counters.get('mesh_dispatches', 0)}</td>"
             f"<td>{counters.get('compile_cache_hits', 0)}</td></tr>")
         mp = self._mesh_payload(tel)

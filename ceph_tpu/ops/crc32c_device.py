@@ -201,6 +201,7 @@ def _pallas_rows_fn():
             out_specs=pl.BlockSpec((block, 32), lambda i: (i, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((rows, 32), jnp.int8),
+            name="crc32c_rows",
         )(b_mat, x)
 
     return run

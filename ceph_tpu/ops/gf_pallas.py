@@ -120,6 +120,7 @@ def _matvec_padded_impl(bmat: jax.Array, data: jax.Array,
         out_specs=pl.BlockSpec((m_out, block), lambda i: (0, i),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m_out, n), jnp.uint8),
+        name="gf_matvec",
     )(bmat, data)
 
 

@@ -476,16 +476,10 @@ class DeviceEncodeEngine:
                       "host_flushes": 0,
                       # auxiliary device work run via run_sync (deep
                       # scrub verify launches)
-                      "aux_runs": 0,
-                      # engine-thread seconds spent launching +
-                      # finalizing device batches: busy_s/flushes is
-                      # the MEASURED per-launch cost the amortization
-                      # analysis divides out (BASELINE.md cluster
-                      # table)
-                      "busy_s": 0.0}
+                      "aux_runs": 0}
         _telemetry().note_engine_window(self._window)
         #: launch pipeline: deque of (items, finalize, kspans,
-        #: launch_t, nbytes) batches whose device programs are queued
+        #: nbytes) batches whose device programs are queued
         #: but not yet downloaded — up to ``window`` deep. The RETIRE
         #: thread harvests strictly FIFO, so continuation order equals
         #: launch order; the engine thread never blocks on a download
@@ -775,15 +769,21 @@ class DeviceEncodeEngine:
         no longer queue behind a blocking drain (the measured
         engine_stage_wait share), and bigger flushes amortize the
         per-peer sub-write batches."""
+        _prof.thread_role("engine_retire")
         while True:
-            with self._ifcv:
-                while not self._inflight and not self._retire_stop:
-                    self._ifcv.wait()
-                if not self._inflight and self._retire_stop:
-                    return
-                entry = self._inflight.popleft()
-                self._retiring = True
-                self._ifcv.notify_all()
+            _pidle = _prof.push_stage("idle", span="retire_idle")
+            try:
+                with self._ifcv:
+                    while not self._inflight and \
+                            not self._retire_stop:
+                        self._ifcv.wait()
+                    if not self._inflight and self._retire_stop:
+                        return
+                    entry = self._inflight.popleft()
+                    self._retiring = True
+                    self._ifcv.notify_all()
+            finally:
+                _prof.pop_stage(_pidle)
             try:
                 self._retire_one(entry)
             finally:
@@ -793,6 +793,7 @@ class DeviceEncodeEngine:
 
     # -- engine thread ------------------------------------------------
     def _run(self) -> None:
+        _prof.thread_role("engine_launch")
         while True:
             # profiler join: blocking on an empty queue is idle time,
             # not engine work — without the mark, every sample of the
@@ -862,7 +863,6 @@ class DeviceEncodeEngine:
                     self._drain_inflight()
                     pending, dec_pending, nbytes = {}, {}, 0
                     _, fn, box, ev = item
-                    t0 = _time.perf_counter()
                     prev_stage = _prof.push_stage("scrub")
                     try:
                         box[0] = fn()
@@ -871,7 +871,6 @@ class DeviceEncodeEngine:
                     finally:
                         _prof.pop_stage(prev_stage)
                     self.stats["aux_runs"] += 1
-                    self.stats["busy_s"] += _time.perf_counter() - t0
                     ev.set()
                 else:                        # barrier
                     self._flush(pending)
@@ -906,158 +905,158 @@ class DeviceEncodeEngine:
             # flag here raced the idle drain and dropped them)
 
     def _flush(self, pending: dict) -> None:
-        if not pending:
-            return
-        # profiler join: while the engine thread stages/launches, a
-        # sample of it belongs to the op's engine_stage_wait interval
-        prev_stage = _prof.push_stage("engine_stage_wait")
-        try:
-            self._flush_inner(pending)
-        finally:
-            _prof.pop_stage(prev_stage)
+        for codec, sinfo, pslot, items in pending.values():
+            # profiler join: while the engine thread stages/launches,
+            # a sample of it belongs to the op's engine_stage_wait
+            # interval. On a profiler trace the group's host work is
+            # ``flush_build``; the window wait and the launch itself
+            # nest inside it under their own names
+            mark = _prof.push_stage(
+                "engine_stage_wait", span="flush_build",
+                ops=len(items),
+                bytes=sum(it[1].nbytes for it in items))
+            try:
+                self._flush_group(codec, sinfo, pslot, items)
+            finally:
+                _prof.pop_stage(mark)
+        pending.clear()
 
-    def _flush_inner(self, pending: dict) -> None:
+    def _flush_group(self, codec, sinfo, pslot, items) -> None:
         import time as _time
         from ceph_tpu.parallel import mesh as mesh_mod
         from ceph_tpu.parallel import placement as _placement
-        t0 = _time.perf_counter()
-        drained = 0.0                 # retirement self-accounts
-        for codec, sinfo, pslot, items in pending.values():
-            if self._stager is not None:
-                # zero-copy staging: the payloads are already
-                # contiguous in the signature's concat buffer —
-                # detach the consumed prefix as one view (no
-                # flush-time np.concatenate on this thread)
-                batch, views = self._stager.take(codec, pslot,
-                                                 len(items))
-                nbytes = batch.nbytes
-            else:
-                batch = None
-                views = [d for _k, d, _c, _s, _cl, _t in items]
-                nbytes = sum(d.nbytes for d in views)
-            _telemetry().note_slot_staged(pslot, -nbytes)
-            # a configured default mesh takes the flush through the
-            # multi-chip encode step (pod deployments; dryrun/tests)
-            # — but only once the batch is big enough to amortize the
-            # collective/placement overhead; small flushes stay on
-            # the single-chip kernel (the dense-vs-sharded threshold,
-            # BASELINE.md "Pipelined engine")
-            mesh = mesh_mod.get_default_mesh()
-            if mesh is not None and nbytes < self._mesh_flush_bytes:
-                mesh = None
-            placed = False
-            if mesh is not None:
-                # PG placement (ISSUE 12): this slot's flush launches
-                # on its owning stripe row — a (1, shard) submesh —
-                # so flushes of different slots occupy DISJOINT chips
-                # and genuinely overlap inside the in-flight window
-                pmap = _placement.active_map()
-                if pmap is not None and pmap.n_slots > 1:
-                    mesh = pmap.submesh(pslot)
-                    placed = True
-            # SMALL flushes route to the HOST matvec (bulk ingest):
-            # below host_flush_bytes the fixed device dispatch cost
-            # (jit call + transfer round trip, ~5 ms measured on the
-            # CPU quick run) dwarfs the host encode (~0.4 ms at
-            # 64 KiB) — the same measured-crossover policy shape as
-            # the mesh threshold above it and the sparse-vs-dense
-            # calibration below it. The encode runs at finalize time
-            # on the RETIRE thread, riding the same FIFO as device
-            # batches, so ordering is identical.
-            host = (self._bulk and mesh is None
-                    and nbytes < self._host_flush_bytes
-                    and ec_util.host_flushable(codec))
+        if self._stager is not None:
+            # zero-copy staging: the payloads are already
+            # contiguous in the signature's concat buffer —
+            # detach the consumed prefix as one view (no
+            # flush-time np.concatenate on this thread)
+            batch, views = self._stager.take(codec, pslot,
+                                             len(items))
+            nbytes = batch.nbytes
+        else:
+            batch = None
+            views = [d for _k, d, _c, _s, _cl, _t in items]
+            nbytes = sum(d.nbytes for d in views)
+        _telemetry().note_slot_staged(pslot, -nbytes)
+        # a configured default mesh takes the flush through the
+        # multi-chip encode step (pod deployments; dryrun/tests)
+        # — but only once the batch is big enough to amortize the
+        # collective/placement overhead; small flushes stay on
+        # the single-chip kernel (the dense-vs-sharded threshold,
+        # BASELINE.md "Pipelined engine")
+        mesh = mesh_mod.get_default_mesh()
+        if mesh is not None and nbytes < self._mesh_flush_bytes:
+            mesh = None
+        placed = False
+        if mesh is not None:
+            # PG placement (ISSUE 12): this slot's flush launches
+            # on its owning stripe row — a (1, shard) submesh —
+            # so flushes of different slots occupy DISJOINT chips
+            # and genuinely overlap inside the in-flight window
+            pmap = _placement.active_map()
+            if pmap is not None and pmap.n_slots > 1:
+                mesh = pmap.submesh(pslot)
+                placed = True
+        # SMALL flushes route to the HOST matvec (bulk ingest):
+        # below host_flush_bytes the fixed device dispatch cost
+        # (jit call + transfer round trip, ~5 ms measured on the
+        # CPU quick run) dwarfs the host encode (~0.4 ms at
+        # 64 KiB) — the same measured-crossover policy shape as
+        # the mesh threshold above it and the sparse-vs-dense
+        # calibration below it. The encode runs at finalize time
+        # on the RETIRE thread, riding the same FIFO as device
+        # batches, so ordering is identical.
+        host = (self._bulk and mesh is None
+                and nbytes < self._host_flush_bytes
+                and ec_util.host_flushable(codec))
+        if batch is not None:
+            _telemetry().note_staging_copies_avoided(nbytes)
+        if not host:
+            batcher = ec_util.StripeBatcher(
+                sinfo, codec, mesh=mesh,
+                on_fallback=self._note_fused_fallback)
+            for i, buf in enumerate(views):
+                batcher.append(i, buf)
             if batch is not None:
-                _telemetry().note_staging_copies_avoided(nbytes)
-            if not host:
-                batcher = ec_util.StripeBatcher(
-                    sinfo, codec, mesh=mesh,
-                    on_fallback=self._note_fused_fallback)
-                for i, buf in enumerate(views):
-                    batcher.append(i, buf)
-                if batch is not None:
-                    batcher.set_preconcat(batch)
-            if mesh is not None:
-                self.stats["mesh_flushes"] += 1
-                _telemetry().note_mesh_flush("encode")
-                if placed:
-                    self.stats["placement_flushes"] += 1
-                    per_slot = self.stats["per_slot_flushes"]
-                    per_slot[pslot] = per_slot.get(pslot, 0) + 1
-                    _telemetry().note_placement_flush()
-            # window backpressure BEFORE the launch: with window=1
-            # batch N+1 launches only after N fully retired (the old
-            # serial engine); deeper windows overlap N+1's staging/
-            # upload with N's compute and N-1's download
+                batcher.set_preconcat(batch)
+        if mesh is not None:
+            self.stats["mesh_flushes"] += 1
+            _telemetry().note_mesh_flush("encode")
+            if placed:
+                self.stats["placement_flushes"] += 1
+                per_slot = self.stats["per_slot_flushes"]
+                per_slot[pslot] = per_slot.get(pslot, 0) + 1
+                _telemetry().note_placement_flush()
+        # window backpressure BEFORE the launch: with window=1
+        # batch N+1 launches only after N fully retired (the old
+        # serial engine); deeper windows overlap N+1's staging/
+        # upload with N's compute and N-1's download
+        mark = _prof.push_stage(
+            "engine_stage_wait", span="flush_window_wait",
+            ops=len(items), bytes=nbytes)
+        try:
             self._wait_window()
-            try:
-                # chaos-harness seam (utils/faults engine_launch
-                # rules): an injected launch failure rides the exact
-                # failure-drain path a real device fault takes
-                _faults.engine_fault("launch")
-                if host:
-                    finalize = ec_util.flush_host_async(
-                        sinfo, codec, list(range(len(views))),
-                        views, batch=batch)
-                    self.stats["host_flushes"] += 1
-                else:
-                    finalize = batcher.flush_async(
-                        with_crcs=ec_util.fuse_crc_policy(codec))
-            except Exception as exc:
-                # launch failed: older batches' continuations must
-                # still run BEFORE these error continuations (per-PG
-                # order) — ride the SAME in-flight FIFO as a poison
-                # entry whose "finalize" raises; the retire thread's
-                # failure-drain path dispatches the error
-                # continuations in exact launch order. Bytes move
-                # staged -> in-window here and leave at retirement
-                # (fate decided there: host fallback).
-                def _poison(exc=exc):
-                    raise exc
-                kspans = [span.child("kernel_dispatch")
-                          for _k, _d, _c, span, _cl, _t in items]
-                self._park((items, _poison, kspans,
-                            _time.perf_counter(), nbytes))
-                continue
-            # batch launched (async): park it on the in-flight deque
-            # — its compute+download overlaps the NEXT batch's
-            # staging/upload; only the window bound forces a harvest
-            if _TP_FLUSH.enabled:
-                _TP_FLUSH(len(items), nbytes)
-            launched = _time.monotonic()
-            tel = _telemetry()
-            kspans = []
-            for _key, _data, _cont, span, clock, ts in items:
-                # queue wait = stage -> launch (the batching latency
-                # an op paid for its amortization win)
-                tel.note_queue_wait("encode", launched - ts)
-                clock.mark("engine_stage_wait", t=launched)
-                if span is not NOOP:   # no formatting when untraced
-                    span.event(f"batch_flush ops={len(items)} "
-                               f"bytes={nbytes}")
-                kspans.append(span.child("kernel_dispatch"))
-            entry = (items, finalize, kspans,
-                     _time.perf_counter(), nbytes)
-            if host and not self._inflight and not self._retiring:
-                # light-load fast path: nothing in flight, so FIFO
-                # order is trivially kept — retire the host flush
-                # INLINE instead of paying a retire-thread handoff
-                # (one fewer cross-thread wakeup on the op's
-                # critical path; the wait chain IS the measured
-                # latency). Only the engine thread parks entries, so
-                # the emptiness check cannot race.
-                tel.note_hbm(staged_delta=-nbytes,
-                             inflight_delta=nbytes)
-                self._retire_one(entry)
+        finally:
+            _prof.pop_stage(mark)
+        try:
+            # chaos-harness seam (utils/faults engine_launch
+            # rules): an injected launch failure rides the exact
+            # failure-drain path a real device fault takes
+            _faults.engine_fault("launch")
+            if host:
+                finalize = ec_util.flush_host_async(
+                    sinfo, codec, list(range(len(views))),
+                    views, batch=batch)
+                self.stats["host_flushes"] += 1
             else:
-                self._park(entry)
-        if pending:
-            # retirement time self-accounts in _retire_one; only
-            # the launch-side time is added here (no double count)
-            with self._ifcv:
-                self.stats["busy_s"] += \
-                    _time.perf_counter() - t0 - drained
-        pending.clear()
+                finalize = batcher.flush_async(
+                    with_crcs=ec_util.fuse_crc_policy(codec))
+        except Exception as exc:
+            # launch failed: older batches' continuations must
+            # still run BEFORE these error continuations (per-PG
+            # order) — ride the SAME in-flight FIFO as a poison
+            # entry whose "finalize" raises; the retire thread's
+            # failure-drain path dispatches the error
+            # continuations in exact launch order. Bytes move
+            # staged -> in-window here and leave at retirement
+            # (fate decided there: host fallback).
+            def _poison(exc=exc):
+                raise exc
+            kspans = [span.child("kernel_dispatch")
+                      for _k, _d, _c, span, _cl, _t in items]
+            self._park((items, _poison, kspans, nbytes))
+            return
+        # batch launched (async): park it on the in-flight deque
+        # — its compute+download overlaps the NEXT batch's
+        # staging/upload; only the window bound forces a harvest
+        if _TP_FLUSH.enabled:
+            _TP_FLUSH(len(items), nbytes)
+        launched = _time.monotonic()
+        tel = _telemetry()
+        kspans = []
+        for _key, _data, _cont, span, clock, ts in items:
+            # queue wait = stage -> launch (the batching latency
+            # an op paid for its amortization win)
+            tel.note_queue_wait("encode", launched - ts)
+            clock.mark("engine_stage_wait", t=launched)
+            if span is not NOOP:   # no formatting when untraced
+                span.event(f"batch_flush ops={len(items)} "
+                           f"bytes={nbytes}")
+            kspans.append(span.child("kernel_dispatch"))
+        entry = (items, finalize, kspans, nbytes)
+        if host and not self._inflight and not self._retiring:
+            # light-load fast path: nothing in flight, so FIFO
+            # order is trivially kept — retire the host flush
+            # INLINE instead of paying a retire-thread handoff
+            # (one fewer cross-thread wakeup on the op's
+            # critical path; the wait chain IS the measured
+            # latency). Only the engine thread parks entries, so
+            # the emptiness check cannot race.
+            tel.note_hbm(staged_delta=-nbytes,
+                         inflight_delta=nbytes)
+            self._retire_one(entry)
+        else:
+            self._park(entry)
 
     def _wait_window(self) -> None:
         """Block until the launch window has a free slot (counting a
@@ -1085,25 +1084,33 @@ class DeviceEncodeEngine:
         tel.note_inflight_depth(depth)
         tel.note_engine_inflight(depth)
 
-    def _drain_inflight(self) -> float:
+    def _drain_inflight(self) -> None:
         """Wait until the retire thread has harvested EVERY in-flight
-        batch (ordering points: barrier, run_sync, stop). Returns 0.0
-        — the retire thread self-accounts its harvest time."""
+        batch (ordering points: barrier, run_sync, stop)."""
         with self._ifcv:
             while self._inflight or self._retiring:
                 self._ifcv.wait()
-        return 0.0
 
-    def _retire_one(self, entry) -> float:
+    def _retire_one(self, entry) -> None:
         """Harvest one in-flight batch (download + dispatch its
-        continuations); returns seconds spent (also accumulated into
-        busy_s here). Runs on the retire thread only — it is the sole
-        creator of FlushGroups, so group chaining is single-writer."""
+        continuations). Runs on the retire thread only — it is the
+        sole creator of FlushGroups, so group chaining is
+        single-writer. On a profiler trace the harvest is
+        ``flush_dispatch``, with the blocking ``flush_download``
+        (marked in ``finalize``) nested inside it."""
+        (items, _finalize, _kspans, nbytes) = entry
+        mark = _prof.push_stage(
+            "device_finalize", span="flush_dispatch",
+            ops=len(items), bytes=nbytes)
+        try:
+            self._retire_batch(entry)
+        finally:
+            _prof.pop_stage(mark)
+
+    def _retire_batch(self, entry) -> None:
         import time as _time
-        prev_stage = _prof.push_stage("device_finalize")
-        t0 = _time.perf_counter()
         harvest_t = _time.monotonic()
-        (items, finalize, kspans, launch_t, nbytes) = entry
+        (items, finalize, kspans, nbytes) = entry
         # per-op timeline: launch -> harvest begin is the pipeline-
         # window wait (overlapped with younger batches' staging)
         for _key, _data, _cont, _span, clock, _ts in items:
@@ -1168,25 +1175,14 @@ class DeviceEncodeEngine:
             # and the merged local txn groups (ISSUE 9)
             self._dispatch_entries(entries)
             _telemetry().note_encode_flush(
-                len(items), nbytes, _time.perf_counter() - t0,
+                len(items), nbytes,
                 trace_id=_first_trace_id(items, span_idx=3))
-        dt = _time.perf_counter() - t0
-        # overlap: launch->harvest-begin passed while the engine did
-        # OTHER work (younger batches staged/launched); the remainder
-        # of the lifetime is this harvest's blocking download
         tel = _telemetry()
-        tel.note_overlap(t0 - launch_t,
-                         _time.perf_counter() - launch_t)
         tel.note_engine_retired()
         tel.note_engine_inflight(len(self._inflight))
         # the batch's bytes leave the window on BOTH outcomes
         # (download or failover) — the gauges-to-zero invariant
         tel.note_hbm(inflight_delta=-nbytes, retired=nbytes)
-        with self._ifcv:     # busy_s has two writers (launch/retire)
-            self.stats["busy_s"] += dt
-        _prof.pop_stage(prev_stage)
-        return dt
-
 
     def _note_fused_fallback(self, path: str, exc: Exception) -> None:
         """A mesh/fused flush path failed and the batch re-ran on the
@@ -1204,28 +1200,32 @@ class DeviceEncodeEngine:
         keyed exactly like the ISA decode-table cache), so their shard
         streams concatenate along the byte axis into a single launch.
         Continuations run inline (see stage_decode)."""
-        import time as _time
-        if not dec_pending:
-            return
-        prev_stage = _prof.push_stage("device_finalize")
-        try:
-            self._flush_decodes_inner(dec_pending)
-        finally:
-            _prof.pop_stage(prev_stage)
+        for (_cid, present, want, pslot), \
+                (codec, sinfo, _slot, items) in dec_pending.items():
+            self._flush_decode_group(present, want, pslot, codec,
+                                     sinfo, items)
+        dec_pending.clear()
 
-    def _flush_decodes_inner(self, dec_pending: dict) -> None:
+    def _flush_decode_group(self, present, want, pslot, codec, sinfo,
+                            items) -> None:
+        """One signature's decode flush. To the sampling profiler all
+        of it is ``device_finalize``; a profiler trace sees three
+        states in turn: ``decode_build`` (the survivors' concatenate),
+        ``decode_run`` (upload, program and download, synchronous on
+        this thread) and ``decode_dispatch`` (the continuations)."""
         import time as _time
         from ceph_tpu.parallel import mesh as mesh_mod
         from ceph_tpu.parallel import placement as _placement
-        for (_cid, present, want, pslot), \
-                (codec, sinfo, _slot, items) in dec_pending.items():
+        staged = sum(_shards_nbytes(shards)
+                     for _k, shards, _w, _c, _s, _cl, _t in items)
+        batch = {"ops": len(items), "bytes": staged}
+        mark = _prof.push_stage("device_finalize",
+                                span="decode_build", **batch)
+        try:
             launched = _time.monotonic()
-            t0 = _time.perf_counter()
             tel = _telemetry()
             # staged bytes leave the ledger here: whatever happens
             # below (decode or fault), this group's buffers are done
-            staged = sum(_shards_nbytes(shards)
-                         for _k, shards, _w, _c, _s, _cl, _t in items)
             tel.note_hbm(staged_delta=-staged, retired=staged)
             tel.note_slot_staged(pslot, -staged)
             for _key, _shards, _want, _cont, span, clock, ts in items:
@@ -1245,6 +1245,9 @@ class DeviceEncodeEngine:
                     for c in present}
                 lens = [len(np.asarray(shards[present[0]]))
                         for _k, shards, _w, _c, _s, _cl, _t in items]
+                _prof.pop_stage(mark)
+                mark = _prof.push_stage("device_finalize",
+                                        span="decode_run", **batch)
                 # multi-chip decode (ISSUE 12): a big-enough
                 # signature batch rides the mesh twin of the decode
                 # matmul on this PG slot's submesh — the same
@@ -1285,7 +1288,10 @@ class DeviceEncodeEngine:
                     span.set_error(f"engine_decode: {exc!r}")
                     span.finish()
                     cont(None, exc)
-                continue
+                return
+            _prof.pop_stage(mark)
+            mark = _prof.push_stage("device_finalize",
+                                    span="decode_dispatch", **batch)
             if _TP_DECODE_FLUSH.enabled:
                 _TP_DECODE_FLUSH(len(items), str(present))
             nbytes = sum(ln * len(present) for ln in lens)
@@ -1298,7 +1304,7 @@ class DeviceEncodeEngine:
                 self._counters.inc("device_decode_batches")
                 self._counters.inc("device_decode_ops", len(items))
             tel.note_decode_flush(
-                len(items), nbytes, _time.perf_counter() - t0,
+                len(items), nbytes,
                 trace_id=_first_trace_id(items, span_idx=4))
             done_t = _time.monotonic()
             off = 0
@@ -1310,7 +1316,8 @@ class DeviceEncodeEngine:
                 cont({c: v[off:off + ln] for c, v in out.items()},
                      None)
                 off += ln
-        dec_pending.clear()
+        finally:
+            _prof.pop_stage(mark)
 
 
 def _first_trace_id(items, span_idx: int) -> str | None:

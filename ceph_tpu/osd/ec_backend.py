@@ -1183,6 +1183,12 @@ class ECBackend(PGBackend):
         want = list(range(self.k))
         count = read_heat.note((pg.pool, oid))
         rotate = count if count >= self._hot_threshold else None
+        # the read's stage split: PG work ends where the sub-read
+        # fan-out starts; the gather (intact or degraded) is
+        # ``shard_read_wait``; what follows is the engine's batching
+        # wait, its decode flush, and ``commit_wait`` to the reply
+        clock = stage_clock.current()
+        clock.mark("pg_process")
         try:
             chunks, attrs = self._read_shards(pg, oid, want,
                                               rotate_count=rotate)
@@ -1190,6 +1196,8 @@ class ECBackend(PGBackend):
         except Exception as exc:
             cont(None, exc)
             return
+        finally:
+            clock.mark("shard_read_wait")
         if all(i in chunks for i in want):
             cont(self._chunks_to_logical(chunks, size), None)
             return

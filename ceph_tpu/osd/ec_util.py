@@ -24,12 +24,31 @@ import numpy as np
 
 from ceph_tpu.models.interface import ErasureCodeError
 from ceph_tpu.utils import checksum
+from ceph_tpu.utils import profiler as _prof
 from ceph_tpu.utils.dout import Dout
 
 log = Dout("osd")
 
 #: initial per-shard crc seed (the reference seeds with -1, ECUtil.h:117)
 HINFO_SEED = 0xFFFFFFFF
+
+
+def _phase(span: str, n_ops: int, nbytes: int, fn, *args):
+    """``fn(*args)`` as one flush phase of the calling thread, through
+    the profiler's seam: ``flush_launch`` is the call that hands a
+    batch to the codec (the jit call on the device routes: dispatch
+    plus the host-to-device enqueue; the whole matvec on the host
+    route, the whole synchronous encode on the plain one), and
+    ``flush_download`` the wait for a device program and the copy of
+    its results to the host. The engine's ``flush_build`` and
+    ``flush_dispatch`` marks are open around them."""
+    mark = _prof.push_stage(
+        "engine_stage_wait" if span == "flush_launch"
+        else "device_finalize", span=span, ops=n_ops, bytes=nbytes)
+    try:
+        return fn(*args)
+    finally:
+        _prof.pop_stage(mark)
 
 
 @dataclass(frozen=True)
@@ -341,7 +360,8 @@ class StripeBatcher:
                 self._note_fallback("fused_crc", exc)
         batch = preconcat if preconcat is not None \
             else np.concatenate(bufs)
-        shards = encode(self.sinfo, self.codec, batch)
+        shards = _phase("flush_launch", len(ops), batch.nbytes,
+                        encode, self.sinfo, self.codec, batch)
         results = []
         cs, sw = self.sinfo.chunk_size, self.sinfo.stripe_width
         off = 0  # in chunk units per shard
@@ -436,7 +456,8 @@ def flush_host_async(sinfo: StripeInfo, codec, ops, bufs,
         data_shards = np.ascontiguousarray(
             batch.reshape(s, k, cs).transpose(1, 0, 2)
             .reshape(k, s * cs))
-        parity = backend_mod.matvec(mat, data_shards, backend)
+        parity = _phase("flush_launch", len(ops), batch.nbytes,
+                        backend_mod.matvec, mat, data_shards, backend)
         results = []
         off = 0
         for op_id, ln in zip(ops, lens):
@@ -550,11 +571,13 @@ def _flush_mesh(mesh, sinfo: StripeInfo, codec, ops, bufs,
         lambda: sharded_codec.make_encode_step(
             mesh, np.asarray(codec.coding_matrix, dtype=np.uint8),
             place=False))
-    chunks_dev, _csum = step(
-        sharded_codec.shard_stripe_batch(mesh, data))
+    chunks_dev, _csum = _phase(
+        "flush_launch", len(ops), batch.nbytes,
+        lambda: step(sharded_codec.shard_stripe_batch(mesh, data)))
 
     def finalize():
-        chunks = np.asarray(chunks_dev)[:s]    # [s, k+m, cs]
+        chunks = _phase("flush_download", len(ops), batch.nbytes,
+                        np.asarray, chunks_dev)[:s]    # [s, k+m, cs]
         streams = {i: np.ascontiguousarray(
             chunks[:, i, :]).reshape(-1) for i in range(n_chunks)}
         results = []
@@ -644,10 +667,12 @@ def fused_program(codec, n_b: int, lmax_b: int, nops_b: int):
     mat = np.asarray(codec.coding_matrix, dtype=np.uint8)
 
     def fused(data, offs, seg_lens):
-        parity = dev.matvec_device(mat, data)
-        shards = jnp.concatenate(
-            [data, parity.astype(jnp.uint8)], axis=0)
-        padded = jnp.pad(shards, ((0, 0), (lmax_b, 0)))
+        # stable names for a device trace: the two halves of the
+        # program appear under ``encode`` and ``crc_windows``
+        with jax.named_scope("encode"):
+            parity = dev.matvec_device(mat, data)
+            shards = jnp.concatenate(
+                [data, parity.astype(jnp.uint8)], axis=0)
 
         def seg(off, ln):
             # window ENDING at the segment end; bytes before the
@@ -657,9 +682,11 @@ def fused_program(codec, n_b: int, lmax_b: int, nops_b: int):
             mask = jnp.arange(lmax_b) >= (lmax_b - ln)
             return win * mask.astype(jnp.uint8)
 
-        segs = jax.vmap(seg)(offs, seg_lens)
-        lin = cd.crc_linear_device(
-            segs.reshape(nops_b * n_chunks, lmax_b))
+        with jax.named_scope("crc_windows"):
+            padded = jnp.pad(shards, ((0, 0), (lmax_b, 0)))
+            segs = jax.vmap(seg)(offs, seg_lens)
+            lin = cd.crc_linear_device(
+                segs.reshape(nops_b * n_chunks, lmax_b))
         return parity, lin
 
     fn = _fused_cache[key] = jax.jit(fused)
@@ -730,12 +757,16 @@ def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
             from ceph_tpu.ops import cost_model
             cost_model.analyze(fn, data_dev, offs_arr, lens_arr,
                                signature=signature)
-    parity_dev, lin_dev = telemetry().timed_call(
-        signature, fn, data_dev, offs_arr, lens_arr)
+    parity_dev, lin_dev = _phase(
+        "flush_launch", len(ops), batch.nbytes,
+        telemetry().timed_call, signature, fn, data_dev, offs_arr,
+        lens_arr)
 
     def finalize():
-        parity = np.asarray(parity_dev)
-        lin = np.asarray(lin_dev).reshape(nops_b, n_chunks)
+        parity, lin = _phase(
+            "flush_download", len(ops), batch.nbytes,
+            lambda: (np.asarray(parity_dev), np.asarray(lin_dev)))
+        lin = lin.reshape(nops_b, n_chunks)
         results = []
         off = 0
         for idx, (op_id, ln) in enumerate(zip(ops, lens)):

@@ -336,6 +336,7 @@ class ShardedOpWQ:
     def _worker(self, sh) -> None:
         mclock = isinstance(sh, _MClockShard)
         _wq_tls.active = True      # marks this thread as a wq worker
+        _prof.thread_role("osd_wq")
         while True:
             # profiler join: a worker parked on its cv is idle, not
             # pg_process work (the classifier would otherwise charge

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import asyncio
 import random
+import selectors
 import struct
 import threading
 import time
@@ -171,6 +172,26 @@ class Throttle:
             self._cond.notify_all()
 
 
+class _MarkedSelector(selectors.DefaultSelector):
+    """The event loop's selector, marking the loop thread's state
+    through the profiler's seam: between two waits for I/O the thread
+    does ``wire`` work (serialize, socket writes, frame reads, fast
+    dispatch); inside ``select`` it has no mark of its own. A whole-
+    thread mark never closes, so a profiler trace would never see
+    it."""
+
+    _mark = None
+
+    def select(self, timeout=None):
+        if self._mark is not None:
+            _prof.pop_stage(self._mark)
+            self._mark = None
+        try:
+            return super().select(timeout)
+        finally:
+            self._mark = _prof.push_stage("wire")
+
+
 class Messenger:
     """One daemon's endpoint: bind+accept, connection cache, typed
     dispatch. ``entity_name`` is the Ceph-style identity ("osd.3",
@@ -181,7 +202,7 @@ class Messenger:
         self.entity_name = entity_name
         self.addr: str = ""
         self._dispatcher: Callable[[Message, Connection], None] | None = None
-        self._loop = asyncio.new_event_loop()
+        self._loop = asyncio.SelectorEventLoop(_MarkedSelector())
         self._thread = threading.Thread(
             target=self._run_loop,
             name=f"ms-{entity_name}", daemon=True)
@@ -223,7 +244,9 @@ class Messenger:
         # serialize, socket writes, frame reads, fast dispatch — is
         # the data plane's ``wire`` stage, so the whole event-loop
         # thread carries the mark (never popped; the thread dies with
-        # the loop)
+        # the loop). _MarkedSelector marks each busy stretch between
+        # two waits again, so that it closes and a trace shows it
+        _prof.thread_role("msgr")
         _prof.push_stage("wire")
         self._loop.run_forever()
 

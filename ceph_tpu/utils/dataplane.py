@@ -32,8 +32,8 @@ from ceph_tpu.utils.perf_counters import PerfCounters, collection
 #: + the commit-wait envelope children), anchor marks excluded (they
 #: have no duration)
 STAGE_KEYS = tuple(
-    s for s in stage_clock.EC_WRITE_STAGES + stage_clock.SUBOP_STAGES
-    + stage_clock.COMMIT_STAGES
+    s for s in stage_clock.EC_WRITE_STAGES + stage_clock.READ_STAGES
+    + stage_clock.SUBOP_STAGES + stage_clock.COMMIT_STAGES
     if s not in ("client_submit", "subop_send", "commit_start"))
 
 #: child-vocabulary stages: they nest INSIDE commit_wait, so the main

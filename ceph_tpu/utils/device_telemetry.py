@@ -99,12 +99,6 @@ class DeviceTelemetry:
                           "stage_encode -> flush launch wait")
         perf.add_time_avg("decode_queue_wait",
                           "stage_decode -> flush launch wait")
-        perf.add_time_avg("flush_device_time",
-                          "engine-thread seconds per encode-flush "
-                          "harvest (device wait + download + "
-                          "continuation dispatch)")
-        perf.add_time_avg("decode_flush_device_time",
-                          "engine-thread seconds per decode flush")
         perf.add_u64_counter("bytes_encoded",
                              "payload bytes through device encode")
         perf.add_u64_counter("bytes_decoded",
@@ -151,16 +145,10 @@ class DeviceTelemetry:
                              "mesh steps compiled through the "
                              "explicit-collectives shard_map spelling")
         # pipelined engine (osd/device_engine.py): launch-window
-        # accounting — depth proves batches overlap, overlap-pct is
-        # the share of a batch's device lifetime hidden behind other
-        # engine work (100% = the download wait fully overlapped)
+        # accounting — depth proves batches overlap
         perf.add_histogram("engine_inflight_depth",
                            "launched-not-retired batches at each "
                            "flush launch (window occupancy)")
-        perf.add_histogram("engine_overlap_pct",
-                           "percent of a batch's launch->retire "
-                           "lifetime spent overlapped with other "
-                           "engine work")
         # stall detection inputs (mgr/health.py ENGINE_STALL): the
         # health engine reads the current window occupancy and checks
         # the retirement counter for progress over its window
@@ -299,21 +287,17 @@ class DeviceTelemetry:
 
     # -- engine flush accounting --------------------------------------
     def note_encode_flush(self, ops: int, nbytes: int,
-                          device_s: float,
                           trace_id: str | None = None) -> None:
         """``trace_id`` (a traced op riding the flush) attaches as the
         histogram-bucket exemplar: a dashboard's outlier flush bucket
         links straight to a kept trace (ISSUE 10)."""
         self.perf.hinc("encode_batch_ops", ops, exemplar=trace_id)
         self.perf.hinc("flush_bytes", nbytes, exemplar=trace_id)
-        self.perf.tinc("flush_device_time", device_s)
         self.perf.inc("bytes_encoded", nbytes)
 
     def note_decode_flush(self, ops: int, nbytes: int,
-                          device_s: float,
                           trace_id: str | None = None) -> None:
         self.perf.hinc("decode_batch_ops", ops, exemplar=trace_id)
-        self.perf.tinc("decode_flush_device_time", device_s)
         self.perf.inc("bytes_decoded", nbytes)
 
     def note_queue_wait(self, kind: str, seconds: float) -> None:
@@ -345,19 +329,6 @@ class DeviceTelemetry:
 
     def note_engine_retired(self) -> None:
         self.perf.inc("engine_retired")
-
-    def note_overlap(self, overlapped_s: float,
-                     lifetime_s: float) -> None:
-        """One retired batch's overlap: ``overlapped_s`` of its
-        ``lifetime_s`` launch->retire window passed while the engine
-        did other work (staging/launching younger batches) instead of
-        blocking on this one's download."""
-        if lifetime_s <= 0:
-            return
-        pct = int(round(100.0 * max(0.0, min(overlapped_s,
-                                             lifetime_s))
-                        / lifetime_s))
-        self.perf.hinc("engine_overlap_pct", pct)
 
     # -- codec-layer accounting ---------------------------------------
     def note_calibration(self, label: str, signature: str,

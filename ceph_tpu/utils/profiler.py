@@ -29,7 +29,11 @@ Design:
   wall-only (never an error).
 - **The stage join** (the key move): daemon hot loops mark the stage
   that owns the thread via :func:`push_stage`/:func:`pop_stage`
-  (plain dict writes — allocation-free, always on, nanoseconds), so
+  (a dict write and a ``jax.profiler.TraceAnnotation`` — always on,
+  under a microsecond; the annotation puts the same state, with the
+  thread's role, on the clock of any running ``jax.profiler`` trace,
+  which is how ``benchmarks/host_trace.py`` attributes the chip's
+  idle time to host states), so
   a sample lands attributed to the PR-6 stage vocabulary: the
   messenger loop is ``wire``, an op-wq worker is ``pg_process`` (or
   ``commit_wait`` for engine continuations), the engine thread is
@@ -76,18 +80,63 @@ OVERFLOW_KEY = "[stack-table-full]"
 _MAX_DEPTH = 48
 
 
-def push_stage(stage: str) -> str | None:
+#: thread ident -> the role its annotations carry in a profiler trace
+#: (every thread's line there is named alike, so the role rides on the
+#: annotation); set once per thread by :func:`thread_role`
+_thread_role: dict[int, str] = {}
+
+
+def thread_role(role: str) -> None:
+    """Name the calling thread's role for the marks it makes from now
+    on (``engine_launch``, ``osd_wq``, ...). A mark made on a thread
+    without one, and not naming one itself, carries role ``other``
+    (a trace drops an empty argument)."""
+    _thread_role[threading.get_ident()] = role
+
+
+#: ``jax.profiler.TraceAnnotation`` once JAX is imported
+_annotation_cls = None
+
+
+def _annotation(name: str, **stats):
+    """``jax.profiler.TraceAnnotation(name, **stats)``, which costs an
+    inactive check when no trace runs. A process that never imported
+    JAX cannot be tracing: it gets None and JAX stays unimported."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        cls = getattr(sys.modules.get("jax.profiler"),
+                      "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    return cls(name, **stats)
+
+
+def push_stage(stage: str, span: str | None = None,
+               role: str | None = None, **stats):
     """Mark the calling thread as owned by ``stage``; returns the
-    previous owner for :func:`pop_stage`. One dict store — safe to
-    leave in hot paths with the profiler off."""
+    token :func:`pop_stage` takes. One dict store for the sampler's
+    join, plus one annotation named ``span`` (``stage`` when not
+    given) with ``role=`` and ``stats`` as its arguments: whenever a
+    ``jax.profiler`` trace runs, whoever started it, the state lands
+    on the profiler's clock in plane ``/host:CPU``. Safe to leave in
+    hot paths: with no trace and no sampler a mark is that dict store
+    and the annotation's own inactive check."""
     ident = threading.get_ident()
     prev = _thread_stage.get(ident)
     _thread_stage[ident] = stage
-    return prev
+    if role is None:
+        role = _thread_role.get(ident, "other")
+    return prev, _annotation(span or stage, role=role, **stats)
 
 
-def pop_stage(prev: str | None) -> None:
-    """Restore the previous owner saved by :func:`push_stage`."""
+def pop_stage(token) -> None:
+    """Close the mark :func:`push_stage` returned ``token`` for and
+    restore the previous owner."""
+    prev, annotation = token
+    if annotation is not None:
+        annotation.__exit__(None, None, None)
     ident = threading.get_ident()
     if prev is None:
         _thread_stage.pop(ident, None)
@@ -355,6 +404,7 @@ class StackProfiler:
         for ident in [i for i in list(_thread_stage)
                       if i not in frames and i in self._threads]:
             _thread_stage.pop(ident, None)
+            _thread_role.pop(ident, None)
         # counters outside the table lock (they have their own)
         self.perf.set_gauge("profile_unique_stacks", n_unique)
         # set-to-absolute via inc deltas is racy across sweeps; the
@@ -522,6 +572,7 @@ def reset_for_tests() -> None:
         collection().remove("profiler")
         _profiler = None
     _thread_stage.clear()
+    _thread_role.clear()
 
 
 def register_asok(asok) -> None:

@@ -53,6 +53,12 @@ EC_WRITE_STAGES = (
     "commit_reply",
 )
 
+#: what a read marks besides (``ec_backend.read_object_async``):
+#: client_submit .. pg_process, then shard_read_wait, then for a
+#: reconstruct engine_stage_wait and device_finalize, then
+#: commit_wait (reassembly + reply build) and commit_reply
+READ_STAGES = ("shard_read_wait",)
+
 #: a shard sub-write's child timeline (primary -> shard OSD -> commit)
 SUBOP_STAGES = ("subop_send", "subop_wire", "subop_dispatch_wait",
                 "subop_commit")
@@ -73,12 +79,15 @@ GLOSSARY = {
     "send_queue_wait": "send_message() -> messenger loop pickup",
     "wire": "frame serialize + socket + receiver read loop",
     "dispatch_queue_wait": "fast dispatch -> op-wq worker dequeue",
-    "pg_process": "dup/blocklist checks + PG lock -> engine staging",
+    "pg_process": "dup/blocklist checks + PG lock -> engine staging "
+                  "(reads: -> shard sub-read fan-out)",
+    "shard_read_wait": "reads: shard sub-read fan-out + gather, "
+                       "intact or degraded",
     "engine_stage_wait": "staged -> batch flush launch (batching)",
     "device_window_wait": "launch -> harvest begin (pipeline window)",
     "device_finalize": "blocking device compute + parity download",
     "commit_wait": "continuation -> all shard sub-ops committed "
-                   "(reads: op execution)",
+                   "(reads: reassembly + reply build)",
     "commit_reply": "reply serialize + wire + client wakeup",
     "subop_send": "anchor: MECSubWrite handed to the messenger",
     "subop_wire": "sub-op frame serialize + socket + shard read loop",

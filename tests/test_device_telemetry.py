@@ -177,10 +177,9 @@ def test_counters_across_staged_encode_decode_round_trip():
         before["encode_queue_wait"]["avgcount"] == 6
     assert after["decode_queue_wait"]["avgcount"] - \
         before["decode_queue_wait"]["avgcount"] == 1
-    assert after["flush_device_time"]["avgcount"] - \
-        before["flush_device_time"]["avgcount"] == 2
-    assert after["decode_flush_device_time"]["avgcount"] - \
-        before["decode_flush_device_time"]["avgcount"] == 1
+    assert sum(after["flush_bytes"]) - sum(before["flush_bytes"]) == 2
+    assert "flush_device_time" not in after
+    assert "decode_flush_device_time" not in after
     d_bytes = [a - b for a, b in zip(after["flush_bytes"],
                                      before["flush_bytes"])]
     # flush sizes: 2048 (bucket 12) and 5*2048 = 10240 (bucket 14)
@@ -269,7 +268,7 @@ def test_device_perf_dump_and_trace_chain():
             counters = dump["counters"]
             assert counters["bytes_encoded"] > 0, counters
             assert sum(counters["encode_batch_ops"]) > 0
-            assert counters["flush_device_time"]["avgcount"] > 0
+            assert counters["encode_queue_wait"]["avgcount"] > 0
             assert "compiles" in counters
             json.dumps(dump)          # the payload is JSON-clean
 
