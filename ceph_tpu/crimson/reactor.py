@@ -258,9 +258,8 @@ class ReactorServices:
         """One engine flush's local txns as ONE store group — PR 15's
         ``queue_transaction_group`` (shared leader-follower barrier
         rounds on durable stores), applied on the owning reactor. The
-        FlushGroup may ship from whichever reactor finished last, so
-        this routes: one counted hop at worst, then commit callbacks
-        sweep inline."""
+        FlushGroup ships on the engine's ship thread, so this routes:
+        one counted hop, then commit callbacks sweep inline."""
         for txn, _cb in pairs:
             self._note_txn_flow(txn)
 

@@ -8,8 +8,10 @@ a TPU cannot be fed per-4KiB-op without drowning in dispatch latency,
 so the daemon's encode work is decoupled from the op path:
 
 - ``stage_encode`` queues an op's padded payload; the engine folds
-  every queued payload (across PGs — batching across placement groups
-  is where the batch size comes from) into ONE device kernel launch
+  every queued payload of one :func:`program_key` (across PGs and
+  OSDs — batching across placement groups is where the batch size
+  comes from: each PG has its own codec OBJECT, the key is what the
+  flush program depends on) into ONE device kernel launch
   via :class:`ceph_tpu.osd.ec_util.StripeBatcher`, then dispatches
   each op's continuation (hinfo + shard-txn build + fan-out) back
   onto the OSD's sharded op queue.
@@ -68,21 +70,25 @@ Bulk ingest (ISSUE 9, ``CEPH_TPU_BULK_INGEST``, default on) — three
 coupled changes that move work across every boundary in batches:
 
 - **Zero-copy staging**: ``stage_encode`` writes each op's payload
-  into a per-signature preallocated concat buffer at staging time
-  (:class:`_ConcatStager`), so the flush hands the device ONE
-  contiguous view instead of re-concatenating N per-op arrays on the
-  engine thread (``staging_copies_avoided_bytes`` counts the bytes
-  that skipped the flush-time copy). Buffer ownership passes to the
-  flush results; a fresh buffer backs the next flush.
+  into a preallocated concat buffer per :func:`program_key` at
+  staging time (:class:`_ConcatStager`), so the flush hands the
+  device ONE contiguous view instead of re-concatenating N per-op
+  arrays on the engine thread (``staging_copies_avoided_bytes``
+  counts the bytes that skipped the flush-time copy). Buffer
+  ownership passes to the flush results; a fresh buffer backs the
+  next flush.
 - **Batched continuation dispatch**: a retired flush dispatches ONE
   wrapper per distinct key (pgid) instead of one callable per op;
-  the wrappers share a :class:`FlushGroup`, and the LAST one to
-  finish ships the flush's deferred cross-PG work — the per-peer
-  MECSubWriteBatch fan-out and the merged local txn group ECBackend
-  registers via :func:`current_group`. Groups flush in strict flush
-  order (each waits its predecessor), and barriers chain behind the
-  last group's flush, so per-PG commit order is exactly the
-  pre-batching order.
+  the wrappers share a :class:`FlushGroup`, and when the LAST one
+  has finished the engine's ship thread ships the flush's deferred
+  cross-PG work — the per-peer MECSubWriteBatch fan-out and the
+  merged local txn group ECBackend registers via
+  :func:`current_group`. Groups ship in strict flush order, one
+  after the other on that one thread (never on an op-wq worker).
+  A barrier is dispatched behind the last group's ship, and its
+  key's continuations retired while it waits are dispatched behind
+  the barrier, so per-PG commit order is exactly the pre-batching
+  order however far behind the ship thread is.
 - **Shared engine service**: co-located OSDs attach to one
   process-wide engine (:func:`shared_engine_attach`) instead of one
   engine each — cross-OSD flushes aggregate into bigger batches and
@@ -94,6 +100,7 @@ coupled changes that move work across every boundary in batches:
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time as _time
@@ -187,41 +194,77 @@ def _placement_slot(key) -> int:
     return pmap.slot(key)
 
 
+_opaque_codec_seq = itertools.count(1)
+_program_key_lock = make_lock("engine.program_key")
+
+
+def program_key(codec, sinfo: ec_util.StripeInfo) -> tuple:
+    """The key under which staged ops meet in one flush: what the
+    flush program and its results depend on and nothing else — the
+    codec's class and ``backend``, its coding matrix (what
+    :func:`ec_util.fused_program` keys its cache by),
+    ``chunk_mapping``, and the stripe geometry. Every PG's ECBackend
+    builds its own codec object from the pool's profile; equal keys
+    mean the objects are interchangeable, so ops of different PGs
+    (and OSDs) of one pool share a flush, and pools that differ in
+    any of these never do. A codec that is not a plain matrix codec
+    (clay, lrc: layered state the key cannot see) gets a key of its
+    own. Runs on every producer thread: the codec's part is computed
+    once per codec object and cached on it, valid while the codec
+    keeps the matrix it was computed from."""
+    cached = getattr(codec, "_engine_program_key", None)
+    mat = getattr(codec, "coding_matrix", None)
+    if cached is None or cached[0] is not mat:
+        from ceph_tpu.models.matrix_codec import MatrixErasureCode
+        with _program_key_lock:     # one key a codec, whoever is first
+            cached = getattr(codec, "_engine_program_key", None)
+            if cached is None or cached[0] is not mat:
+                if isinstance(codec, MatrixErasureCode) and \
+                        mat is not None:
+                    ck = (type(codec), codec.backend, mat.shape,
+                          mat.tobytes(), tuple(codec.chunk_mapping))
+                else:
+                    ck = (type(codec), next(_opaque_codec_seq))
+                cached = codec._engine_program_key = (mat, ck)
+    return (cached[1], sinfo.stripe_width, sinfo.chunk_size)
+
+
 class _ConcatStager:
-    """Per-signature preallocated concat buffers, written at staging
-    time (the zero-copy leg of ISSUE 9). ``append`` copies the op's
-    payload into the signature's open buffer on the PRODUCER thread;
-    ``take`` hands the engine the consumed prefix as one contiguous
-    view plus per-op views into it — no flush-time np.concatenate.
-    Ownership of the handed buffer passes to the flush (result shard
-    views may alias it); unconsumed tail bytes (ops racing the flush
-    cut) relocate into a fresh buffer."""
+    """Per-program-key preallocated concat buffers, written at
+    staging time (the zero-copy leg of ISSUE 9). ``append`` copies
+    the op's payload into its key's open buffer on the PRODUCER
+    thread; ``take`` hands the engine the consumed prefix as one
+    contiguous view plus per-op views into it — no flush-time
+    np.concatenate. A key is (:func:`program_key`, placement slot):
+    ops of every PG whose codec and stripe geometry are equal land in
+    ONE buffer, in queue order. Ownership of the handed buffer passes
+    to the flush (result shard views may alias it); unconsumed tail
+    bytes (ops racing the flush cut, of whatever PGs) relocate into a
+    fresh buffer."""
 
     _MIN_CAP = 256 << 10
 
     def __init__(self) -> None:
         self.lock = make_lock("engine.stager")
-        #: (id(codec), placement slot) -> {"buf", "used",
-        #: "slots": [[off, len], ...]} — keyed by signature AND slot
+        #: (program_key, placement slot) -> {"buf", "used",
+        #: "slots": [[off, len], ...]} — keyed by program AND slot
         #: (ISSUE 12) so each placement slot's flush hands its owning
         #: submesh one contiguous view
-        self._by_codec: dict[tuple, dict] = {}
+        self._by_key: dict[tuple, dict] = {}
         self.stats = {"staged_bytes": 0, "relocated_bytes": 0}
 
-    def _state(self, codec, pslot: int) -> dict:
-        st = self._by_codec.get((id(codec), pslot))
+    def _state(self, gkey: tuple) -> dict:
+        st = self._by_key.get(gkey)
         if st is None:
-            st = self._by_codec[(id(codec), pslot)] = {
+            st = self._by_key[gkey] = {
                 "buf": np.empty(self._MIN_CAP, dtype=np.uint8),
                 "used": 0, "slots": []}
         return st
 
-    def append_locked(self, codec, pslot: int,
-                      data: np.ndarray) -> None:
+    def append_locked(self, gkey: tuple, data: np.ndarray) -> None:
         """Caller holds ``self.lock`` (the engine queue put rides the
-        same critical section so per-(codec, slot) order == queue
-        order)."""
-        st = self._state(codec, pslot)
+        same critical section so per-key order == queue order)."""
+        st = self._state(gkey)
         need = st["used"] + data.nbytes
         if need > len(st["buf"]):
             cap = max(len(st["buf"]), self._MIN_CAP)
@@ -235,15 +278,15 @@ class _ConcatStager:
         st["used"] = need
         self.stats["staged_bytes"] += data.nbytes
 
-    def take(self, codec, pslot: int, count: int
+    def take(self, gkey: tuple, count: int
              ) -> tuple[np.ndarray, list[np.ndarray]]:
         """Detach the first ``count`` staged ops of this
-        (signature, slot): returns (contiguous batch view, per-op
+        (program key, slot): returns (contiguous batch view, per-op
         views). The tail (ops staged after the engine decided to
         flush) moves to a fresh buffer so its queued tokens stay
         valid."""
         with self.lock:
-            st = self._state(codec, pslot)
+            st = self._state(gkey)
             slots = st["slots"][:count]
             tail = st["slots"][count:]
             buf = st["buf"]
@@ -273,21 +316,30 @@ class FlushGroup:
     """Per-retired-flush rendezvous (the batched fan-out leg of
     ISSUE 9): the engine dispatches one continuation wrapper per
     distinct key; each wrapper's ops may :meth:`defer` cross-PG work
-    (per-peer sub-write batches, merged local txn groups), and the
-    LAST wrapper to finish ships it — after the PREVIOUS flush's
-    group shipped, so sends to a peer keep flush order (the per-PG
+    (per-peer sub-write batches, merged local txn groups). When the
+    LAST wrapper has finished the group is ``ready``, and the
+    engine's ship thread ships it: groups ship one after the other in
+    flush order, so sends to a peer keep flush order (the per-PG
     commit-order contract extended across the batch boundary).
-    Barriers chain behind the flush via :meth:`after_flush`."""
+    Barriers chain behind the flush via :meth:`after_flush`.
 
-    def __init__(self, nkeys: int,
-                 prev_group: "FlushGroup | None") -> None:
+    The ship must NOT run on a wrapper's thread: chained there
+    through after-flush callbacks, a busy chain makes one op-wq
+    worker ship dozens of groups in one nested cascade (65 deep,
+    2.4 s measured) while every op and sub-write hashed to its shard
+    waits — that was the 4 MiB write's tail (PERF.md section 6,
+    PR 27)."""
+
+    def __init__(self, nkeys: int) -> None:
         self._lock = make_lock("engine.flush_group")
         self._pending = max(1, nkeys)
         #: bucket -> (ship_fn, [items]); insertion-ordered
         self._deferred: dict = {}
         self._after: list = []
-        self._prev_group = prev_group
         self._flushed = False
+        #: every wrapper finished: the ship thread may ship
+        self.ready = threading.Event()
+        #: shipped
         self.event = threading.Event()
 
     def defer(self, bucket, ship_fn, item) -> None:
@@ -301,8 +353,9 @@ class FlushGroup:
             ent[1].append(item)
 
     def after_flush(self, cb) -> None:
-        """Run ``cb`` once the group has shipped (immediately if it
-        already has)."""
+        """Run ``cb`` once the group has shipped and every callback
+        registered before it has run (immediately if that is so
+        already): callbacks run strictly in registration order."""
         with self._lock:
             if not self._flushed:
                 self._after.append(cb)
@@ -310,24 +363,19 @@ class FlushGroup:
         cb()
 
     def done(self) -> None:
-        """One per-key wrapper finished; the last one ships — after
-        the PREVIOUS flush's group shipped (cross-key wq interleaving
-        could otherwise reorder two flushes' sends to one peer). The
-        fence is NON-blocking: when the predecessor is still open,
-        the ship runs as its after-flush callback instead of parking
-        this wq worker on a wait (a blocked worker would serialize
-        unrelated PGs' continuations behind the fence)."""
+        """One per-key wrapper finished; after the last one the group
+        is ready to ship. Never blocks and never ships: the wq worker
+        goes back to its queue."""
         with self._lock:
             self._pending -= 1
             if self._pending > 0:
                 return
-        prev, self._prev_group = self._prev_group, None
-        if prev is not None:
-            prev.after_flush(self._ship)
-        else:
-            self._ship()
+        self.ready.set()
 
-    def _ship(self) -> None:
+    def ship(self) -> None:
+        """Ship everything deferred, then run the after-flush
+        callbacks (the engine's ship thread, once the group is
+        ready and its predecessor shipped)."""
         with self._lock:
             deferred = list(self._deferred.values())
             self._deferred = {}
@@ -336,15 +384,21 @@ class FlushGroup:
                 ship_fn(items)
             except Exception as exc:
                 log(0, f"flush-group ship failed: {exc!r}")
-        with self._lock:
-            self._flushed = True
-            after, self._after = self._after, []
         self.event.set()
-        for cb in after:
-            try:
-                cb()
-            except Exception as exc:
-                log(0, f"flush-group after-flush cb failed: {exc!r}")
+        while True:
+            # a callback registered while these run queues behind
+            # them instead of running at once on its caller's thread
+            with self._lock:
+                after, self._after = self._after, []
+                if not after:
+                    self._flushed = True
+                    return
+            for cb in after:
+                try:
+                    cb()
+                except Exception as exc:
+                    log(0, "flush-group after-flush cb failed: "
+                        f"{exc!r}")
 
 
 _group_tls = threading.local()
@@ -390,10 +444,17 @@ class DeviceEncodeEngine:
         #: CEPH_TPU_BULK_INGEST can A/B consecutive clusters
         self._bulk = bulk_ingest_enabled()
         self._stager = _ConcatStager() if self._bulk else None
-        #: flush-order chain: each retired flush's FlushGroup waits
-        #: for its predecessor's event before shipping
+        #: flush order: every retired flush's FlushGroup goes on
+        #: this queue as it is made, and the ship thread ships them
+        #: in that order, each once it is ready
+        self._ship_q: queue.SimpleQueue = queue.SimpleQueue()
         self._last_group: FlushGroup | None = None
-        self._last_group_event: threading.Event | None = None
+        #: dispatch key -> the group whose ship dispatches that key's
+        #: newest barrier: the key's continuations retired since then
+        #: are dispatched behind the barrier, not past it (written on
+        #: the launch thread with the window drained, read on the
+        #: retire thread)
+        self._barrier_group: dict = {}
         self._counters = counters
         # ISSUE 13: the four engine knobs resolve explicit-arg > env
         # > g_conf Option, and every UNPINNED one registers a config
@@ -453,6 +514,10 @@ class DeviceEncodeEngine:
         #: largest ops-per-launch seen — proof the batching engages
         self.stats = {"flushes": 0, "ops": 0, "bytes": 0,
                       "max_batch_ops": 0, "errors": 0,
+                      # ops retired in a flush that held ops of more
+                      # than one dispatch key (PG): how often the
+                      # program key lets PGs share a flush
+                      "cross_pg_ops": 0, "decode_cross_pg_ops": 0,
                       "decode_flushes": 0, "decode_ops": 0,
                       "decode_bytes": 0, "max_decode_batch_ops": 0,
                       "decode_errors": 0, "device_fused_fallbacks": 0,
@@ -498,6 +563,9 @@ class DeviceEncodeEngine:
             target=self._retire_run, name="ec-device-retire",
             daemon=True)
         self._retire_thread.start()
+        self._ship_thread = threading.Thread(
+            target=self._ship_run, name="ec-device-ship", daemon=True)
+        self._ship_thread.start()
         # runtime knob observers attach LAST (fully-built engine: the
         # window observer touches the inflight CV) — literal names so
         # the registry-drift lint can hold every tuner-managed knob
@@ -574,9 +642,11 @@ class DeviceEncodeEngine:
         by_key: dict = {}
         for key, fn in entries:
             by_key.setdefault(key, []).append(fn)
-        group = FlushGroup(len(by_key), self._last_group)
+        group = FlushGroup(len(by_key))
         self._last_group = group
-        self._last_group_event = group.event
+        # queued BEFORE its wrappers run: creation order (the retire
+        # thread alone makes groups) is ship order
+        self._ship_q.put(group)
 
         for key, fns in by_key.items():
             def run(fns=fns, group=group):
@@ -592,17 +662,56 @@ class DeviceEncodeEngine:
                     _group_tls.group = None
                     group.done()
             run._profile_stage = "commit_wait"
-            self._dispatch(key, run)
+            fence = self._barrier_group.get(key)
+            if fence is not None and fence._flushed:
+                del self._barrier_group[key]    # long dispatched
+                fence = None
+            if fence is None:
+                self._dispatch(key, run)
+            else:
+                # a barrier of this key waits for an earlier group's
+                # ship (or has been dispatched after it): keep the
+                # key's order, this wrapper goes behind the barrier
+                fence.after_flush(
+                    lambda key=key, run=run: self._dispatch(key, run))
 
-    def _after_last_group(self, cb) -> None:
-        """Run ``cb`` after the most recently dispatched flush group
-        has shipped (immediately when there is none) — the barrier
-        ordering point extended across deferred batch sends."""
+    def _ship_run(self) -> None:
+        """Ship retired flushes' groups strictly in flush order, on
+        this thread alone: a group ships when its last wrapper has
+        finished and every earlier group has shipped. On a profiler
+        trace a ship is ``flush_ship``; waiting for a group, or for
+        its wrappers, is ``ship_idle``."""
+        _prof.thread_role("engine_ship")
+        while True:
+            mark = _prof.push_stage("idle", span="ship_idle")
+            try:
+                group = self._ship_q.get()
+                if group is None:
+                    return
+                group.ready.wait()
+            finally:
+                _prof.pop_stage(mark)
+            mark = _prof.push_stage("commit_wait", span="flush_ship")
+            try:
+                group.ship()
+            finally:
+                _prof.pop_stage(mark)
+
+    def _dispatch_barrier(self, key, fn) -> None:
+        """Dispatch a barrier's ``fn`` on ``key`` after the most
+        recently dispatched flush group has shipped (at once when
+        there is none) — the barrier ordering point extended across
+        deferred batch sends. The ship thread may be groups behind,
+        so the key is fenced on that group: its continuations retired
+        from now on are dispatched behind the barrier
+        (:meth:`_dispatch_entries`), and per-key order stays
+        submission order however late the ship is."""
         group = self._last_group
         if group is not None and self._bulk:
-            group.after_flush(cb)
+            self._barrier_group[key] = group
+            group.after_flush(lambda: self._dispatch(key, fn))
         else:
-            cb()
+            self._dispatch(key, fn)
 
     # -- producer side (op-shard threads) -----------------------------
     @staticmethod
@@ -651,19 +760,24 @@ class DeviceEncodeEngine:
         # placement weighting.
         pslot = _placement_slot(key)
         _telemetry().note_slot_staged(pslot, data.nbytes)
+        # the key under which this op meets others in a flush,
+        # computed HERE and carried on the queue: the stager's buffer
+        # and the engine's batch are found by the same value
+        gkey = (program_key(codec, sinfo), pslot)
         if self._stager is not None:
-            # zero-copy staging: the payload lands in the signature's
-            # concat buffer NOW, on this producer thread; the engine
-            # flush takes one contiguous view. The queue put rides the
-            # stager lock so per-signature slot order == queue order.
+            # zero-copy staging: the payload lands in its program
+            # key's concat buffer NOW, on this producer thread; the
+            # engine flush takes one contiguous view. The queue put
+            # rides the stager lock so per-key slot order == queue
+            # order.
             ref = _StagedRef(data.nbytes)
             with self._stager.lock:
-                self._stager.append_locked(codec, pslot, data)
+                self._stager.append_locked(gkey, data)
                 self._q.put(("enc", key, codec, sinfo, ref, cont,
-                             span, clock, _time.monotonic(), pslot))
+                             span, clock, _time.monotonic(), gkey))
             return
         self._q.put(("enc", key, codec, sinfo, data, cont, span,
-                     clock, _time.monotonic(), pslot))
+                     clock, _time.monotonic(), gkey))
 
     def stage_barrier(self, key, fn: Callable[[], None]) -> None:
         """Queue an ordering barrier: ``fn`` dispatches on ``key``
@@ -685,8 +799,10 @@ class DeviceEncodeEngine:
         self._note_staged_flow(cont, _shards_nbytes(shards))
         pslot = _placement_slot(key)
         _telemetry().note_slot_staged(pslot, _shards_nbytes(shards))
+        sig = (program_key(codec, sinfo), tuple(sorted(shards)),
+               tuple(sorted(want)), pslot)
         self._q.put(("dec", key, codec, sinfo, shards, want, cont,
-                     span, clock, _time.monotonic(), pslot))
+                     span, clock, _time.monotonic(), sig))
 
     def decode_sync(self, key, codec, sinfo: ec_util.StripeInfo,
                     shards: dict[int, np.ndarray], want: list[int],
@@ -751,14 +867,15 @@ class DeviceEncodeEngine:
             self._retire_stop = True
             self._ifcv.notify_all()
         self._retire_thread.join(timeout=10)
-        # shutdown drain, batched edition: the engine thread has
-        # DISPATCHED every continuation wrapper, but the last flush
-        # group ships its deferred sub-write batches on an op-wq
-        # worker — wait for that ship so nothing chained behind it
-        # (barriers, local txn groups) is dropped by a wq that stops
-        # right after us
-        ev = self._last_group_event
-        if ev is not None and not ev.wait(10):
+        # shutdown drain, batched edition: the retire thread has
+        # DISPATCHED every continuation wrapper and queued every
+        # flush group; the ship thread ships them as the wrappers
+        # finish on the op-wq — wait for the last ship so nothing
+        # chained behind it (barriers, local txn groups) is dropped
+        # by a wq that stops right after us
+        self._ship_q.put(None)
+        self._ship_thread.join(timeout=10)
+        if self._ship_thread.is_alive():
             log(1, "engine stop: last flush group never shipped")
 
     # -- retire thread ------------------------------------------------
@@ -804,11 +921,15 @@ class DeviceEncodeEngine:
             if item is None:
                 self._drain_inflight()
                 return
-            # (id(codec), placement slot) -> (codec, sinfo, slot,
-            # items) — slot-keyed (ISSUE 12) so each stripe row's
-            # flush launches on its owning submesh
+            # (program_key, placement slot) -> (codec, sinfo, slot,
+            # items): ops of every PG with an equal program key meet
+            # in one flush, which runs with the FIRST op's codec and
+            # sinfo objects (interchangeable by construction of the
+            # key) — slot-keyed (ISSUE 12) so each stripe row's flush
+            # launches on its owning submesh
             pending: dict[tuple, tuple] = {}
-            # (id(codec), present, want, slot) -> state
+            # (program_key, present, want, slot) -> state: a decode
+            # flush shares one decode matrix
             dec_pending: dict[tuple, tuple] = {}
             nbytes = 0
             while True:
@@ -819,13 +940,13 @@ class DeviceEncodeEngine:
                     return
                 if item[0] == "enc":
                     (_, key, codec, sinfo, data, cont, span, clock,
-                     ts, pslot) = item
+                     ts, gkey) = item
                     # handoff seam (ISSUE 17): producer put -> engine
                     # thread pickup, one cross-thread hop per stage
                     _dsp.telemetry().note_handoff(
                         "engine_stage", _time.monotonic() - ts)
                     _, _, _, items = pending.setdefault(
-                        (id(codec), pslot), (codec, sinfo, pslot, []))
+                        gkey, (codec, sinfo, gkey[1], []))
                     items.append((key, data, cont, span, clock, ts))
                     nbytes += data.nbytes
                     if nbytes >= self._flush_bytes:
@@ -838,14 +959,11 @@ class DeviceEncodeEngine:
                         pending, dec_pending, nbytes = {}, {}, 0
                 elif item[0] == "dec":
                     (_, key, codec, sinfo, shards, want, cont, span,
-                     clock, ts, pslot) = item
+                     clock, ts, sig) = item
                     _dsp.telemetry().note_handoff(
                         "engine_stage", _time.monotonic() - ts)
-                    sig = (id(codec),
-                           tuple(sorted(shards)), tuple(sorted(want)),
-                           pslot)
                     _, _, _, items = dec_pending.setdefault(
-                        sig, (codec, sinfo, pslot, []))
+                        sig, (codec, sinfo, sig[3], []))
                     items.append((key, shards, want, cont, span,
                                   clock, ts))
                     nbytes += sum(np.asarray(v).nbytes
@@ -884,9 +1002,7 @@ class DeviceEncodeEngine:
                     # deferred batch sends: a barrier's own fan-out
                     # (remove/RMW) must not beat the older writes'
                     # batched sub-writes to the shards
-                    self._after_last_group(
-                        lambda key=key, fn=fn:
-                        self._dispatch(key, fn))
+                    self._dispatch_barrier(key, fn)
                 try:
                     item = self._q.get_nowait()
                 except queue.Empty:
@@ -905,7 +1021,7 @@ class DeviceEncodeEngine:
             # flag here raced the idle drain and dropped them)
 
     def _flush(self, pending: dict) -> None:
-        for codec, sinfo, pslot, items in pending.values():
+        for gkey, (codec, sinfo, pslot, items) in pending.items():
             # profiler join: while the engine thread stages/launches,
             # a sample of it belongs to the op's engine_stage_wait
             # interval. On a profiler trace the group's host work is
@@ -916,22 +1032,21 @@ class DeviceEncodeEngine:
                 ops=len(items),
                 bytes=sum(it[1].nbytes for it in items))
             try:
-                self._flush_group(codec, sinfo, pslot, items)
+                self._flush_group(gkey, codec, sinfo, pslot, items)
             finally:
                 _prof.pop_stage(mark)
         pending.clear()
 
-    def _flush_group(self, codec, sinfo, pslot, items) -> None:
+    def _flush_group(self, gkey, codec, sinfo, pslot, items) -> None:
         import time as _time
         from ceph_tpu.parallel import mesh as mesh_mod
         from ceph_tpu.parallel import placement as _placement
         if self._stager is not None:
             # zero-copy staging: the payloads are already
-            # contiguous in the signature's concat buffer —
-            # detach the consumed prefix as one view (no
-            # flush-time np.concatenate on this thread)
-            batch, views = self._stager.take(codec, pslot,
-                                             len(items))
+            # contiguous in the key's concat buffer — detach the
+            # consumed prefix as one view (no flush-time
+            # np.concatenate on this thread)
+            batch, views = self._stager.take(gkey, len(items))
             nbytes = batch.nbytes
         else:
             batch = None
@@ -1094,8 +1209,8 @@ class DeviceEncodeEngine:
     def _retire_one(self, entry) -> None:
         """Harvest one in-flight batch (download + dispatch its
         continuations). Runs on the retire thread only — it is the
-        sole creator of FlushGroups, so group chaining is
-        single-writer. On a profiler trace the harvest is
+        sole creator of FlushGroups, so the ship queue's order is
+        flush order. On a profiler trace the harvest is
         ``flush_dispatch``, with the blocking ``flush_download``
         (marked in ``finalize``) nested inside it."""
         (items, _finalize, _kspans, nbytes) = entry
@@ -1138,6 +1253,8 @@ class DeviceEncodeEngine:
             done_t = _time.monotonic()
             self.stats["flushes"] += 1
             self.stats["ops"] += len(items)
+            if _spans_keys(items):
+                self.stats["cross_pg_ops"] += len(items)
             self.stats["bytes"] += nbytes
             ft = _flows.flows_if_active()
             if ft is not None:
@@ -1297,6 +1414,8 @@ class DeviceEncodeEngine:
             nbytes = sum(ln * len(present) for ln in lens)
             self.stats["decode_flushes"] += 1
             self.stats["decode_ops"] += len(items)
+            if _spans_keys(items):
+                self.stats["decode_cross_pg_ops"] += len(items)
             self.stats["decode_bytes"] += nbytes
             self.stats["max_decode_batch_ops"] = max(
                 self.stats["max_decode_batch_ops"], len(items))
@@ -1328,6 +1447,13 @@ def _first_trace_id(items, span_idx: int) -> str | None:
         if tid:
             return tid
     return None
+
+
+def _spans_keys(items) -> bool:
+    """Whether a flush's items (dispatch key first) are of more than
+    one dispatch key, i.e. of more than one PG."""
+    first = items[0][0]
+    return any(it[0] != first for it in items)
 
 
 def _shards_nbytes(shards: dict) -> int:

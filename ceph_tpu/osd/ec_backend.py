@@ -318,9 +318,9 @@ class ECBackend(PGBackend):
                 commit_cb = (lambda p=pos:
                              iw.complete(p) and iw.on_all_commit())
                 if group is not None:
-                    # the group ships from whichever thread finishes
-                    # last, with no tenant context — stamp the flow on
-                    # the txn so the ship-time store attribution keeps
+                    # the group ships on the engine's ship thread,
+                    # with no tenant context — stamp the flow on the
+                    # txn so the ship-time store attribution keeps
                     # per-item labels (ISSUE 20)
                     txn._flow = _flows.current_flow() or ""
                     group.defer((id(self.parent), "local"),

@@ -280,7 +280,7 @@ class StripeBatcher:
         self._pending_bytes = 0
         #: zero-copy staging (ISSUE 9): when the appended buffers are
         #: adjacent views into ONE contiguous array (the engine's
-        #: per-signature concat buffer, filled at stage time), the
+        #: concat buffer of one program key, filled at stage time), the
         #: caller hands that array here and flush skips its own
         #: np.concatenate — the flush-time copy the old path paid
         self._preconcat: np.ndarray | None = None
