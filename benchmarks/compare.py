@@ -4,22 +4,27 @@ plain reference, every number beside its limit.
 The comparisons are exact, so every limit is 0 (or, for "the decode
 path was used at all", a least count of 1). What is compared:
 
-- ``ops_failed``: ops of the window that raised or timed out;
-- ``reads_unequal``: reads of the window whose bytes differ from the
-  seeded bytes (the degraded cell: every read of the window);
-- ``readback_unequal``: sampled objects the window wrote, read back
-  through the client after the window, that differ from the seeded
-  bytes;
+- the window kind's own rows (``windows/<op>.py``, ``judge_ops``). A
+  closed loop: ``ops_failed``, ops of the window that raised or timed
+  out; ``reads_unequal``, reads of the window whose bytes differ from
+  the seeded bytes (the degraded cell: every read of the window). A
+  recovery: the shards rebuilt, those that are not there, and how many
+  of the rebuilt ones the sample compared;
+- ``readback_unequal``: sampled objects (those the window wrote, or
+  the preloaded ones), read back through the client after the window,
+  that differ from the seeded bytes;
 - ``shards_unequal`` / ``crcs_unequal``: shards of the sampled objects
   as the OSD stores hold them, and the crc each shard's ``hinfo``
-  holds, that differ from ``reference.encode`` / ``reference.crc32c``;
-  ``shards_missing``: shards of a sampled object that no live OSD
-  holds, beyond those on the OSDs the mix killed;
+  holds, that differ from ``shards(data, pool)`` of the
+  configuration's own reference module / ``reference.crc32c``, which
+  no codec changes; ``shards_missing``: shards of a sampled object that
+  no live OSD holds, beyond those the window kind excuses;
 - the route: growth over the window of the engine's ``host_flushes``,
   ``device_fused_fallbacks``, ``errors``, ``decode_errors``, programs
-  compiled inside the window, primaries without a device engine, and in
-  a degraded cell at least one decode flush. A run that left the device
-  path measured something else.
+  compiled inside the window, primaries without a device engine, and
+  what the kind adds (``judge_route``): at least one encode or decode
+  flush, no reconstruct that fell back to the host. A run that left
+  the device path measured something else.
 """
 
 from __future__ import annotations
@@ -43,12 +48,12 @@ def sample_names(names: list[str], count: int, seed_words: list[int]
 
 
 def compare_objects(observed: list[dict], payload_of, pool: dict,
-                    shards_absent_ok: int = 0) -> dict:
+                    absent_ok=None, ref=reference) -> dict:
     """Counts of what differs between ``observed`` (see
-    ``Served.observe``) and the reference's encode of each object's
-    seeded bytes."""
-    k, m, unit = pool["k"], pool["m"], pool["stripe_unit"]
-    matrix = reference.coding_matrix(k, m)
+    ``Served.observe``) and the stored shards of each object's seeded
+    bytes as ``ref``, the configuration's reference module, states
+    them for the configuration's whole ``pool``. ``absent_ok(obs)``:
+    how many of the object's shards the window kind excuses."""
     out = {"readback_unequal": 0, "shards_unequal": 0,
            "crcs_unequal": 0, "shards_missing": 0}
     examples = []
@@ -57,9 +62,10 @@ def compare_objects(observed: list[dict], payload_of, pool: dict,
         if obs["read_back"] is not None and obs["read_back"] != data:
             out["readback_unequal"] += 1
             examples.append(f"{obs['name']}: read-back differs")
-        want = reference.encode(data, k, m, unit, matrix=matrix)
-        absent = k + m - len(obs["shards"])
-        out["shards_missing"] += max(0, absent - shards_absent_ok)
+        want = ref.shards(data, pool)
+        absent = len(want) - len(obs["shards"])
+        out["shards_missing"] += max(
+            0, absent - (absent_ok(obs) if absent_ok else 0))
         for pos, got in obs["shards"].items():
             if bytes(got) != want[pos].tobytes():
                 out["shards_unequal"] += 1
@@ -96,7 +102,8 @@ class Compared:
         return {name: {"value": value, "limit": limit, "rule": rule}
                 for name, value, limit, rule in self.rows}
 
-    def print_last(self, file=sys.stderr) -> None:
+    def print_last(self, file=None) -> None:
+        file = file or sys.stderr       # as it is now, not at import
         for name, value, limit, rule in self.rows:
             ok = value <= limit if rule == "<=" else value >= limit
             print(f"compared {name}: {value} (limit {rule} {limit})"
@@ -105,29 +112,25 @@ class Compared:
         file.flush()
 
 
-def judge(loop_summary: dict, ops: list, objects: dict, window: dict,
-          primaries: tuple[int, int], degraded: bool) -> Compared:
-    """Put every number beside its limit."""
+def judge(window, summary: dict, ops: list, observed: list,
+          objects: dict, grown: dict, primaries: tuple[int, int]
+          ) -> Compared:
+    """Put every number beside its limit: the window kind's own
+    (``window.judge_ops``), what the stores hold, the route, and what
+    the kind asks of the route (``window.judge_route``)."""
     cmp = Compared()
-    cmp.at_most("ops_failed", loop_summary["failed"])
-    cmp.at_least("ops_acknowledged",
-                 loop_summary["attempted"] - loop_summary["failed"], 1)
-    cmp.at_most("reads_unequal",
-                sum(1 for r in ops if r.equal is False))
+    window.judge_ops(cmp, summary, ops, observed)
     for key in ("readback_unequal", "shards_unequal", "crcs_unequal",
                 "shards_missing"):
         cmp.at_most(key, objects[key])
-    eng = window["engine"]
+    eng = grown["engine"]
     cmp.at_most("host_flushes", eng.get("host_flushes", 0))
     cmp.at_most("fused_fallbacks", eng.get("device_fused_fallbacks", 0))
     cmp.at_most("engine_errors", eng.get("errors", 0))
     cmp.at_most("decode_errors", eng.get("decode_errors", 0))
-    cmp.at_most("compiled_in_window", window["compiles"])
+    cmp.at_most("compiled_in_window", grown["compiles"])
     seen, missing = primaries
     cmp.at_least("primaries_seen", seen, 1)
     cmp.at_most("primaries_without_device", missing)
-    if degraded:
-        cmp.at_least("decode_flushes", eng.get("decode_flushes", 0), 1)
-    else:
-        cmp.at_least("encode_flushes", eng.get("flushes", 0), 1)
+    window.judge_route(cmp, grown)
     return cmp

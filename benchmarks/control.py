@@ -15,7 +15,12 @@ size, k, m and sample:
   ``hinfo`` is not that of the shard (the seed is stored instead);
 - a degraded cell, ``not_reconstructed``: a read with a data shard lost
   returns the surviving chunks with zeros where the lost one was, an
-  approximate answer where it was exact.
+  approximate answer where it was exact;
+- a recovery cell, ``rebuilt_by_xor``: the shard rebuilt onto the spare
+  is the XOR of the k shards read (a single-parity rebuild, one pass
+  and no matrix) and not the code's reconstruct, with the crc the
+  push carries over from ``hinfo``: the pool reports clean and holds
+  a wrong shard, so it survives one failure fewer than it says.
 
 Each control's observations go through ``compare.compare_objects`` /
 ``compare.judge``, the same functions a run uses, and every control has
@@ -47,25 +52,29 @@ import spec                     # noqa: E402
 from loadgen import OpRecord, Payloads, seed_words  # noqa: E402
 
 
-def _clean_window(n_ops: int, degraded: bool) -> tuple[dict, dict]:
+def _clean_window(n_ops: int, decodes: bool) -> tuple[dict, dict]:
     """A window in which every op was acknowledged and the route
     counters stayed clean: the control breaks the data, nothing
     else."""
-    summary = {"attempted": n_ops, "failed": 0}
-    engine = {"flushes": 0 if degraded else n_ops,
-              "decode_flushes": n_ops if degraded else 0}
-    return summary, {"engine": engine, "compiles": 0}
+    summary = {"attempted": n_ops, "failed": 0, "window_s": 1.0,
+               "rebuilt_shards": n_ops}
+    engine = {"flushes": 0 if decodes else n_ops,
+              "decode_flushes": n_ops if decodes else 0}
+    return summary, {"engine": engine, "compiles": 0,
+                     "decode_fallbacks": 0}
 
 
 def observe_control(control: str, names: list[str], payloads: Payloads,
-                    pool: dict) -> list[dict]:
+                    pool: dict, ref=reference,
+                    rebuilt: dict | None = None) -> list[dict]:
     """What ``Served.observe`` would return from a system that breaks
-    the guarantee ``control`` names."""
-    k, m, unit = pool["k"], pool["m"], pool["stripe_unit"]
+    the guarantee ``control`` names. ``rebuilt``: object -> the
+    position recovery rebuilt."""
+    k, m = pool["k"], pool["m"]
     out = []
     for name in names:
         data = payloads.of(name)
-        shards = reference.encode(data, k, m, unit)
+        shards = ref.shards(data, pool)
         crcs = reference.shard_crcs(shards)
         if control == "one_parity_short":
             shards[k + m - 1] = shards[k + m - 2].copy() if m > 1 \
@@ -73,8 +82,12 @@ def observe_control(control: str, names: list[str], payloads: Payloads,
             crcs = reference.shard_crcs(shards)
         elif control == "crc_not_kept":
             crcs = [reference.HINFO_SEED] * (k + m)
+        elif control == "rebuilt_by_xor":
+            lost = rebuilt[name]
+            read = [s for pos, s in enumerate(shards) if pos != lost]
+            shards[lost] = np.bitwise_xor.reduce(read[:k], axis=0)
         else:
-            raise ValueError(f"no write control {control!r}")
+            raise ValueError(f"no control {control!r}")
         out.append({"name": name, "read_back": data,
                     "shards": {i: s.tobytes()
                                for i, s in enumerate(shards)},
@@ -102,33 +115,50 @@ def read_control(names: list[str], payloads: Payloads, pool: dict,
 
 
 def run_controls(cell: spec.Cell, seed: int) -> list[dict]:
-    mix, pool = cell.traffic, cell.config["pool"]
-    degraded = mix["osds_down"] > 0
+    mix, pool, ref = cell.traffic, cell.config["pool"], cell.reference
+    window = cell.window(None, mix, seed)
     payloads = Payloads(seed, mix["object_bytes"], mix["payload_pool"])
     rng = np.random.default_rng(seed_words(seed) + [9])
     results = []
-    if degraded:
+    if mix["op"] == "recover":
+        names = sorted({f"obj_{int(i)}" for i in rng.integers(
+            mix["preload_objects"], size=mix["check_sample"])})
+        # one victim: an object's PG rebuilt one position, any of k+m
+        lost = {name: int(rng.integers(pool["k"] + pool["m"]))
+                for name in names}
+        observed = observe_control("rebuilt_by_xor", names, payloads,
+                                   pool, ref, rebuilt=lost)
+        objects = compare.compare_objects(observed, payloads.of, pool,
+                                          ref=ref)
+        summary, grown = _clean_window(len(names), True)
+        summary["rebuilt_positions"] = {n: [p] for n, p in lost.items()}
+        cmp = compare.judge(window, summary, [], observed, objects,
+                            grown, (1, 0))
+        results.append(("rebuilt_by_xor", cmp))
+    elif window.degraded:
         names = [f"obj_{int(i)}" for i in rng.integers(
             mix["preload_objects"], size=mix["check_sample"])]
         # one data position: what a PG is left with once the spare
         # OSD has taken over the other (``Served.kill_osds``)
         lost = [int(rng.integers(pool["k"]))]
         ops = read_control(names, payloads, pool, lost)
-        clean = compare.compare_objects([], payloads.of, pool)
-        summary, window = _clean_window(len(ops), True)
-        cmp = compare.judge(summary, ops, clean, window, (1, 0), True)
+        clean = compare.compare_objects([], payloads.of, pool, ref=ref)
+        summary, grown = _clean_window(len(ops), True)
+        cmp = compare.judge(window, summary, ops, [], clean, grown,
+                            (1, 0))
         results.append(("not_reconstructed", cmp))
     else:
         names = [f"w{int(t)}_{int(i)}" for t, i in zip(
             rng.integers(mix["clients"], size=mix["check_sample"]),
             rng.integers(1000, size=mix["check_sample"]))]
         for control in ("one_parity_short", "crc_not_kept"):
-            objects = compare.compare_objects(
-                observe_control(control, names, payloads, pool),
-                payloads.of, pool)
-            summary, window = _clean_window(len(names), False)
-            cmp = compare.judge(summary, [], objects, window, (1, 0),
-                                False)
+            observed = observe_control(control, names, payloads, pool,
+                                       ref)
+            objects = compare.compare_objects(observed, payloads.of,
+                                              pool, ref=ref)
+            summary, grown = _clean_window(len(names), False)
+            cmp = compare.judge(window, summary, [], observed, objects,
+                                grown, (1, 0))
             results.append((control, cmp))
     out = []
     for control, cmp in results:

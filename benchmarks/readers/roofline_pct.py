@@ -13,8 +13,7 @@ def read(ctx: dict, work: str, ops_counter: str) -> float | None:
     ops = ctx["engine_traced"].get(ops_counter, 0)
     if ops <= 0:
         return None
-    pool, mix = ctx["config"]["pool"], ctx["traffic"]
-    nbytes = WORK[work](ops, mix["object_bytes"], pool["k"], pool["m"],
-                        pool["stripe_unit"])
+    nbytes = WORK[work](ops, ctx["traffic"]["object_bytes"],
+                        ctx["config"]["pool"], ctx.get("reference"))
     least_s = nbytes / ctx["peaks"]["hbm_bytes_per_s"]
     return 100.0 * least_s / trace["busy_s"]

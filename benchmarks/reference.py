@@ -127,6 +127,20 @@ def encode(data: bytes, k: int, m: int, stripe_unit: int,
     return shards
 
 
+_MATRICES: dict[tuple[int, int], list[list[int]]] = {}
+
+
+def shards(data: bytes, pool: dict) -> list[np.ndarray]:
+    """The k+m shards an object's bytes are stored as in ``pool`` (a
+    configuration's whole ``pool`` object): what every reference
+    module states, here for ``technique=reed_sol_van``."""
+    k, m = pool["k"], pool["m"]
+    if (k, m) not in _MATRICES:
+        _MATRICES[k, m] = coding_matrix(k, m)
+    return encode(data, k, m, pool["stripe_unit"],
+                  matrix=_MATRICES[k, m])
+
+
 # -- crc32c -------------------------------------------------------------
 
 def _crc_table() -> np.ndarray:

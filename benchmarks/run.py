@@ -7,9 +7,11 @@
 A new process per run: brings the cell's configuration up (mon + OSDs +
 clients in this process, one EC pool, every OSD on the one device
 engine), warms every flush bucket the cell's traffic can meet, runs the
-closed loop for ``--seconds``, compares what the window itself wrote or
-read with the plain reference, and prints ONE JSON object as the last
-line of standard output:
+window the traffic's ``op`` names (``windows/<op>.py``: a closed loop
+for ``--seconds``, or a recovery from the down mark until clean),
+compares what the window itself wrote, read or rebuilt with the
+configuration's plain reference, and prints ONE JSON object as the
+last line of standard output:
 
     {"correct", "attempted", "failed", "metrics", "device",
      ["breakdown",] "compared"}
@@ -47,7 +49,7 @@ for _p in (ROOT, BENCH_DIR):
 import compare                  # noqa: E402
 import spec                     # noqa: E402
 import trace_reduce             # noqa: E402
-from loadgen import ClosedLoop, quantile, seed_words  # noqa: E402
+from loadgen import quantile, seed_words  # noqa: E402
 
 #: the traced sub-window of a ``--trace 1`` run: it starts this long
 #: after the window and lasts this long (less in a short window)
@@ -161,20 +163,6 @@ class Tracer:
         return trace_reduce.reduce(profile, self.window_s)
 
 
-def end_to_end_values(mix: dict, summary: dict, setup_s: float) -> dict:
-    """The end-to-end readings of one window, under the names the
-    traffic file gives them. All acknowledged bytes over all the
-    window's seconds; the tail over every op that was acknowledged (a
-    failed op makes the run not correct)."""
-    reports = mix["reports"]
-    values = {"setup_s": setup_s,
-              reports["throughput"]: summary["MBps"]}
-    if summary["latencies_ms"]:
-        values[reports["tail"]["name"]] = quantile(
-            summary["latencies_ms"], reports["tail"]["quantile"])
-    return values
-
-
 def latency_profile(sorted_ms: list[float]) -> dict:
     """For the reader of standard error: where the tail sits."""
     if not sorted_ms:
@@ -225,8 +213,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: int,
     the result line and the numbers compared."""
     from served import Served
     mix, config = cell.traffic, cell.config
-    degraded = mix["osds_down"] > 0
     served = Served(config, mix, seed)
+    window = cell.window(served, mix, seed)
     note(cell=cell.name, seed=seed, seconds=seconds, trace=trace,
          device=device)
     try:
@@ -239,17 +227,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: int,
         if mix["preload_objects"]:
             served.preload()
             note(phase="preload", objects=len(served.preloaded))
-        if degraded:
-            served.kill_osds()
-            note(phase="osds_down_and_settled", victims=served.victims)
-            served.warm_degraded_reads()
-            note(phase="warm_reads", compiles=served.compiles(),
-                 compile_s=served.compile_seconds(),
-                 objects_by_lost_data_shards=served.degraded_objects)
-        loop = ClosedLoop(
-            served.io, mix, served.payloads, seed,
-            read_names=served.intact if degraded else served.preloaded,
-            degraded_names=served.reconstructing)
+        # what the window kind needs from set-up
+        window.prepare(note)
         tracer = None
         if trace:
             tracer = Tracer(served, os.path.join(
@@ -257,39 +236,33 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: int,
         before = served.snapshot()
         setup_s = time.monotonic() - _T0
         note(phase="window", setup_s=round(setup_s, 2))
-        loop.run(seconds, during=tracer)
-        window = served.growth(before, served.snapshot())
+        summary, ops = window.run(seconds, during=tracer)
+        grown = served.growth(before, served.snapshot())
         peak = memory_peak_bytes()
-        summary = loop.summary()
-        if loop.overflow:
-            raise RuntimeError(
-                f"the window wrote max_objects = {mix['max_objects']} "
-                "objects: the traffic file's bound on host memory; a "
-                "benchmark PR has to raise it")
         note(phase="window_done", attempted=summary["attempted"],
              failed=summary["failed"], MBps=round(summary["MBps"], 2),
              window_s=round(summary["window_s"], 2),
              latency_ms=latency_profile(summary["latencies_ms"]),
-             engine=window["engine"], compiles=window["compiles"],
-             errors=summary["errors"])
+             engine=grown["engine"], compiles=grown["compiles"],
+             compiled=grown["compiled"], errors=summary["errors"],
+             **{key: val for key, val in summary.items()
+                if key not in ("attempted", "failed", "MBps",
+                               "window_s", "latencies_ms", "errors")
+                and isinstance(val, (int, float, list))})
         primaries = served.primaries_without_device()
-        ops = loop.ops()
-        if degraded:
-            pool_names = served.preloaded
-        else:
-            pool_names = [r.name for r in ops if r.ok]
-        sample = compare.sample_names(pool_names, mix["check_sample"],
+        sample = compare.sample_names(window.check_names,
+                                      mix["check_sample"],
                                       seed_words(seed))
-        observed = served.observe(sample, read_back=not degraded)
+        observed = served.observe(sample, read_back=window.read_back)
         note(phase="observed", objects=len(observed))
     finally:
         # the program's state is freed before the reference runs
         served.stop()
     objects = compare.compare_objects(
         observed, served.payloads.of, config["pool"],
-        shards_absent_ok=len(served.victims))
-    compared = compare.judge(summary, ops, objects, window, primaries,
-                             degraded)
+        absent_ok=window.absent_ok, ref=cell.reference)
+    compared = compare.judge(window, summary, ops, observed, objects,
+                             grown, primaries)
     if objects["examples"]:
         note(unequal=objects["examples"])
     device = dict(device, memory_peak_bytes=peak)
@@ -300,17 +273,18 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: int,
         device["window_s"] = reduced["window_s"]
         breakdown = {"device_ops": reduced["device_ops"],
                      "idle_gaps": reduced["idle_gaps"]}
-        ctx = {"stages": window["stages"],
-               "engine_window": window["engine"],
+        ctx = {"stages": grown["stages"],
+               "engine_window": grown["engine"],
                "engine_traced": tracer.engine, "trace": reduced,
                "peaks": spec.peaks(device["kind"], cell.bench_dir),
-               "config": config, "traffic": mix, "loop": summary}
+               "config": config, "reference": cell.reference,
+               "traffic": mix, "loop": summary}
         values = layer_values(cell, ctx)
         note(phase="traced", busy_s=reduced["busy_s"],
              window_s=reduced["window_s"], events=reduced["events"],
              engine_traced=tracer.engine)
     else:
-        values = end_to_end_values(mix, summary, setup_s)
+        values = dict(window.values(summary), setup_s=setup_s)
     return result_line(cell, trace, compared, summary, values, device,
                        breakdown), compared
 

@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+import spec
 from loadgen import Payloads, seed_words
 
 POOL = "bench"
@@ -40,7 +41,9 @@ class Served:
         self.payloads: Payloads | None = None
         self.preloaded: list[str] = []
         self.victims: list[int] = []
-        self._grace = None
+        #: configuration options as they were before ``start`` and
+        #: ``set_options`` changed them
+        self._conf_before: dict = {}
         #: lost data shards -> preloaded objects that lack so many
         self.degraded_objects: dict[int, int] = {}
         #: preloaded objects that lack a data shard / lack none
@@ -54,16 +57,13 @@ class Served:
         dep, pool = self.config["deployment"], self.pool
         if dep["store"] != "memstore":
             raise ValueError("only memstore deployments fit a run")
-        conf = g_conf()
-        self._grace = conf["osd_heartbeat_grace"]
-        conf.set("osd_heartbeat_grace",
-                 float(dep["osd_heartbeat_grace"]))
+        self.set_options(
+            osd_heartbeat_grace=float(dep["osd_heartbeat_grace"]))
         self.cluster = MiniCluster(n_osds=dep["n_osds"],
                                    store=dep["store"]).start()
-        self.cluster.create_ec_pool(
-            POOL, k=pool["k"], m=pool["m"], plugin=pool["plugin"],
-            pg_num=pool["pg_num"], backend=pool["backend"],
-            technique=pool["technique"])
+        # the whole erasure-code profile, as the file states it
+        self.cluster.create_ec_pool(POOL, pg_num=pool["pg_num"],
+                                    **spec.ec_profile(pool))
         self.rados = self.cluster.client()
         self.io = self.rados.open_ioctx(POOL)
         self.io.op_timeout = self.mix["op_timeout_s"]
@@ -75,6 +75,15 @@ class Served:
                 f"the pool's stripe_unit is {unit}, the configuration "
                 f"states {pool['stripe_unit']}")
 
+    def set_options(self, **options) -> None:
+        """Set options of the program's configuration; ``stop`` puts
+        back what they were before the first change."""
+        from ceph_tpu.utils.config import g_conf
+        conf = g_conf()
+        for name, value in options.items():
+            self._conf_before.setdefault(name, conf[name])
+            conf.set(name, value)
+
     def stop(self) -> None:
         """Stop and join every daemon; frees the stores."""
         from ceph_tpu.utils.config import g_conf
@@ -83,8 +92,9 @@ class Served:
                 self.cluster.stop()
         finally:
             self.cluster = None
-            if self._grace is not None:
-                g_conf().set("osd_heartbeat_grace", self._grace)
+            for name, value in self._conf_before.items():
+                g_conf().set(name, value)
+            self._conf_before = {}
 
     # -- views of the program's counters ----------------------------------
     def _engine(self):
@@ -103,11 +113,15 @@ class Served:
                 if isinstance(v, (int, float))}
 
     @staticmethod
-    def compiles() -> int:
-        """Programs compiled by this process so far."""
+    def compiles_by_signature() -> dict[str, int]:
         from ceph_tpu.utils.device_telemetry import telemetry
         table = telemetry().snapshot()["compiles_by_signature"]
-        return sum(ent["compiles"] for ent in table.values())
+        return {sig: ent["compiles"] for sig, ent in table.items()}
+
+    @classmethod
+    def compiles(cls) -> int:
+        """Programs compiled by this process so far."""
+        return sum(cls.compiles_by_signature().values())
 
     @staticmethod
     def compile_seconds() -> float:
@@ -128,9 +142,18 @@ class Served:
                                             int(ent["avgcount"]))
         return out
 
+    @staticmethod
+    def decode_fallbacks() -> int:
+        """Reconstructs that left the engine for the host codec."""
+        from ceph_tpu.utils.device_telemetry import telemetry
+        return int(telemetry().perf.get("engine_decode_fallbacks"))
+
     def snapshot(self) -> dict:
+        by_signature = self.compiles_by_signature()
         return {"engine": self.engine_stats(),
-                "compiles": self.compiles(),
+                "compiles": sum(by_signature.values()),
+                "compiled": by_signature,
+                "decode_fallbacks": self.decode_fallbacks(),
                 "stages": self.stage_sums()}
 
     @staticmethod
@@ -142,7 +165,12 @@ class Served:
             s0, c0 = before["stages"].get(stage, (0.0, 0))
             stages[stage] = {"sum_s": s1 - s0, "count": c1 - c0}
         return {"engine": eng, "stages": stages,
-                "compiles": after["compiles"] - before["compiles"]}
+                "compiles": after["compiles"] - before["compiles"],
+                "compiled": sorted(
+                    sig for sig, n in after["compiled"].items()
+                    if n > before["compiled"].get(sig, 0)),
+                "decode_fallbacks": after["decode_fallbacks"]
+                - before["decode_fallbacks"]}
 
     def primaries_without_device(self) -> tuple[int, int]:
         """(primaries looked at, those whose ECBackend has no device
@@ -288,51 +316,89 @@ class Served:
             list(pool.map(self._write, names))
         self.preloaded = names
 
-    def kill_osds(self) -> None:
-        """Kill ``osds_down`` OSDs chosen from the seed, wait until the
-        map marks them down (there is no ``osd down`` command: the
-        wait is the heartbeat grace), then until the recovery that can
-        happen has happened. The program's map leaves a down OSD out of
-        CRUSH at once, so a spare OSD takes over a lost position and
-        recovery starts with the new map; reads racing it would be
-        measured at whatever share of it the window caught. The window
-        starts from the state that lasts: the OSDs down, the spares
-        filled, every other lost position a hole."""
-        n = self.mix["osds_down"]
-        rng = np.random.default_rng(seed_words(self.seed) + [5])
-        self.victims = sorted(int(v) for v in rng.choice(
-            self.config["deployment"]["n_osds"], size=n, replace=False))
+    def draw_osds(self, n: int, salt: int = 5) -> list[int]:
+        """``n`` distinct OSDs chosen from the seed, in drawn order."""
+        rng = np.random.default_rng(seed_words(self.seed) + [salt])
+        return [int(v) for v in rng.choice(
+            self.config["deployment"]["n_osds"], size=n, replace=False)]
+
+    def kill_osds(self, n: int, victims: list[int] | None = None
+                  ) -> None:
+        """Kill ``n`` OSDs chosen from the seed (or ``victims``) and
+        return when the map marks them down (there is no ``osd down``
+        command: the wait is the heartbeat grace). The program's map
+        leaves a down OSD out of CRUSH at once, so a spare OSD takes
+        over a lost position and recovery starts with the new map."""
+        self.victims = sorted(self.draw_osds(n) if victims is None
+                              else victims)
         epoch = self.cluster.epoch()
         for victim in self.victims:
             self.cluster.kill_osd(victim)
         for victim in self.victims:
             self.cluster.wait_for_osd_down(victim, timeout=120)
         self.rados.wait_for_epoch(epoch + 1, timeout=60)
-        self.cluster.wait_for_clean(timeout=240)
 
-    def lost_data_positions(self, ps: int) -> int:
-        """How many of the PG's k data positions no live OSD holds now
-        (a hole in the acting set)."""
-        _, acting, _ = self.cluster.mon.osdmap.pg_to_up_acting(
-            self.pool_id, ps)
-        return sum(1 for osd in list(acting)[:self.pool["k"]]
-                   if osd not in self.cluster.osds)
+    def settle(self, timeout: float = 240.0) -> None:
+        """Until the recovery that can happen has happened. Reads
+        racing it would be measured at whatever share of it the window
+        caught; a window that wants the state that lasts (the OSDs
+        down, the spares filled, every other lost position a hole)
+        starts from here."""
+        self.cluster.wait_for_clean(timeout=timeout)
+
+    def revive_osds(self) -> None:
+        """Start the killed OSDs again on the stores they left, and
+        wait until the map has them up and every PG is clean."""
+        for victim in self.victims:
+            self.cluster.revive_osd(victim)
+        self.cluster.wait_for_osds_up(timeout=120)
+        self.victims = []
+        self.settle()
+
+    def held(self, ps: int, pos: int, osd_id: int) -> set[str]:
+        """The objects OSD ``osd_id`` holds at position ``pos`` of the
+        PG: nothing when it is not alive or has no such collection."""
+        from ceph_tpu.osd.pg import pg_cid
+        from ceph_tpu.store.object_store import StoreError
+        osd = self.cluster.osds.get(osd_id)
+        if osd is None:
+            return set()
+        try:
+            return set(osd.store.list_objects(
+                pg_cid(self.pool_id, ps, pos)))
+        except StoreError:
+            return set()
+
+    def lost_data_shards(self, names: list[str]) -> dict[str, int]:
+        """For each object, how many of its k data shards no live OSD
+        of the acting set holds now: a hole in the acting set, or a
+        spare that has taken the position over and not been filled."""
+        osdmap = self.cluster.mon.osdmap
+        held: dict[int, list[set[str]]] = {}
+        out = {}
+        for name in names:
+            ps = osdmap.object_to_pg(self.pool_id, name)
+            if ps not in held:
+                _, acting, _ = osdmap.pg_to_up_acting(self.pool_id, ps)
+                held[ps] = [self.held(ps, pos, osd) for pos, osd in
+                            enumerate(list(acting)[:self.pool["k"]])]
+            out[name] = sum(1 for have in held[ps] if name not in have)
+        return out
 
     def warm_degraded_reads(self) -> None:
         """Every decode bucket: for each number of lost data shards
         the pool now has, gated bursts of reads inside the PG that
-        holds most such objects (a decode flush batches within one
-        PG's erasure signature)."""
+        holds most such objects: they share one erasure signature
+        (which shards are present, which are wanted), and a decode
+        flush batches the ops of one signature, of whatever PG."""
         by_kind: dict[int, dict[int, list[str]]] = {}
         osdmap = self.cluster.mon.osdmap
-        lost_of: dict[int, int] = {}
-        for name in self.preloaded:
-            ps = osdmap.object_to_pg(self.pool_id, name)
-            if ps not in lost_of:
-                lost_of[ps] = self.lost_data_positions(ps)
-            if lost_of[ps]:
-                by_kind.setdefault(lost_of[ps], {}).setdefault(
-                    ps, []).append(name)
+        self.reconstructing, self.intact = [], []
+        for name, lost in self.lost_data_shards(self.preloaded).items():
+            if lost:
+                by_kind.setdefault(lost, {}).setdefault(
+                    osdmap.object_to_pg(self.pool_id, name),
+                    []).append(name)
                 self.reconstructing.append(name)
             else:
                 self.intact.append(name)
@@ -353,7 +419,8 @@ class Served:
         asked), and every shard AS THE OSD STORES HOLD IT with the crc
         its ``hinfo`` holds, by the map as it is now: a shard that
         recovery rebuilt on a spare OSD is among them, a position that
-        stayed a hole is absent."""
+        stayed a hole is absent; ``unmapped`` counts the positions to
+        which the map assigns no OSD at all."""
         from ceph_tpu.osd.pg import pg_cid
         back: dict[str, bytes] = {}
         if read_back:
@@ -384,5 +451,6 @@ class Served:
                                                      "hinfo"))
                 crcs[pos] = int(hinfo["hashes"][pos])
             out.append({"name": name, "read_back": back.get(name),
-                        "shards": shards, "crcs": crcs})
+                        "shards": shards, "crcs": crcs,
+                        "unmapped": sum(1 for o in acting if o < 0)})
         return out

@@ -1,11 +1,14 @@
 """A tiny copy of the benchmark's data directories for CPU tests.
 
 The copy holds the committed data files unchanged plus NEW files only:
-one configuration, two traffic mixes, one per-layer metric over a
-counter no committed metric reads, and their entries in a
-``BENCHMARK.json`` of its own. Nothing that is there is edited, which is
-what a later PR may do too; that the harness then finds and runs the new
-cells is the test that it is driven by data.
+a tiny configuration of the pool that is there, one of ANOTHER plugin
+(``shec``, with the profile key ``c`` beyond the six the harness once
+named, and a reference module of its own), the same pool once more
+without that module, three traffic mixes (a tiny recovery among them),
+one per-layer metric over a counter no committed metric reads, and
+their entries in a ``BENCHMARK.json`` of its own. Nothing that is there
+is edited, which is what a later PR may do too; that the harness then
+finds and runs the new cells is the test that it is driven by data.
 """
 
 from __future__ import annotations
@@ -15,15 +18,19 @@ import os
 import shutil
 import sys
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
 BENCH_DIR = os.path.join(ROOT, "benchmarks")
 for _p in (ROOT, BENCH_DIR):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
 #: the harness's own code stays where it is; only data is copied
-DATA = ("configs", "traffic", "layer_metrics", "readers", "peaks.json")
+DATA = ("configs", "traffic", "layer_metrics", "readers", "windows",
+        "pending", "peaks.json", "reference.py")
+
+#: the cell that waits in ``benchmarks/pending/`` (PERF.md section 7)
+PENDING = "k8m3_recovery_4m"
 
 #: what the fake device reports (the accelerator check is skipped)
 CPU_DEVICE = {"platform": "cpu", "kind": "cpu-test", "count": 1}
@@ -60,11 +67,33 @@ def make_root(tmp: str) -> str:
     conf["deployment"]["osd_heartbeat_grace"] = 4
     _dump(conf, os.path.join(bench, "configs", "tiny_k8m3.json"))
 
+    # another plugin: the whole profile (``c``) has to reach it, and
+    # only its own reference module states what its shards are
+    shec = _load(os.path.join(BENCH_DIR, "configs",
+                              "rs_k8m3_12osd.json"))
+    shec.update(name="tiny_shec", reference="shec_reference")
+    shec["pool"] = {"plugin": "shec", "technique": "single", "k": 6,
+                    "m": 4, "c": 3, "backend": "jax",
+                    "stripe_unit": 4096, "pg_num": 8}
+    shec["deployment"]["osd_heartbeat_grace"] = 4
+    _dump(shec, os.path.join(bench, "configs", "tiny_shec.json"))
+    shutil.copy(os.path.join(HERE, "shec_reference.py"),
+                os.path.join(bench, "shec_reference.py"))
+    del shec["reference"]
+    shec["name"] = "tiny_shec_by_rs"
+    _dump(shec, os.path.join(bench, "configs", "tiny_shec_by_rs.json"))
+
     for name, base, extra in (
             ("tiny_write", "write_4m", {}),
             ("tiny_degraded", "degraded_read_4m",
              {"preload_objects": 12, "payload_pool": 12,
-              "max_objects": 12})):
+              "max_objects": 12}),
+            ("tiny_recover", "recovery_4m",
+             # no 64 MiB flush closes a tiny batch: a flush can hold
+             # every rebuild that is in flight
+             {"preload_objects": 24, "payload_pool": 12,
+              "warm_bursts": [1, 2, 4, 8, 16, 32],
+              "clean_timeout_s": 60, "poll_s": 0.05})):
         mix = _load(os.path.join(BENCH_DIR, "traffic", base + ".json"))
         mix.update(object_bytes=64 << 10, clients=4,
                    warm_bursts=[1, 2, 4], check_sample=4,
@@ -82,21 +111,36 @@ def make_root(tmp: str) -> str:
 
     table = _load(os.path.join(bench, "peaks.json"))
     bm = _load(os.path.join(ROOT, "BENCHMARK.json"))
-    bm["configs"].append(
-        {"name": "tiny_k8m3", "source": "tests/benchmarks",
-         "file": "benchmarks/configs/tiny_k8m3.json", "reduced": [],
-         "why": "CPU test size"})
+    bm["configs"] += [
+        {"name": name, "source": "tests/benchmarks: " + name,
+         "file": f"benchmarks/configs/{name}.json", "reduced": [],
+         "why": "CPU test size"}
+        for name in ("tiny_k8m3", "tiny_shec", "tiny_shec_by_rs")]
+    # the pending cell is registered as the PR that admits it will do
+    # it: by its entries alone (here with a bound that nothing judges)
+    pending = _load(os.path.join(bench, "pending", PENDING + ".json"))
+    bm["workloads"].append(pending["workload"])
+    setup = bm["end_to_end"].pop()
+    assert setup["name"] == "setup_s"
+    bm["end_to_end"] += [dict(met, bound=0.25)
+                         for met in pending["end_to_end"]] + [setup]
+    bm["per_layer"] += pending["per_layer"]
+    like = {"tiny.write": ("tiny_k8m3", "tiny_write", "k8m3_write_4m"),
+            "tiny.degraded": ("tiny_k8m3", "tiny_degraded",
+                              "k8m3_degraded_read_4m"),
+            "tiny.recover": ("tiny_k8m3", "tiny_recover", PENDING),
+            "tiny.shec_write": ("tiny_shec", "tiny_write",
+                                "k8m3_write_4m"),
+            "tiny.shec_by_rs": ("tiny_shec_by_rs", "tiny_write",
+                                "k8m3_write_4m")}
     bm["workloads"] += [
-        {"name": "tiny.write", "config": "tiny_k8m3",
-         "traffic": "tiny_write", "chips": 1, "why": "CPU test"},
-        {"name": "tiny.degraded", "config": "tiny_k8m3",
-         "traffic": "tiny_degraded", "chips": 1, "why": "CPU test"}]
+        {"name": cell, "config": config, "traffic": mix, "chips": 1,
+         "why": "CPU test"} for cell, (config, mix, _) in like.items()]
     for metric in bm["end_to_end"] + bm["per_layer"]:
         cells = metric.get("workloads")
-        if cells and "k8m3_write_4m" in cells:
-            cells.append("tiny.write")
-        if cells and "k8m3_degraded_read_4m" in cells:
-            cells.append("tiny.degraded")
+        if cells:
+            cells += [cell for cell, (_, _, real) in like.items()
+                      if real in cells]
     bm["per_layer"].append(
         {"name": "tiny_bytes_per_flush", "unit": "bytes/flush",
          "better": "higher", "source": "program_counter",
@@ -123,3 +167,20 @@ def _snapshot(bench: str) -> dict:
             with open(path, "rb") as f:
                 out[os.path.relpath(path, bench)] = f.read()
     return out
+
+
+def run_main(capfd, root: str, workload: str, trace: int = 0,
+             seed: int = 3_000_000_011, seconds: float = 1.5
+             ) -> tuple[int, list[str]]:
+    """The command's own ``main`` in this process, the look for a chip
+    skipped: (exit code, the lines of standard output)."""
+    import run
+    out = run.Out()
+    try:
+        rc = run.main(["--workload", workload, "--seed", str(seed),
+                       "--seconds", str(seconds), "--trace",
+                       str(trace)], root=root, device=CPU_DEVICE,
+                      out=out)
+    finally:
+        out.restore()
+    return rc, [ln for ln in capfd.readouterr().out.splitlines() if ln]

@@ -26,38 +26,7 @@ E2E_KEYS = {"correct", "attempted", "failed", "metrics", "device",
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
-@pytest.fixture(scope="module")
-def tiny_root(tmp_path_factory):
-    return bench_tiny.make_root(str(tmp_path_factory.mktemp("bench")))
-
-
-@pytest.fixture
-def cpu_env(monkeypatch):
-    """What the test, not the program, steers: the tiny flushes stay on
-    the device route (a 64 KiB flush is below host_flush_bytes; the jax
-    backend fuses the crc pass only when asked)."""
-    from ceph_tpu.utils import faults
-    from ceph_tpu.utils.device_telemetry import telemetry
-    monkeypatch.setenv("CEPH_TPU_HOST_FLUSH_BYTES", "0")
-    monkeypatch.setenv("CEPH_TPU_FUSE_CRC", "1")
-    faults.reset_for_tests(0)
-    telemetry().reset()
-    yield
-    faults.reset_for_tests(0)
-
-
-def _run(capfd, root, workload, trace=0, seed=3_000_000_011,
-         seconds=1.5):
-    """(exit code, the lines of standard output)."""
-    out = run.Out()
-    try:
-        rc = run.main(["--workload", workload, "--seed", str(seed),
-                       "--seconds", str(seconds), "--trace",
-                       str(trace)], root=root, device=CPU_DEVICE,
-                      out=out)
-    finally:
-        out.restore()
-    return rc, [ln for ln in capfd.readouterr().out.splitlines() if ln]
+_run = bench_tiny.run_main
 
 
 def test_last_line_end_to_end(tiny_root, cpu_env, capfd):
@@ -193,6 +162,7 @@ def test_a_broken_timed_path_is_not_correct(tiny_root, cpu_env, capfd,
 # -- the control --------------------------------------------------------
 
 WRITE_CONTROLS = {"one_parity_short", "crc_not_kept"}
+RECOVERY_CONTROLS = {"rebuilt_by_xor"}
 
 
 @pytest.mark.parametrize("workload,controls,seed", [
@@ -202,6 +172,11 @@ WRITE_CONTROLS = {"one_parity_short", "crc_not_kept"}
     ("tiny.degraded", {"not_reconstructed"}, 1),
     ("tiny.degraded", {"not_reconstructed"}, 2_500_000_000),
     ("tiny.degraded", {"not_reconstructed"}, 77),
+    ("tiny.recover", RECOVERY_CONTROLS, 1),
+    ("tiny.recover", RECOVERY_CONTROLS, 2_500_000_000),
+    ("tiny.recover", RECOVERY_CONTROLS, 77),
+    # another plugin's pool is held to its own reference module
+    ("tiny.shec_write", WRITE_CONTROLS, 1),
     # one committed cell at its own size (the chip runs have all three)
     ("k4m2_write_1m", WRITE_CONTROLS, 1),
 ])

@@ -25,13 +25,44 @@ def test_padded_object_bytes():
     (3, 1 * MIB, 4, 2, 3 * 1 * MIB * 6 / 4),
 ])
 def test_encode_hbm_bytes(ops, obj, k, m, want):
-    assert work.encode_hbm_bytes(ops, obj, k, m, 4096) == want
+    pool = {"k": k, "m": m, "stripe_unit": 4096, "plugin": "jerasure"}
+    assert work.encode_hbm_bytes(ops, obj, pool) == want
+
+
+RS = {"k": 8, "m": 3, "stripe_unit": 4096}
 
 
 def test_decode_hbm_bytes_reads_k_survivors_once():
-    assert work.decode_hbm_bytes(5, 4 * MIB, 8, 3, 4096) == \
+    import reference
+    assert work.decode_hbm_bytes(5, 4 * MIB, RS) == 5 * 4 * MIB
+    # the RS reference states no cheaper rebuild: k shards it is
+    assert work.decode_hbm_bytes(5, 4 * MIB, RS, reference) == \
         5 * 4 * MIB
     assert set(work.WORK) == {"encode_hbm_bytes", "decode_hbm_bytes"}
+
+
+def test_decode_hbm_bytes_asks_the_configurations_reference():
+    """A codec that rebuilds from less than k whole shards states so
+    in its reference module, and the roofline reads that work."""
+    import shec_reference
+    pool = {"plugin": "shec", "technique": "single", "k": 6, "m": 4,
+            "c": 3, "stripe_unit": 4096}
+    # windows of m=4, c=3 over k=6 are 4, 5, 4 and 5 columns wide
+    shard = 6 * MIB // 6
+    assert shec_reference.rebuild_read_bytes(pool, 6 * MIB) == \
+        4 * shard
+    assert work.decode_hbm_bytes(3, 6 * MIB, pool, shec_reference) == \
+        3 * 4 * shard
+    assert work.decode_hbm_bytes(3, 6 * MIB, pool) == 3 * 6 * MIB
+    read = spec.reader("roofline_pct")
+    args = {"work": "decode_hbm_bytes", "ops_counter": "decode_ops"}
+    ctx = _ctx(trace={"busy_s": 0.01, "window_s": 5.0},
+               engine_traced={"decode_ops": 3},
+               traffic={"object_bytes": 6 * MIB})
+    ctx["config"] = {"pool": pool}
+    by_k = read(ctx, **args)
+    by_ref = read(dict(ctx, reference=shec_reference), **args)
+    assert by_ref == pytest.approx(by_k * 4 / 6)
 
 
 def _ctx(**over):
