@@ -37,6 +37,14 @@ SIMD_ALIGN = 32
 class ErasureCode(ErasureCodeInterface):
     """Base class implementing the generic split/pad/assemble machinery."""
 
+    #: what the codec states to the device engine's seams
+    #: (osd/ec_util.flush_kind): ``"matrix"``: a flush is one
+    #: ``[m, k]`` GF matrix over the byte stream (MatrixErasureCode
+    #: without a chunk mapping); ``"layered"``: a flush is one layered
+    #: program over plane-major lanes (clay); None: the host codec
+    #: serves, stripe by stripe
+    device_flush: str | None = None
+
     def __init__(self) -> None:
         self._profile: ErasureCodeProfile = {}
         self.chunk_mapping: list[int] = []

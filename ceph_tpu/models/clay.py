@@ -28,6 +28,13 @@ ErasureCodeClay.cc:394-644) — optimal repair bandwidth, surfaced through
 ``minimum_to_decode`` returning (offset, count) sub-chunk ranges exactly
 like the reference (ErasureCodeInterface.h:280-300).
 
+The served path (a pool with ``backend=jax|pallas``, PR 29) does not
+call this codec stripe by stripe: the codec states ``device_flush =
+"layered"`` and the engine runs a whole flush as one program
+(osd/ec_util.layered_program, layered_decode_program, with the
+builders of models/clay_device.flush_encoder / flush_decode_table).
+What follows describes the codec's OWN per-call device routes.
+
 TPU execution: the plane-by-plane layered machinery is pure GF(2^8)-linear
 algebra applied byte-position-wise along each sub-chunk, so for any fixed
 erasure signature the whole codec collapses to ONE flat matrix over
@@ -150,6 +157,41 @@ class ErasureCodeClay(ErasureCode):
         profile["scalar_mds"] = scalar_mds
         profile["technique"] = technique
         self._profile = profile
+
+    # -- the served path's seam (osd/ec_util.flush_kind) -------------------
+
+    #: what this codec states to the device engine: a whole flush is
+    #: ONE layered program over plane-major lanes, encode and decode
+    #: (models/clay_device.flush_encoder / flush_decode_table)
+    device_flush = "layered"
+
+    def flush_key(self) -> tuple:
+        """What the flush programs depend on, as a value: equal for
+        two codec objects of one profile, so ops of every PG and OSD
+        of a pool meet in one flush and one staging buffer
+        (osd/device_engine.program_key)."""
+        return (self.backend, self._k, self._m, self.d,
+                np.asarray(self.mds.coding_matrix).tobytes(),
+                np.asarray(self.pft.coding_matrix).tobytes())
+
+    def flush_encoder(self):
+        """``planes [k, ssc, L] -> parity planes [m, ssc, L]``, safe
+        inside a jit: the ONE encode builder the served path takes."""
+        from ceph_tpu.models import clay_device
+        return clay_device.flush_encoder(self)
+
+    def flush_decode_table(self, present: tuple, want: tuple):
+        """``(table, built)``: the operand that makes the one decode
+        program serve this erasure signature."""
+        from ceph_tpu.models import clay_device
+        return clay_device.flush_decode_table(self, present, want)
+
+    @staticmethod
+    def flush_decode(table, planes):
+        """``planes [k*ssc, L] -> [e*ssc, L]`` by ``table``, safe
+        inside a jit: the ONE decode builder the served path takes."""
+        from ceph_tpu.models import clay_device
+        return clay_device.flush_decode(table, planes)
 
     # -- geometry ----------------------------------------------------------
 
@@ -556,12 +598,13 @@ class ErasureCodeClay(ErasureCode):
         except KeyError:
             resolved = None
         if resolved == "pallas":
-            # round-4 production path: the whole structured chain
-            # (pairwise uncouple -> plane-wise MDS -> recouple) in ONE
-            # pallas kernel with a VMEM-resident working set — 525
-            # GB/s measured (RS-kernel class) vs 9 GB/s for the dense
-            # linearized matrix, which is COMPUTE-bound at ~64x the
-            # RS MAC count (models/clay_device.build_encode_kernel)
+            # the codec's own per-call route (NOT the served path,
+            # which batches a whole flush: osd/ec_util.layered_program):
+            # the whole structured chain (pairwise uncouple ->
+            # plane-wise MDS -> recouple) in ONE pallas kernel with a
+            # VMEM-resident working set (kernel alone, not
+            # re-measured: 525 GB/s vs 9 GB/s for the dense linearized
+            # matrix; models/clay_device.build_encode_kernel)
             try:
                 if getattr(self, "_enc_kernel", None) is None and \
                         not getattr(self, "_enc_kernel_failed", False):
@@ -658,10 +701,10 @@ class ErasureCodeClay(ErasureCode):
         if self.decode_kernel:
             # round-5 structured decode kernel
             # (clay_device.build_transform_kernel): bit-exact, but
-            # MEASURED SLOWER than the dense matrix on current Mosaic
-            # (2.6 vs 14.4 GB/s decode-2 — the multi-level unrolled
-            # body hits a compiler scheduling cliff, BASELINE.md r5
-            # negative result), so it is opt-in
+            # slower than the dense matrix (kernel alone, not
+            # re-measured: 2.6 vs 14.4 GB/s decode-2 — the multi-level
+            # unrolled body hits a compiler scheduling cliff,
+            # BASELINE.md r5 negative result), so it is opt-in
             # (profile decode_kernel=true), not the default
             return self._decode_chunks_kernel(want_to_read, chunks,
                                               out, missing, size)

@@ -22,26 +22,30 @@ are cached, so encode (erased = parity nodes) compiles once per
 profile. Bit-exactness vs the host plane machinery is asserted in
 tests/test_clay_device.py.
 
-Measured (v5e, k=8,m=4,d=11 encode, 64 MiB batches): 4.7 GB/s — the
-score-level chain inherently sweeps the full [q*t, ssc, L] working
-set ~6x per level (permuted gathers + masked selects), so the DENSE
-linearized signature matrix (models/clay.py, one [m*ssc, k*ssc]
-matmul, ~9 GB/s despite 20x FLOP waste) remains the production device
-path; this module is the faithful staged expression of the algorithm,
-kept as the validated alternative and the basis for a future
-plane-blocked kernel.
+RATES IN THIS FILE: every figure marked "kernel alone, not
+re-measured" dates from before the device engine, the fused flush and
+the benchmark existed (BASELINE.md rounds 2-6): a builder called in a
+loop on device-resident arrays, never the served path. What the served
+path takes, and what it measured on the chip as a whole flush program
+(PR 29), is at the foot of this docstring.
+
+``build_transform`` (kernel alone, not re-measured: 4.7 GB/s, v5e,
+k=8,m=4,d=11 encode, 64 MiB batches): the score-level chain sweeps the
+full [q*t, ssc, L] working set ~6x per level (permuted gathers +
+masked selects); the faithful staged expression of the algorithm,
+compiled per erasure signature.
 
 Round-3 finding (``build_encode_fast``): for the ENCODE erasure
 pattern (all parities erased) the score-level chain collapses to ONE
 active level, so encode is exactly three stages — a 2-term pairwise
 pass over the data, ONE plane-wise [m,k] MDS matmul (RS-kernel
-class, 561 GB/s in isolation on this chip), and a 2-term recouple
-pass. The structured encoder below is bit-exact and does ~1/20 the
-dense MACs, yet measures only 8.2 GB/s composed (vs 9.0 dense):
-XLA inserts a layout copy between the gather/select producers and
-the pallas custom call (a bare row-gather feeding the kernel already
-drops it from 270 to 82 GB/s), and the per-slot constant-select
-chains do not fuse into single passes.
+class), and a 2-term recouple pass. The structured encoder below is
+bit-exact and does ~1/20 the dense MACs (kernel alone, not
+re-measured: 8.2 GB/s composed against 9.0 for the dense linearized
+matrix: XLA inserts a layout copy between the gather/select producers
+and the pallas custom call, and the per-slot constant-select chains do
+not fuse into single passes). It is what a plain-XLA backend serves
+(:func:`flush_encoder`).
 
 Round-4 result (``build_encode_kernel``): the whole three-stage chain
 inside ONE pallas kernel with the working set VMEM-resident. The key
@@ -51,27 +55,51 @@ layout changes exist to copy); the (node, plane) pair gathers become
 routing); per-slot GF coefficients are per-row VPU XOR chains; the
 plane-wise MDS runs per plane over its contiguous z-major row group
 as an [8m, 8kk] bit-matmul. ~2k MACs/byte vs the dense linearized
-matrix's ~16k (dense measures ~9 GB/s because it is COMPUTE-bound at
-64x the RS MAC count). Measured (v5e, k=8,m=4,d=11, 67 MB batches,
-plateau method): **525 GB/s**, spread 0.0% — RS-kernel class, 58x the
-dense path, 10x past the >= 50 target. Bit-exact vs the host layered
-oracle (both pallas-TPU and interpret mode); production encode routes
-here for pallas backends (models/clay.py _encode_chunks_lin).
+matrix's ~16k (the dense matrix is compute-bound at 64x the RS MAC
+count). Kernel alone, not re-measured: 525 GB/s (v5e, 67 MB batches,
+plateau method). Bit-exact vs the host layered oracle (both pallas-TPU
+and interpret mode); the served path's encode on a pallas backend.
 
-The single-XLA-program experiment (``build_encode_fused``) measured
-1.8 GB/s on chip — kept as the documented negative result: outside a
-kernel, the row gathers materialize and the bit-plane expansion
-amplifies HBM traffic ~30x.
+The single-XLA-program experiment (``build_encode_fused``; kernel
+alone, not re-measured: 1.8 GB/s) is the documented negative result:
+outside a kernel, the row gathers materialize and the bit-plane
+expansion amplifies HBM traffic ~30x.
+
+WHAT THE SERVED PATH TAKES (PR 29; osd/ec_util.layered_program /
+layered_decode_program): ONE encode builder, :func:`flush_encoder`
+(``build_encode_kernel`` on a pallas backend; a plain-XLA backend,
+which cannot run a Mosaic kernel, takes ``build_encode_fast``), and
+ONE decode builder, :func:`flush_decode_table` + :func:`flush_decode`
+(the dense linearized transform of a signature as an int8 bit-matrix
+OPERAND of one bit-sliced MXU matmul, so one compiled program per
+shape bucket serves every signature). Measured on one TPU v5 lite as
+WHOLE flush programs of 4 MiB objects, payload re-laid on the device,
+crc rows included (my chip run, PR 29, best of 5): encode with
+``build_encode_kernel`` 1.60 / 5.11 / 18.3 ms for 1 / 4 / 16 ops (2.6
+to 3.7 GB/s of payload; nearly all of it is the two turns of the
+layout and the crc rows, not the kernel), compiling in 22-28 s a
+bucket; with ``build_encode_fast`` 3.31 / 17.4 ms for 1 / 4 ops,
+compiling in 19 / 63 s (311 s for 16 ops on the described chip): the
+kernel is taken. Decode, one lost shard: 0.93 / 2.86 / 7.43 ms; two:
+1.20 / 3.51 / 10.7 ms; a signature's table costs 35-99 ms of host
+time to build, once. Not on the served path, kept with their tests
+for the ``simplicity`` issue ROADMAP.md Q3.5 queues:
+``build_transform``, ``build_encode_fused``,
+``build_transform_kernel`` (its tables are compiled in per signature),
+``build_decode_matvec`` with ops/gf_block_sparse (a plan per
+signature, compiled in).
 """
 
 from __future__ import annotations
 
 import functools
+import threading
 from dataclasses import dataclass, field
 
 import numpy as np
 
 from ceph_tpu.ops import bitmatrix, gf256
+from ceph_tpu.utils.lru import BoundedLRU
 
 
 # -- static trace ------------------------------------------------------
@@ -359,7 +387,8 @@ def _mds_decode_matrix(codec, intact: list, er: list) -> np.ndarray:
     return np.stack([np.asarray(sol[i], dtype=np.uint8) for i in er])
 
 
-def build_encode_fast(codec, tables_only: bool = False):
+def build_encode_fast(codec, tables_only: bool = False,
+                      matvec_device=None):
     """Structured device ENCODE (the round-2 verdict's plane-blocked
     kernel, ErasureCodeClay.cc:644-709 coupling structure): for the
     all-parity erasure pattern the score-level chain collapses to ONE
@@ -478,15 +507,16 @@ def build_encode_fast(codec, tables_only: bool = False):
         holder = _T()
         holder.tables = tables
         return holder
-    from ceph_tpu.ops import backend as backend_mod
-    try:
-        resolved, _ = backend_mod.resolve(codec.backend)
-    except KeyError:
-        resolved = "jax"
-    if resolved == "pallas":
-        from ceph_tpu.ops.gf_pallas import matvec_device
-    else:
-        from ceph_tpu.ops.gf_jax import matvec_device
+    if matvec_device is None:
+        from ceph_tpu.ops import backend as backend_mod
+        try:
+            resolved, _ = backend_mod.resolve(codec.backend)
+        except KeyError:
+            resolved = "jax"
+        if resolved == "pallas":
+            from ceph_tpu.ops.gf_pallas import matvec_device
+        else:
+            from ceph_tpu.ops.gf_jax import matvec_device
     t_a1, t_a2 = tables["t_a1"], tables["t_a2"]
     t_b1, t_b2, t_b3 = (tables["t_b1"], tables["t_b2"],
                         tables["t_b3"])
@@ -533,7 +563,8 @@ def build_encode_fast(codec, tables_only: bool = False):
 def build_encode_fused(codec):
     """Round-4: the three structured-encode stages as ONE XLA program
     (no custom-call boundaries, no per-stage jit seams). The round-3
-    composition ran at 8.2 GB/s because each stage was its own jitted
+    composition ran at 8.2 GB/s (kernel alone, not re-measured)
+    because each stage was its own jitted
     piece: XLA inserted layout copies into the pallas custom call and
     could not fuse the select chains across dispatch boundaries. Here
     the pairwise uncouple (gather + xor chains), the plane-wise MDS
@@ -619,9 +650,10 @@ def build_encode_kernel(codec, tile: int = 512):
       [z*kk, (z+1)*kk) row group: unpack bits -> one [8m, 8kk]
       bit-matmul on the MXU -> weighted-sum repack, all in VMEM.
 
-    ~2k MACs/byte total vs the dense linearized matrix's ~16k (the
-    measured reason dense tops out at ~9 GB/s: it is COMPUTE-bound at
-    64x the RS MAC count). Bit-exact vs the host layered oracle.
+    ~2k MACs/byte total vs the dense linearized matrix's ~16k (dense
+    is compute-bound at 64x the RS MAC count). Bit-exact vs the host
+    layered oracle. As the served flush program's encode (PR 29, my
+    chip run): see the module docstring.
 
     Returns ``[k, ssc, L] uint8 -> [m, ssc, L]`` with L pow2-bucketed.
     """
@@ -792,6 +824,7 @@ def build_encode_kernel(codec, tile: int = 512):
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((R_out, L), jnp.uint8),
             interpret=jax.default_backend() == "cpu",
+            name="clay_encode",
         )(cflat, *consts)
 
     def encode(c_data):
@@ -909,8 +942,9 @@ def build_transform_kernel(codec, erased: frozenset[int],
     Pallas kernel — the decode counterpart of ``build_encode_kernel``
     (matching decode_layered, ErasureCodeClay.cc:644-709). The dense
     linearized decode matrix is COMPUTE-bound at ~5% density (14.4
-    GB/s for decode-2, BASELINE.md); this runs the sparse structure
-    directly:
+    GB/s for decode-2, BASELINE.md: kernel alone, not re-measured);
+    this runs the sparse structure directly (NOT the served path's
+    decode: its tables are compiled in per signature):
 
     - state lives Z-MAJOR, each plane's node group PADDED to
       P = ceil(qt/8)*8 rows (row z*P + n): every per-plane MDS slice
@@ -1262,6 +1296,102 @@ def build_decode_matvec(codec, mat: np.ndarray, label: str = "decode"):
     if t_sparse < t_dense:
         return done(sparse_fn, "sparse", measured)
     return done(dense_fn, "dense", measured)
+
+
+# -- the served path: ONE encode builder, ONE decode builder -----------
+#
+# osd/ec_util's layered flush programs take these two and nothing
+# selects between alternatives at run time. A flush arrives re-laid to
+# plane-major lanes ([node, plane, S stripes * sub-chunk bytes]): every
+# stripe of every op of the flush is one more run of lanes.
+
+_served_lock = threading.Lock()
+#: codec.flush_key() -> the trace-safe layered encode of that profile
+_served_encoders: dict = {}
+#: (flush_key, present, want) -> the signature's bit matrix on the
+#: device; bounded like the codec's own linearized-transform LRU (the
+#: ISA decode-table cache's role). C(12, <=4) signatures exist; a
+#: degraded pool of 64 PGs meets at most 64 of them
+_served_tables: BoundedLRU = BoundedLRU(256)
+
+#: lanes per step of the decode matmul: bounds the bit-plane
+#: expansion XLA materializes (8x the block, int8)
+DECODE_LANE_BLOCK = 1 << 14
+
+
+def flush_encoder(codec):
+    """The served path's layered encode of ``codec``'s profile:
+    ``planes [k, ssc, L] uint8 -> parity planes [m, ssc, L]``, safe to
+    call inside an outer jit (ec_util.layered_program): pairwise
+    uncouple, the ssc plane-wise ``[m, k]`` solves, pairwise recouple.
+    By the backend the profile names, as ec_util.fused_program picks
+    the RS kernel (no look at what this host has): on ``pallas`` the
+    whole chain is ONE Mosaic kernel with a VMEM-resident working set
+    (:func:`build_encode_kernel`); a plain-XLA backend cannot run a
+    Mosaic kernel and takes the same three stages as XLA ops around
+    the gf_jax matvec (:func:`build_encode_fast`). Built once per
+    profile and shared by every codec object of it."""
+    key = codec.flush_key()
+    with _served_lock:
+        fn = _served_encoders.get(key)
+        if fn is None:
+            if codec.backend == "pallas":
+                fn = build_encode_kernel(codec)
+            else:
+                from ceph_tpu.ops.gf_jax import matvec_device
+                fn = build_encode_fast(codec,
+                                       matvec_device=matvec_device)
+            _served_encoders[key] = fn
+    return fn
+
+
+def flush_decode_table(codec, present: tuple, want: tuple):
+    """The operand that makes the one decode program serve erasure
+    signature (``present``: the k chunks read, sorted; ``want``: the
+    chunks to rebuild): the linearized ``[len(want)*ssc, k*ssc]``
+    transform of the layered decode (score-ordered planes, probed once
+    from the host oracle, models/clay._decode_matrix) expanded to its
+    GF(2) bit matrix, int8, resident on the device. Returns
+    ``(table, built)``; cached per (profile, signature) for every
+    codec object of the profile, as the reference caches ISA decode
+    tables (ErasureCodeIsa.cc:226-303)."""
+    import jax.numpy as jnp
+    built = []
+
+    def build():
+        built.append(1)
+        n = codec.get_chunk_count()
+        erased = tuple(c for c in range(n) if c not in present)
+        mat = codec._decode_matrix(tuple(present), erased)
+        ssc = codec.sub_chunk_no
+        rows = np.concatenate(
+            [mat[erased.index(c) * ssc:(erased.index(c) + 1) * ssc]
+             for c in want])
+        return jnp.asarray(
+            bitmatrix.expand_bitmatrix(rows).astype(np.int8))
+
+    table = _served_tables.get_or_build(
+        (codec.flush_key(), tuple(present), tuple(want)), build)
+    return table, bool(built)
+
+
+def flush_decode(table, planes):
+    """The served path's decode of one signature over a whole flush:
+    ``table [8*e*ssc, 8*k*ssc] int8`` (an OPERAND: one compiled
+    program per shape serves every signature), ``planes [k*ssc, L]
+    uint8 -> [e*ssc, L]``: one bit-sliced GF(2^8) matmul on the MXU,
+    stepped over lane blocks. Safe to call inside an outer jit."""
+    import jax
+    from ceph_tpu.ops.gf_jax import _bitsliced_matvec_device
+    rows, lanes = planes.shape
+    block = min(lanes, DECODE_LANE_BLOCK)
+    if lanes == block:
+        return _bitsliced_matvec_device(table, planes)
+    steps = lanes // block
+    blocks = planes.reshape(rows, steps, block).transpose(1, 0, 2)
+    out = jax.lax.map(
+        lambda blk: _bitsliced_matvec_device(table, blk), blocks)
+    return out.transpose(1, 0, 2).reshape(table.shape[0] // 8, lanes)
 
 
 class ClayDeviceCodec:

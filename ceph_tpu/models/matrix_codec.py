@@ -64,6 +64,12 @@ class MatrixErasureCode(ErasureCode):
         return self._k
 
     @property
+    def device_flush(self) -> str | None:
+        """Position-wise: stripes fold into the byte axis, so a whole
+        flush is one matrix multiply — unless chunks are remapped."""
+        return None if self.chunk_mapping else "matrix"
+
+    @property
     def generator(self) -> np.ndarray:
         return gf256.systematic_generator(self.coding_matrix)
 

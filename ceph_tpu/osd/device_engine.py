@@ -207,11 +207,13 @@ def program_key(codec, sinfo: ec_util.StripeInfo) -> tuple:
     builds its own codec object from the pool's profile; equal keys
     mean the objects are interchangeable, so ops of different PGs
     (and OSDs) of one pool share a flush, and pools that differ in
-    any of these never do. A codec that is not a plain matrix codec
-    (clay, lrc: layered state the key cannot see) gets a key of its
-    own. Runs on every producer thread: the codec's part is computed
-    once per codec object and cached on it, valid while the codec
-    keeps the matrix it was computed from."""
+    any of these never do. A layered codec (clay) states its own
+    value (``flush_key()``: backend, k, m, d and the two sub-codes'
+    matrices, what :func:`ec_util.layered_program` keys its cache
+    by). A codec that is neither (lrc: state the key cannot see) gets
+    a key of its own. Runs on every producer thread: the codec's part
+    is computed once per codec object and cached on it, valid while
+    the codec keeps the matrix it was computed from."""
     cached = getattr(codec, "_engine_program_key", None)
     mat = getattr(codec, "coding_matrix", None)
     if cached is None or cached[0] is not mat:
@@ -223,6 +225,8 @@ def program_key(codec, sinfo: ec_util.StripeInfo) -> tuple:
                         mat is not None:
                     ck = (type(codec), codec.backend, mat.shape,
                           mat.tobytes(), tuple(codec.chunk_mapping))
+                elif ec_util.flush_kind(codec) == "layered":
+                    ck = (type(codec),) + tuple(codec.flush_key())
                 else:
                     ck = (type(codec), next(_opaque_codec_seq))
                 cached = codec._engine_program_key = (mat, ck)
@@ -541,7 +545,15 @@ class DeviceEncodeEngine:
                       "host_flushes": 0,
                       # auxiliary device work run via run_sync (deep
                       # scrub verify launches)
-                      "aux_runs": 0}
+                      "aux_runs": 0,
+                      # a layered codec's (clay) ops that went through
+                      # the one-program-a-flush routes, and erasure
+                      # signatures whose decode table had to be built
+                      # by a flush, on first use (tables a primary
+                      # builds when it peers are not among them)
+                      "layered_encode_ops": 0,
+                      "layered_decode_ops": 0,
+                      "signature_builds": 0}
         _telemetry().note_engine_window(self._window)
         #: launch pipeline: deque of (items, finalize, kspans,
         #: nbytes) batches whose device programs are queued
@@ -1253,6 +1265,8 @@ class DeviceEncodeEngine:
             done_t = _time.monotonic()
             self.stats["flushes"] += 1
             self.stats["ops"] += len(items)
+            if getattr(finalize, "layered", False):
+                self.stats["layered_encode_ops"] += len(items)
             if _spans_keys(items):
                 self.stats["cross_pg_ops"] += len(items)
             self.stats["bytes"] += nbytes
@@ -1329,7 +1343,10 @@ class DeviceEncodeEngine:
         of it is ``device_finalize``; a profiler trace sees three
         states in turn: ``decode_build`` (the survivors' concatenate),
         ``decode_run`` (upload, program and download, synchronous on
-        this thread) and ``decode_dispatch`` (the continuations)."""
+        this thread) and ``decode_dispatch`` (the continuations); a
+        layered codec's flush has ``signature_build`` between the
+        first two: getting the signature's table, a cache lookup, and
+        the build when no primary built it at peering (counted)."""
         import time as _time
         from ceph_tpu.parallel import mesh as mesh_mod
         from ceph_tpu.parallel import placement as _placement
@@ -1363,6 +1380,18 @@ class DeviceEncodeEngine:
                 lens = [len(np.asarray(shards[present[0]]))
                         for _k, shards, _w, _c, _s, _cl, _t in items]
                 _prof.pop_stage(mark)
+                layered = ec_util.device_layered(codec)
+                table = None
+                if layered:
+                    mark = _prof.push_stage(
+                        "device_finalize", span="signature_build",
+                        **batch)
+                    table, built = ec_util.signature_table(
+                        codec, *ec_util.decode_signature(
+                            codec, merged, want))
+                    if built:
+                        self.stats["signature_builds"] += 1
+                    _prof.pop_stage(mark)
                 mark = _prof.push_stage("device_finalize",
                                         span="decode_run", **batch)
                 # multi-chip decode (ISSUE 12): a big-enough
@@ -1372,7 +1401,7 @@ class DeviceEncodeEngine:
                 # falls back to the single-chip/host route below
                 out = None
                 mesh = mesh_mod.get_default_mesh()
-                if mesh is not None and \
+                if mesh is not None and not layered and \
                         staged >= self._mesh_flush_bytes and \
                         ec_util.device_decodable(codec):
                     placed = False
@@ -1390,7 +1419,10 @@ class DeviceEncodeEngine:
                             tel.note_placement_flush()
                     except Exception as exc:
                         self._note_fused_fallback("mesh_decode", exc)
-                if out is None:
+                if layered:
+                    out = ec_util.decode_layered(
+                        sinfo, codec, merged, list(want), table=table)
+                elif out is None:
                     out = ec_util.decode(sinfo, codec, merged,
                                          list(want))
             except Exception as exc:
@@ -1414,6 +1446,8 @@ class DeviceEncodeEngine:
             nbytes = sum(ln * len(present) for ln in lens)
             self.stats["decode_flushes"] += 1
             self.stats["decode_ops"] += len(items)
+            if layered:
+                self.stats["layered_decode_ops"] += len(items)
             if _spans_keys(items):
                 self.stats["decode_cross_pg_ops"] += len(items)
             self.stats["decode_bytes"] += nbytes

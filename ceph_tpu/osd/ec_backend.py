@@ -68,6 +68,7 @@ from ceph_tpu.store.object_store import (
     StoreError,
     Transaction,
 )
+from ceph_tpu.utils import profiler as _prof
 from ceph_tpu.utils import stage_clock, tracing
 from ceph_tpu.utils.config import g_conf
 from ceph_tpu.utils.dataplane import dataplane
@@ -220,6 +221,35 @@ class ECBackend(PGBackend):
             log(1, f"{pg}: device decode fell back to host "
                 f"(want {want})")
         return ec_util.decode(self.sinfo, self.codec, shards, want)
+
+    def on_peered(self, pg: PG) -> None:
+        """A layered codec's decode table depends on the erasure
+        signature (which k chunks a read gets, which it rebuilds), and
+        the primary knows its PG's holes from here on: build the table
+        a degraded read of this acting set will use NOW, on the
+        peering worker, so that no read's decode flush has to
+        (osd/device_engine ``signature_builds`` counts those that
+        do). The cache is the profile's, shared by every PG."""
+        if self.device is None or \
+                not ec_util.device_layered(self.device_codec):
+            return
+        want = list(range(self.k))
+        available = self.up_positions(pg)
+        if all(i in available for i in want):
+            return
+        try:
+            plan = self.codec.minimum_to_decode(want, available)
+        except Exception:
+            return              # not decodable now: nothing to ready
+        mark = _prof.push_stage("pg_process", span="signature_build")
+        try:
+            ec_util.signature_table(
+                self.device_codec, *ec_util.decode_signature(
+                    self.device_codec, dict.fromkeys(plan), want))
+        except Exception as exc:
+            log(1, f"{pg}: decode table not built at peering: {exc!r}")
+        finally:
+            _prof.pop_stage(mark)
 
     def _chunks_to_logical(self, shards: dict[int, np.ndarray],
                            size: int) -> bytes:

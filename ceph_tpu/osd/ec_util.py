@@ -7,8 +7,12 @@ loops ``ec_impl->encode`` once per stripe_width window (ECUtil.cc:120-159).
 The TPU translation (SURVEY.md §5 "stripe batch = leading vmap dim"): the
 per-stripe loop disappears. For matrix codecs the position-wise math lets S
 stripes fold into one [k, S*chunk_size] kernel call — one launch for a
-whole append batch instead of S launches; the generic fallback loops for
-codecs with cross-position structure (Clay).
+whole append batch instead of S launches. A sub-chunked codec (Clay:
+``device_flush = "layered"``) is position-wise ALONG a sub-chunk, so S
+stripes re-laid plane-major are one chunk with S times longer
+sub-chunks: one codec call on the host, one layered program a flush on
+the device (``layered_program``, ``layered_decode_program``). The
+generic fallback loops (lrc).
 
 ``HashInfo`` is the cumulative per-shard crc xattr (ECUtil.h:101-162,
 append logic ECUtil.cc:161-177, stored under the hinfo key :235): every
@@ -120,13 +124,25 @@ def encode(sinfo: StripeInfo, codec, data: bytes | np.ndarray,
     # [S, k, cs] -> per-shard contiguous [S*cs]
     stripes = buf.reshape(s, k, cs)
     data_shards = stripes.transpose(1, 0, 2).reshape(k, s * cs)
-    from ceph_tpu.models.matrix_codec import MatrixErasureCode
     out: dict[int, np.ndarray] = {}
-    if isinstance(codec, MatrixErasureCode) and not codec.chunk_mapping:
+    kind = flush_kind(codec)
+    if kind == "matrix":
         # position-wise codec: stripes fold into the byte axis
         parity = codec._matvec(codec.coding_matrix, data_shards)
         for i in want:
             out[i] = data_shards[i] if i < k else parity[i - k]
+    elif kind == "layered":
+        # sub-chunked codec: the math is position-wise along a
+        # sub-chunk, so S stripes re-laid plane-major are ONE chunk
+        # whose sub-chunks are S times as long: one codec call
+        ssc = codec.get_sub_chunk_count()
+        planes = _turn(data_shards, s, ssc)
+        parity = codec.encode_chunks(
+            [i for i in want if i >= k],
+            {j: planes[j] for j in range(k)})
+        for i in want:
+            out[i] = data_shards[i] if i < k else \
+                _turn(parity[i][None, :], ssc, s)[0]
     else:
         per_stripe = [codec.encode_chunks(
             want, {j: stripes[si, j] for j in range(k)}) for si in range(s)]
@@ -174,12 +190,29 @@ def decode(sinfo: StripeInfo, codec, shards: dict[int, np.ndarray],
     missing = [i for i in want if i not in shards]
     if not missing:
         return {i: np.asarray(shards[i], dtype=np.uint8) for i in want}
-    from ceph_tpu.models.matrix_codec import MatrixErasureCode
-    if isinstance(codec, MatrixErasureCode) and not codec.chunk_mapping:
+    kind = flush_kind(codec)
+    if kind == "matrix":
         # one kernel call across all stripes
         return codec.decode_chunks(
             want, {i: np.asarray(v, dtype=np.uint8)
                    for i, v in shards.items()})
+    if kind == "layered":
+        if device_layered(codec):
+            # one layered program over the whole batch
+            return decode_layered(sinfo, codec, shards, want)
+        # host twin: re-laid plane-major the S stripes are one chunk
+        # with S times longer sub-chunks (see encode): one codec call
+        ssc = codec.get_sub_chunk_count()
+        ids = sorted(shards)
+        planes = _turn(np.stack(
+            [np.asarray(shards[i], dtype=np.uint8) for i in ids]),
+            s, ssc)
+        got = codec.decode_chunks(
+            want, {i: planes[j] for j, i in enumerate(ids)})
+        return {i: np.asarray(shards[i], dtype=np.uint8)
+                if i in shards else
+                _turn(np.asarray(got[i])[None, :], ssc, s)[0]
+                for i in want}
     out = {i: np.zeros(s * cs, dtype=np.uint8) for i in want}
     for si in range(s):
         got = codec.decode_chunks(
@@ -349,6 +382,15 @@ class StripeBatcher:
             except Exception as exc:
                 self._note_fallback("mesh", exc)
                 # single-device fallback below
+        if device_layered(self.codec):
+            try:
+                return _flush_layered_async(
+                    self.sinfo, self.codec, ops, bufs,
+                    batch=preconcat, with_crcs=with_crcs)
+            except Exception as exc:
+                # the plain path below re-encodes the whole batch
+                # with one call of the codec (counted)
+                self._note_fallback("layered", exc)
         if with_crcs and _device_fusable(self.codec):
             try:
                 return _flush_device_fused_async(
@@ -400,10 +442,24 @@ _DEVICE_MATVEC = {"jax", "pallas"}
 _FUSE_CRC_MAX_SEG_BYTES = 256 << 20
 
 
+def flush_kind(codec) -> str | None:
+    """The capability a codec STATES to the engine's seams
+    (``device_flush``, models/base.py): ``"matrix"``: a batch is one
+    GF matrix over the byte stream; ``"layered"``: a batch is one
+    layered program over plane-major lanes (clay); None: stripe by
+    stripe on the host codec."""
+    return getattr(codec, "device_flush", None)
+
+
 def _device_fusable(codec) -> bool:
-    from ceph_tpu.models.matrix_codec import MatrixErasureCode
-    return (isinstance(codec, MatrixErasureCode)
-            and not codec.chunk_mapping
+    return (flush_kind(codec) == "matrix"
+            and getattr(codec, "backend", "") in _DEVICE_MATVEC)
+
+
+def device_layered(codec) -> bool:
+    """A layered codec whose profile names a device backend: its
+    flushes and reconstructs are one layered program each."""
+    return (flush_kind(codec) == "layered"
             and getattr(codec, "backend", "") in _DEVICE_MATVEC)
 
 
@@ -412,10 +468,22 @@ def host_flushable(codec) -> bool:
     codec: plain matrix codecs encode with one host matvec over the
     coding matrix (layered/chunk-mapped codecs keep their own encode
     path)."""
-    from ceph_tpu.models.matrix_codec import MatrixErasureCode
-    return (isinstance(codec, MatrixErasureCode)
-            and not codec.chunk_mapping
+    return (flush_kind(codec) == "matrix"
             and codec.coding_matrix is not None)
+
+
+def _turn(streams: np.ndarray, outer: int, inner: int) -> np.ndarray:
+    """``[c, outer*inner*sub]`` -> the same bytes with the two leading
+    axes of every row swapped. ``_turn(shards, S, ssc)``: S stored
+    chunks of ``ssc`` sub-chunks each become plane-major (sub-chunk z
+    of every stripe side by side), which a layered codec reads as ONE
+    chunk whose sub-chunks are S times as long; ``_turn(planes, ssc,
+    S)`` is the way back."""
+    c, n = streams.shape
+    sub = n // (outer * inner)
+    return np.ascontiguousarray(
+        streams.reshape(c, outer, inner, sub).transpose(0, 2, 1, 3)
+    ).reshape(c, n)
 
 
 _host_matvec_backend: str | None = None
@@ -475,9 +543,11 @@ def flush_host_async(sinfo: StripeInfo, codec, ops, bufs,
 def device_decodable(codec) -> bool:
     """Whether the daemon's batched DECODE path can take this codec:
     plain matrix codecs reconstruct with one signature-keyed matmul
-    (decode() above collapses to a single device launch); layered/
-    mapped codecs (clay, lrc) keep their host machinery."""
-    return _device_fusable(codec)
+    (decode() above collapses to a single device launch), a layered
+    codec (clay) with one layered program whose signature table is an
+    operand (:func:`decode_layered`); mapped codecs (lrc) keep their
+    host machinery."""
+    return _device_fusable(codec) or device_layered(codec)
 
 
 def fuse_crc_policy(codec) -> bool:
@@ -487,7 +557,7 @@ def fuse_crc_policy(codec) -> bool:
     amplification across many in-process OSDs thrashes the host —
     only when explicitly forced (CEPH_TPU_FUSE_CRC=1)."""
     import os
-    if not _device_fusable(codec):
+    if not device_decodable(codec):
         return False
     return codec.backend == "pallas" or \
         bool(os.environ.get("CEPH_TPU_FUSE_CRC"))
@@ -650,9 +720,6 @@ def fused_program(codec, n_b: int, lmax_b: int, nops_b: int):
     (tests/test_chip_compile.py) build the SAME function."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
-    from ceph_tpu.ops import crc32c_device as cd
-    n_chunks = codec.get_chunk_count()
     key = (codec.backend, codec.coding_matrix.tobytes(),
            n_b, lmax_b, nops_b)
     fn = _fused_cache.get(key)
@@ -673,24 +740,178 @@ def fused_program(codec, n_b: int, lmax_b: int, nops_b: int):
             parity = dev.matvec_device(mat, data)
             shards = jnp.concatenate(
                 [data, parity.astype(jnp.uint8)], axis=0)
-
-        def seg(off, ln):
-            # window ENDING at the segment end; bytes before the
-            # segment (neighbour ops / padding) masked to zero
-            win = lax.dynamic_slice(
-                padded, (0, off + ln), (n_chunks, lmax_b))
-            mask = jnp.arange(lmax_b) >= (lmax_b - ln)
-            return win * mask.astype(jnp.uint8)
-
-        with jax.named_scope("crc_windows"):
-            padded = jnp.pad(shards, ((0, 0), (lmax_b, 0)))
-            segs = jax.vmap(seg)(offs, seg_lens)
-            lin = cd.crc_linear_device(
-                segs.reshape(nops_b * n_chunks, lmax_b))
-        return parity, lin
+        return parity, _crc_windows(shards, offs, seg_lens, lmax_b)
 
     fn = _fused_cache[key] = jax.jit(fused)
     return fn, True
+
+
+def _crc_windows(shards, offs, seg_lens, lmax_b: int):
+    """Inside a flush program: every op's per-shard LINEAR crc part
+    from the device-resident ``shards [n_chunks, n_b]`` in stored
+    order. Per-op segment boundaries are dynamic inputs; a fixed-width
+    window ENDING at each segment's end is cut, and the bytes before
+    the segment (neighbour ops, padding) masked to zero (free under
+    crc linearity). Returns ``[nops_b * n_chunks]``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from ceph_tpu.ops import crc32c_device as cd
+    n_chunks = shards.shape[0]
+    nops_b = offs.shape[0]
+
+    def seg(off, ln):
+        win = lax.dynamic_slice(
+            padded, (0, off + ln), (n_chunks, lmax_b))
+        mask = jnp.arange(lmax_b) >= (lmax_b - ln)
+        return win * mask.astype(jnp.uint8)
+
+    with jax.named_scope("crc_windows"):
+        padded = jnp.pad(shards, ((0, 0), (lmax_b, 0)))
+        segs = jax.vmap(seg)(offs, seg_lens)
+        return cd.crc_linear_device(
+            segs.reshape(nops_b * n_chunks, lmax_b))
+
+
+def layered_program(codec, chunk_size: int, s_b: int, lmax_b: int,
+                    nops_b: int, with_crcs: bool = True):
+    """The jitted flush program of a layered (sub-chunked) codec for
+    one bucketed batch signature: ``fn(batch [s_b * k * chunk_size]
+    uint8, offs [nops_b], seg_lens [nops_b]) -> (parity [m, s_b *
+    chunk_size], crc linear parts [nops_b * (k+m)] | None)``.
+
+    ``batch`` is the staged payload AS THE STAGER HOLDS IT: ``s_b``
+    stripes of k chunks of ``ssc`` sub-chunks. On the device it is
+    turned to plane-major lanes ``[k, ssc, s_b * sub]``, runs the
+    codec's layered encode (``codec.flush_encoder()``:
+    uncouple, the plane-wise solves, recouple), is turned back to the
+    m parity shards in stored order, and the linear crc parts of all
+    k+m shards of every op come from the same device-resident bytes.
+    Returns ``(fn, is_new)``; cached per (profile, buckets)."""
+    import jax
+    import jax.numpy as jnp
+    k = codec.get_data_chunk_count()
+    m = codec.get_chunk_count() - k
+    ssc = codec.get_sub_chunk_count()
+    sub = chunk_size // ssc
+    key = ("layered", codec.flush_key(), chunk_size, s_b, lmax_b,
+           nops_b, with_crcs)
+    fn = _fused_cache.get(key)
+    if fn is not None:
+        return fn, False
+    if len(_fused_cache) > 256:
+        _fused_cache.clear()
+    encode = codec.flush_encoder()
+    n_b = s_b * chunk_size
+
+    def layered(batch, offs, seg_lens):
+        x = batch.reshape(s_b, k, ssc, sub)
+        with jax.named_scope("relayout"):
+            planes = x.transpose(1, 2, 0, 3).reshape(k, ssc, s_b * sub)
+        with jax.named_scope("encode"):
+            par = encode(planes).astype(jnp.uint8)
+        with jax.named_scope("relayout"):
+            parity = par.reshape(m, ssc, s_b, sub).transpose(
+                0, 2, 1, 3).reshape(m, n_b)
+        if not with_crcs:
+            return parity, None
+        with jax.named_scope("relayout"):
+            data = x.transpose(1, 0, 2, 3).reshape(k, n_b)
+            shards = jnp.concatenate([data, parity], axis=0)
+        return parity, _crc_windows(shards, offs, seg_lens, lmax_b)
+
+    fn = _fused_cache[key] = jax.jit(layered)
+    return fn, True
+
+
+def layered_decode_program(codec, chunk_size: int, n_b: int, e: int):
+    """The jitted decode program of a layered codec for one shape
+    bucket: ``fn(survivors [k, n_b] uint8 in stored order, table) ->
+    [e, n_b]`` rebuilt shards in stored order. ``table`` (the erasure
+    signature's, ``codec.flush_decode_table``) is an OPERAND,
+    so one compiled program serves every signature. Returns ``(fn,
+    is_new)``."""
+    import jax
+    k = codec.get_data_chunk_count()
+    ssc = codec.get_sub_chunk_count()
+    sub = chunk_size // ssc
+    s_b = n_b // chunk_size
+    key = ("layered_dec", codec.flush_key(), chunk_size, n_b, e)
+    fn = _fused_cache.get(key)
+    if fn is not None:
+        return fn, False
+    if len(_fused_cache) > 256:
+        _fused_cache.clear()
+
+    def layered_decode(survivors, table):
+        with jax.named_scope("relayout"):
+            planes = survivors.reshape(k, s_b, ssc, sub).transpose(
+                0, 2, 1, 3).reshape(k * ssc, s_b * sub)
+        with jax.named_scope("decode"):
+            rec = codec.flush_decode(table, planes)
+        with jax.named_scope("relayout"):
+            return rec.reshape(e, ssc, s_b, sub).transpose(
+                0, 2, 1, 3).reshape(e, n_b)
+
+    fn = _fused_cache[key] = jax.jit(layered_decode)
+    return fn, True
+
+
+def decode_signature(codec, shards, want) -> tuple[tuple, tuple]:
+    """(present, missing) of a layered reconstruct: the first k
+    surviving chunks in order (any k rebuild the same bytes; taking
+    the first k keeps the table stable per erasure signature) and the
+    wanted chunks that are not there."""
+    k = codec.get_data_chunk_count()
+    return (tuple(sorted(shards)[:k]),
+            tuple(i for i in want if i not in shards))
+
+
+def signature_table(codec, present: tuple, missing: tuple):
+    """``(table, built)``: the operand of :func:`decode_layered` for
+    one erasure signature, from the process-wide cache or built now
+    (the caller counts and times a build)."""
+    return codec.flush_decode_table(present, missing)
+
+
+def decode_layered(sinfo: StripeInfo, codec,
+                   shards: dict[int, np.ndarray], want: list[int],
+                   table=None) -> dict[int, np.ndarray]:
+    """Batched reconstruct of a layered codec on the device: the
+    survivors of every op of the batch, concatenated per shard, go
+    through ONE program (:func:`layered_decode_program`), pow2-
+    bucketed on the byte axis. ``table``: the signature's operand when
+    the caller already holds it (the engine, which counts builds)."""
+    cs = sinfo.chunk_size
+    k = codec.get_data_chunk_count()
+    present, missing = decode_signature(codec, shards, want)
+    out = {i: np.asarray(shards[i], dtype=np.uint8)
+           for i in want if i in shards}
+    if not missing:
+        return out
+    if len(present) < k:
+        raise ErasureCodeError(
+            f"layered decode: {len(present)} survivors < k={k}",
+            errno_=5)
+    n = len(np.asarray(shards[present[0]]))
+    if n % cs:
+        raise ErasureCodeError(
+            f"decode: shard length {n} not a multiple of {cs}")
+    if table is None:
+        table, _built = signature_table(codec, present, missing)
+    n_b = _pow2_bucket(n, 1 << 14)
+    fn, _new = layered_decode_program(codec, cs, n_b, len(missing))
+    survivors = np.empty((k, n_b), dtype=np.uint8)
+    survivors[:, n:] = 0
+    for row, c in enumerate(present):
+        survivors[row, :n] = np.asarray(shards[c], dtype=np.uint8)
+    from ceph_tpu.utils.device_telemetry import telemetry
+    rec = np.asarray(telemetry().timed_call(
+        f"layered_dec[{codec.backend} k{k}e{len(missing)}]N{n_b}",
+        fn, survivors, table))
+    for row, c in enumerate(missing):
+        out[c] = rec[row, :n]
+    return out
 
 
 def fused_buckets(n_bytes: int, max_len: int, n_ops: int
@@ -702,6 +923,35 @@ def fused_buckets(n_bytes: int, max_len: int, n_ops: int
     return (_pow2_bucket(n_bytes, 1 << 14),
             _pow2_bucket(max_len, max(cd.ROW_BYTES, 1 << 12)),
             _pow2_bucket(n_ops, 1))
+
+
+def _segments(lens: list[int], nops_b: int
+              ) -> tuple[np.ndarray, np.ndarray]:
+    """The per-op (offset, length) operands of a flush program, per
+    shard in bytes, zero beyond the batch's ops."""
+    offs_arr = np.zeros(nops_b, dtype=np.int32)
+    offs_arr[:len(lens)] = np.cumsum([0] + lens[:-1])
+    lens_arr = np.zeros(nops_b, dtype=np.int32)
+    lens_arr[:len(lens)] = lens
+    return offs_arr, lens_arr
+
+
+def _per_op(ops, lens, data_shards, parity, lin) -> list:
+    """``[(op_id, shards, crcs)]`` of a fused flush: every op's slice
+    of the k data and m parity streams, and its row of the crc linear
+    parts (``lin [nops_b, k+m]`` or None)."""
+    k = data_shards.shape[0]
+    results = []
+    off = 0
+    for idx, (op_id, ln) in enumerate(zip(ops, lens)):
+        shards = {i: data_shards[i, off:off + ln] for i in range(k)}
+        for j in range(parity.shape[0]):
+            shards[k + j] = parity[j, off:off + ln]
+        crcs = None if lin is None else \
+            {i: int(v) for i, v in enumerate(lin[idx])}
+        results.append((op_id, shards, crcs))
+        off += ln
+    return results
 
 
 def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
@@ -720,7 +970,6 @@ def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
     cs, sw = sinfo.chunk_size, sinfo.stripe_width
     k = codec.get_data_chunk_count()
     n_chunks = codec.get_chunk_count()
-    m = n_chunks - k
     lens = [len(b) // sw * cs for b in bufs]
     if batch is None:
         batch = np.concatenate(bufs)
@@ -739,10 +988,7 @@ def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
         data_dev[:, :n_bytes] = data_shards
     else:
         data_dev = data_shards
-    offs_arr = np.zeros(nops_b, dtype=np.int32)
-    offs_arr[:len(ops)] = np.cumsum([0] + lens[:-1])
-    lens_arr = np.zeros(nops_b, dtype=np.int32)
-    lens_arr[:len(ops)] = lens
+    offs_arr, lens_arr = _segments(lens, nops_b)
     from ceph_tpu.utils.device_telemetry import telemetry
     signature = (f"fused_crc[{codec.backend}"
                  f"{list(codec.coding_matrix.shape)}]"
@@ -766,22 +1012,69 @@ def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
         parity, lin = _phase(
             "flush_download", len(ops), batch.nbytes,
             lambda: (np.asarray(parity_dev), np.asarray(lin_dev)))
-        lin = lin.reshape(nops_b, n_chunks)
-        results = []
-        off = 0
-        for idx, (op_id, ln) in enumerate(zip(ops, lens)):
-            shards = {i: data_shards[i, off:off + ln]
-                      for i in range(k)}
-            for j in range(m):
-                shards[k + j] = parity[j, off:off + ln]
-            crcs = {i: int(lin[idx, i]) for i in range(n_chunks)}
-            results.append((op_id, shards, crcs))
-            off += ln
-        return results
+        return _per_op(ops, lens, data_shards, parity,
+                       lin.reshape(nops_b, n_chunks))
 
     # expose the compiled program + staged host inputs for harnesses
     # (bench/engine_loop.py measures THIS exact program — reaching
     # into the cache with a hand-copied key would silently drift)
     finalize.fused_fn = fn
     finalize.staged = (data_dev, offs_arr, lens_arr)
+    return finalize
+
+
+def _flush_layered_async(sinfo: StripeInfo, codec, ops, bufs,
+                         batch=None, with_crcs: bool = True):
+    """One :func:`layered_program` per bucketed batch signature for a
+    sub-chunked codec: the staged batch is uploaded AS STAGED (no host
+    re-layout before the launch), the device turns it plane-major,
+    encodes, turns the parity back and takes every op's crc parts.
+    The k data shards' stored form (the transposition the matrix route
+    makes before its launch) is built at finalize, on the retire
+    thread, while the device runs. Same ``finalize() -> results``
+    contract as :func:`_flush_device_fused_async`."""
+    cs, sw = sinfo.chunk_size, sinfo.stripe_width
+    k = codec.get_data_chunk_count()
+    n_chunks = codec.get_chunk_count()
+    m = n_chunks - k
+    lens = [len(b) // sw * cs for b in bufs]
+    if batch is None:
+        batch = np.concatenate(bufs)
+    s = len(batch) // sw
+    n_bytes = s * cs
+    n_b, lmax_b, nops_b = fused_buckets(n_bytes, max(lens), len(ops))
+    s_b = n_b // cs
+    if nops_b * n_chunks * lmax_b > _FUSE_CRC_MAX_SEG_BYTES:
+        with_crcs = False          # the backend hashes on the host
+    fn, _new = layered_program(codec, cs, s_b, lmax_b, nops_b,
+                               with_crcs)
+    if s_b != s:
+        staged = np.empty(s_b * sw, dtype=np.uint8)
+        staged[:len(batch)] = batch
+        staged[len(batch):] = 0
+    else:
+        staged = batch
+    offs_arr, lens_arr = _segments(lens, nops_b)
+    from ceph_tpu.utils.device_telemetry import telemetry
+    signature = (f"layered[{codec.backend} k{k}m{m}"
+                 f"{'crc' if with_crcs else ''}]"
+                 f"S{s_b}L{lmax_b}ops{nops_b}")
+    parity_dev, lin_dev = _phase(
+        "flush_launch", len(ops), batch.nbytes,
+        telemetry().timed_call, signature, fn, staged, offs_arr,
+        lens_arr)
+
+    def finalize():
+        data_shards = np.ascontiguousarray(
+            batch.reshape(s, k, cs).transpose(1, 0, 2)
+        ).reshape(k, n_bytes)
+        parity, lin = _phase(
+            "flush_download", len(ops), batch.nbytes,
+            lambda: (np.asarray(parity_dev),
+                     None if lin_dev is None else
+                     np.asarray(lin_dev).reshape(nops_b, n_chunks)))
+        return _per_op(ops, lens, data_shards, parity, lin)
+
+    #: the engine counts the ops of such a flush (layered_encode_ops)
+    finalize.layered = True
     return finalize

@@ -2569,6 +2569,9 @@ class OSD:
         log(1, f"{pg}: peered, authority pos {auth_pos} v{auth_lv}, "
             f"missing={ {p: len(m) for p, m in pg.peer_missing.items()} }")
         self._flush_waiting(pg)
+        # what the backend can make ready now that the primary knows
+        # the PG's holes (a layered codec's decode table)
+        pg.backend.on_peered(pg)
         if pg.peer_missing:
             self.op_wq.enqueue(pg.pgid, lambda: self._recover(pg),
                                qos=QOS_RECOVERY)
@@ -3062,10 +3065,8 @@ class OSD:
             # nor clear entries the new peering computed
             acting = list(pg.acting)
             epoch = pg.epoch
-        try:
-            self._recover_work(pg, work, acked_by_pos, acting, epoch,
-                               truncated_pos=truncated_pos)
-        finally:
+
+        def finish() -> None:
             with pg.lock:
                 pg.recovery_in_flight = False
             self._unreserve_recovery()
@@ -3076,13 +3077,32 @@ class OSD:
                 self.op_wq.enqueue(pg.pgid,
                                    lambda: self._recover(pg),
                                    qos=QOS_RECOVERY)
+
+        try:
+            self._recover_work(pg, work, acked_by_pos, acting, epoch,
+                               truncated_pos=truncated_pos,
+                               finish=finish)
+        except BaseException:
+            finish()
+            raise
         return acked_by_pos
 
     def _recover_work(self, pg: PG, work: dict[int, dict[str, int]],
                       acked_by_pos: dict[int, list[str]],
                       acting: list[int], epoch: int,
-                      truncated_pos: set[int] | None = None) -> None:
+                      truncated_pos: set[int] | None = None,
+                      finish: Callable[[], None] | None = None
+                      ) -> None:
+        """Build and send the round's pushes, then RETURN: the round
+        is closed by a continuation (``_recover_close``, a new item on
+        this PG's wq shard) when the last push reply has come or
+        ``2 * SUBOP_TIMEOUT`` has passed. No op-wq worker blocks on
+        push replies: a blocked worker could not handle the pushes
+        OTHER rounds sent to this OSD on the same shard index, and
+        rounds of different OSDs waited for each other in chains and
+        rings that only the timeout broke (PERF.md, PR 28 and 29)."""
         unrebuildable: dict[str, int] = {}    # oid -> wanted version
+        sent: list[tuple[int, int, SubOpWait, dict[str, int]]] = []
         for pos, missing in work.items():
             osd = acting[pos] if pos < len(acting) else -1
             if osd < 0:
@@ -3090,6 +3110,7 @@ class OSD:
             tid = self.new_tid()
             wait = SubOpWait(set(missing))
             self.register_wait(tid, wait)
+            sent.append((pos, tid, wait, missing))
             # build the round's pushes CONCURRENTLY: shard-read fan-
             # outs overlap their network round trips, and the decode
             # of every reconstruct lands in the device engine inside
@@ -3126,35 +3147,84 @@ class OSD:
                     pg.rollback_pending.pop(oid, None)
                 _TP_RECOVERY_PUSH(oid, pos, version)
                 if osd == self.whoami:
-                    # apply inline (we run on this PG's wq thread; the
-                    # self-reply completes the wait synchronously)
+                    # apply inline (the self-reply completes the
+                    # entry synchronously)
                     self._handle_pg_push(push, _SelfConn(self))
                 else:
                     self.send_osd(osd, push)
-            replies = wait.wait(SUBOP_TIMEOUT * 2)
-            self.unregister_wait(tid)
-            acked = [oid for oid, rep in replies.items()
-                     if getattr(rep, "committed", False)]
-            acked_by_pos[pos] = acked
-            # the shard's pgmeta only advances once every pushed object
-            # is acked durable — a lost push leaves it visibly behind,
-            # so the next peering retries instead of trusting it.
-            # A position truncated by the round cap can never
-            # log-sync yet: objects beyond the cap are still missing.
-            if set(acked) == set(missing) and \
-                    pos not in (truncated_pos or ()):
-                self._log_sync_shard(pg, pos, acked, acting, epoch)
-            elif acked:
-                with pg.lock:
-                    if pg.epoch == epoch:
-                        m = pg.peer_missing.get(pos)
-                        if m:
-                            for oid in acked:
-                                m.pop(oid, None)
-                log(1, f"{pg}: pos {pos} partial recovery "
-                    f"({len(acked)}/{len(missing)}), log-sync deferred")
-        if unrebuildable:
-            self._try_rollback(pg, unrebuildable, acting, epoch)
+
+        # close the round ONCE: by the last reply of the last
+        # position, or by the timer
+        gate = {"left": len(sent), "closed": False}
+        gate_lock = threading.Lock()
+        timer: list = []
+
+        def close() -> None:
+            with gate_lock:
+                if gate["closed"]:
+                    return
+                gate["closed"] = True
+            if timer:
+                timer[0].cancel()
+            self.op_wq.enqueue(
+                pg.pgid,
+                lambda: self._recover_close(
+                    pg, sent, acked_by_pos, acting, epoch,
+                    truncated_pos, unrebuildable, finish),
+                qos=QOS_RECOVERY)
+
+        def one_done() -> None:
+            with gate_lock:
+                gate["left"] -= 1
+                last = gate["left"] <= 0
+            if last:
+                close()
+
+        if not sent:
+            close()
+            return
+        timer.append(threading.Timer(SUBOP_TIMEOUT * 2, close))
+        timer[0].daemon = True
+        timer[0].start()
+        for _pos, _tid, wait, _missing in sent:
+            wait.when_done(one_done)
+
+    def _recover_close(self, pg: PG, sent, acked_by_pos, acting,
+                       epoch, truncated_pos, unrebuildable,
+                       finish) -> None:
+        """The end of a recovery round, on the PG's wq shard: what the
+        push replies that came say, position by position."""
+        try:
+            for pos, tid, wait, missing in sent:
+                self.unregister_wait(tid)
+                replies = wait.snapshot()
+                acked = [oid for oid, rep in replies.items()
+                         if getattr(rep, "committed", False)]
+                acked_by_pos[pos] = acked
+                # the shard's pgmeta only advances once every pushed
+                # object is acked durable — a lost push leaves it
+                # visibly behind, so the next peering retries instead
+                # of trusting it. A position truncated by the round
+                # cap can never log-sync yet: objects beyond the cap
+                # are still missing.
+                if set(acked) == set(missing) and \
+                        pos not in (truncated_pos or ()):
+                    self._log_sync_shard(pg, pos, acked, acting, epoch)
+                elif acked:
+                    with pg.lock:
+                        if pg.epoch == epoch:
+                            m = pg.peer_missing.get(pos)
+                            if m:
+                                for oid in acked:
+                                    m.pop(oid, None)
+                    log(1, f"{pg}: pos {pos} partial recovery "
+                        f"({len(acked)}/{len(missing)}), log-sync "
+                        "deferred")
+            if unrebuildable:
+                self._try_rollback(pg, unrebuildable, acting, epoch)
+        finally:
+            if finish is not None:
+                finish()
 
     def _try_rollback(self, pg: PG, failed: dict[str, int],
                       acting: list[int], epoch: int) -> None:

@@ -66,24 +66,53 @@ def user_xattrs(attrs: dict[str, bytes]) -> dict[str, bytes]:
 
 
 class SubOpWait:
-    """Blocking rendezvous for a read fan-out."""
+    """Rendezvous for a fan-out: blocking (:meth:`wait`, a read
+    fan-out) or by continuation (:meth:`when_done`, a recovery
+    round's push replies: no worker blocks on them)."""
 
     def __init__(self, expected: set[int]) -> None:
         self.lock = make_lock("pg_backend.subop_wait")
         self.cond = make_condition("pg_backend.subop_wait", self.lock)
         self.pending: set[int] = set(expected)
         self.results: dict[int, object] = {}
+        self._on_done: Callable[[], None] | None = None
+
+    def _settle(self) -> Callable[[], None] | None:
+        """Caller holds ``self.lock``: the continuation to run (once)
+        when nothing is pending any more."""
+        self.cond.notify_all()
+        if self.pending or self._on_done is None:
+            return None
+        fn, self._on_done = self._on_done, None
+        return fn
 
     def complete(self, shard: int, result: object) -> None:
         with self.lock:
             self.results[shard] = result
             self.pending.discard(shard)
-            self.cond.notify_all()
+            fn = self._settle()
+        if fn is not None:
+            fn()
 
     def drop(self, shard: int) -> None:
         with self.lock:
             self.pending.discard(shard)
-            self.cond.notify_all()
+            fn = self._settle()
+        if fn is not None:
+            fn()
+
+    def when_done(self, fn: Callable[[], None]) -> None:
+        """Run ``fn`` once, on whichever thread completes the last
+        pending entry (at once, here, when none is pending)."""
+        with self.lock:
+            self._on_done = fn
+            fn = self._settle()
+        if fn is not None:
+            fn()
+
+    def snapshot(self) -> dict[int, object]:
+        with self.lock:
+            return dict(self.results)
 
     def wait(self, timeout: float = SUBOP_TIMEOUT) -> dict[int, object]:
         with self.lock:
@@ -314,6 +343,10 @@ class PGBackend:
 
     def min_size_ok(self, pg: PG) -> bool:
         return len(self.up_positions(pg)) >= self.pool.min_size
+
+    def on_peered(self, pg: PG) -> None:
+        """The primary has peered ``pg`` and knows which positions are
+        up: make ready what reads of this acting set will need."""
 
 
 def object_write_txn(cid: str, oid: str, data: bytes, version: int,

@@ -7,8 +7,9 @@ here first, at no chip time: the GF matvec at the encode/decode
 matrices of the served profiles, the crc row kernel, the fused
 encode+crc flush program at the buckets ``chip_smoke.py`` really
 produces, the XOR-schedule encode, one block-sparse plan and the Clay
-k=8,m=4,d=11 encode and layered-repair kernels, and the mesh steps for
-the 2x2 mesh. A compile that passes is not a chip run: nothing
+k=8,m=4,d=11 encode and layered-repair kernels, the Clay pool's served
+flush programs (layered encode+crc, table-operand decode) at the cells'
+buckets, and the mesh steps for the 2x2 mesh. A compile that passes is not a chip run: nothing
 executes, so this says nothing about results or times.
 
 One file, on purpose: the process that describes the topology holds
@@ -231,6 +232,57 @@ def test_clay_repair_kernel(one_chip, as_tpu):
     _, text = _compile(
         fn, _u8((qt, codec.sub_chunk_no, 2048), one_chip))
     assert "tpu_custom_call" in text
+
+
+# -- the Clay pool's served flush programs (ISSUE 29) --------------------
+
+def _clay_served():
+    return _codec("clay", k=8, m=4, d=11, scalar_mds="jerasure",
+                  technique="reed_sol_van", backend="pallas")
+
+
+@pytest.mark.parametrize("n_ops", [1, 16])
+def test_clay_layered_flush_program(one_chip, as_tpu, n_ops):
+    """The one program a Clay flush is: the staged batch of ``n_ops``
+    4 MiB objects turned plane-major on the device, the layered encode
+    kernel, the parity turned back, the crc rows of all 12 shards; at
+    the smallest and the largest bucket the write cell's warm-up
+    bursts compile."""
+    from ceph_tpu.osd import ec_util
+    codec, op_len, unit = _clay_served(), 512 << 10, 4096
+    n_b, lmax_b, nops_b = ec_util.fused_buckets(
+        n_ops * op_len, op_len, n_ops)
+    fn, _new = ec_util.layered_program(codec, unit, n_b // unit,
+                                       lmax_b, nops_b, True)
+    idx = jax.ShapeDtypeStruct((nops_b,), jnp.int32,
+                               sharding=one_chip)
+    compiled, text = _compile(fn, _u8((n_b * 8,), one_chip), idx, idx)
+    # the layered encode kernel and the crc rows, in one program
+    assert text.count("tpu_custom_call") >= 2
+    assert "clay_encode" in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    # three flushes in flight must fit the chip's 16 GB with room
+    assert total < 4 << 30, (n_ops, total)
+
+
+@pytest.mark.parametrize("n_ops,lost", [(1, 1), (16, 2)])
+def test_clay_layered_decode_program(one_chip, n_ops, lost):
+    """One decode program a shape bucket: the signature's table is an
+    operand (int8 bit matrix), so nothing of a signature is compiled
+    in; one lost shard at one read, two at sixteen."""
+    from ceph_tpu.osd import ec_util
+    codec, unit = _clay_served(), 4096
+    n_b = ec_util._pow2_bucket(n_ops * (512 << 10), 1 << 14)
+    fn, _new = ec_util.layered_decode_program(codec, unit, n_b, lost)
+    table = jax.ShapeDtypeStruct((8 * lost * 64, 8 * 8 * 64), jnp.int8,
+                                 sharding=one_chip)
+    compiled, _text = _compile(fn, _u8((8, n_b), one_chip), table)
+    mem = compiled.memory_analysis()
+    # the rebuilt shards alone come back (rows padded to the tiling)
+    assert lost * n_b <= mem.output_size_in_bytes <= 4 * n_b
+    assert mem.temp_size_in_bytes < 2 << 30, (n_ops, lost)
 
 
 # -- the 2x2 mesh: what chip_smoke.py --chips 4 runs ---------------------
