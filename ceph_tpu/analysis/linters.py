@@ -221,7 +221,8 @@ def check_wire_symmetry(src: SourceFile) -> list[Finding]:
                             and isinstance(item.value, ast.Constant):
                         mtype = item.value.value
             elif isinstance(item, ast.FunctionDef):
-                if item.name == "encode_payload":
+                if item.name in ("encode_payload",
+                                 "encode_payload_parts"):
                     encode_fn = item
                 elif item.name == "decode_payload":
                     decode_fn = item

@@ -366,14 +366,14 @@ class ECBackend(PGBackend):
                         lambda items, osd=osd:
                         self._ship_subwrite_batch(osd, items),
                         (tid, pg.pool, pg.ps, pos, oid, version,
-                         txn.encode(), child.wire(), epoch,
+                         txn.encode_parts(), child.wire(), epoch,
                          op_clock is not stage_clock.NOOP,
                          _flows.current_flow() or ""))
                 else:
                     sub = M.MECSubWrite(
                         tid=tid, pool=pg.pool, ps=pg.ps, shard=pos,
                         epoch=epoch, oid=oid, version=version,
-                        txn_bytes=txn.encode(), trace=child.wire(),
+                        txn_bytes=txn.encode_parts(), trace=child.wire(),
                         flow=_flows.current_flow() or "")
                     if op_clock is not stage_clock.NOOP:
                         # child timeline anchor: handed to the

@@ -417,7 +417,7 @@ class ReplicatedBackend(PGBackend):
                 self.parent.send_osd(osd, M.MECSubWrite(
                     tid=tid, pool=pg.pool, ps=pg.ps, shard=pos,
                     epoch=epoch, oid=oid, version=entry.version,
-                    txn_bytes=txn.encode(), trace=child.wire(),
+                    txn_bytes=txn.encode_parts(), trace=child.wire(),
                     flow=_flows.current_flow() or ""))
                 child.finish()
 

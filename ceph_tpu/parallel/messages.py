@@ -50,11 +50,6 @@ _REGISTRY: dict[int, type] = {}
 class Message:
     MSG_TYPE = 0
     FIELDS: list[tuple[str, str]] = []
-    #: name of a ``bytes_list`` field whose payloads dominate the
-    #: frame (bulk batch messages): ``encode_payload_parts`` passes
-    #: them through by reference instead of re-copying into one blob
-    #: (scatter-gather serialize, ROADMAP 1c)
-    BULK_FIELD: str | None = None
 
     def __init__(self, **kw) -> None:
         self.seq = 0
@@ -79,42 +74,22 @@ class Message:
                     f"{existing.__name__}")
             _REGISTRY[cls.MSG_TYPE] = cls
 
-    def encode_payload(self) -> bytes:
+    def encode_payload_parts(self) -> list[bytes]:
+        """Scatter-gather serialization: the payload as a buffer list
+        whose concatenation == ``encode_payload()`` byte for byte
+        (pinned in tests/test_encoding_sections.py). The ``Encoder``
+        joins runs of small fields and leaves every large value (a
+        write's data, a shard, an encoded transaction's parts) a part
+        of its own, by reference: a ping is one part, a bulk message
+        its few header runs around its payloads. The messenger writes
+        the parts and crc-chains across them, or joins them once."""
         body = Encoder()
         for name, kind in self.FIELDS:
             _ENC[kind](body, getattr(self, name))
-        e = Encoder()
-        e.section(1, body)
-        return e.getvalue()
+        return Encoder().section(1, body).getparts()
 
-    def encode_payload_parts(self) -> list[bytes]:
-        """Scatter-gather serialization: the payload as a buffer
-        list whose concatenation == ``encode_payload()`` byte for
-        byte (pinned in tests/test_messenger.py), with the
-        ``BULK_FIELD`` payloads passed through by reference — no
-        re-copy of chunk data into one contiguous blob. The
-        messenger writes the parts and crc-chains across them; only
-        messages that declare a bulk field pay the parts machinery."""
-        bulk = self.BULK_FIELD
-        if not bulk:
-            return [self.encode_payload()]
-        body = Encoder()
-        for name, kind in self.FIELDS:
-            if name == bulk:
-                vals = getattr(self, name)
-                body.u32(len(vals))
-                for v in vals:
-                    body.u32(len(v))
-                    body.raw(v)
-            else:
-                _ENC[kind](body, getattr(self, name))
-        # ENCODE_START framing over the uncopied body (the byte-
-        # identical twin of Encoder.section)
-        hdr = Encoder()
-        hdr.u8(1)
-        hdr.u8(1)
-        hdr.u32(body.nbytes())
-        return hdr.getparts() + body.getparts()
+    def encode_payload(self) -> bytes:
+        return b"".join(self.encode_payload_parts())
 
     @classmethod
     def decode_payload(cls, buf: bytes) -> "Message":
@@ -319,10 +294,6 @@ class MOSDOpBatch(Message):
               # must never be lost to batching (ISSUE 20)
               ("flows", "str_list")]
 
-    #: scatter-gather framing (ROADMAP 1c): ship ``datas`` payloads
-    #: as their own frame parts instead of re-copying into one blob
-    BULK_FIELD = "datas"
-
 
 class MOSDOpReplyBatch(Message):
     """One ack for every op an MOSDOpBatch carried: entry i answers
@@ -513,10 +484,6 @@ class MECSubWriteBatch(Message):
               # batches many tenants' sub-writes; the receiving shard
               # attributes each entry's txn bytes to its own flow
               ("flows", "str_list")]
-
-    #: scatter-gather framing (ROADMAP 1c): the shard txns ship as
-    #: their own frame parts — no re-copy into one contiguous payload
-    BULK_FIELD = "txns"
 
 
 class MECSubWriteBatchReply(Message):

@@ -473,15 +473,16 @@ class Messenger:
         # one join: the loopback decode needs a contiguous buffer
         # anyway (scatter-gather pays off on the real wire below)
         payload = b"".join(msg.encode_payload_parts())
+        serialize_s = time.monotonic() - t_pick
         self._seq += 1
         mtype = msg.MSG_TYPE
-        tel.note_send(mtype, len(payload) + _HDR.size,
-                      time.monotonic() - t_pick, 0.0)
+        tel.note_send(mtype, len(payload) + _HDR.size, serialize_s, 0.0)
         # wire framing ledger (ISSUE 14): the loopback pays no frame
         # header/meta/crc — overhead here is the header-equivalent
         tel.note_framing(len(payload), len(payload) + _HDR.size,
                          loopback=True,
                          is_batch=mtype in _BATCH_TYPES)
+        t_decode = time.monotonic()
         try:
             m2 = decode_message(mtype, payload)
         except Exception as exc:
@@ -492,6 +493,8 @@ class Messenger:
         m2.seq = self._seq
         m2._rx_t = time.monotonic()
         tel.note_recv(mtype, len(payload))
+        tel.note_loopback_codec(mtype, len(payload),
+                                serialize_s + m2._rx_t - t_decode)
         conn = _LoopbackConnection(peer, self.entity_name, self.addr)
         try:
             # deliver on the RECEIVER's event loop — the exact thread

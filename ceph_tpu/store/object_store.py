@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Callable, Iterator
 
 from ceph_tpu.analysis.lock_witness import make_condition, make_lock
-from ceph_tpu.utils.encoding import Decoder, Encoder
+from ceph_tpu.utils.encoding import Decoder, Encoder, Parts
 
 
 def group_commit_enabled() -> bool:
@@ -232,7 +232,9 @@ class Transaction:
         return len(self.ops)
 
     # -- wire ---------------------------------------------------------
-    def encode(self) -> bytes:
+    def encode_parts(self) -> Parts:
+        """The encoding as its scatter list (a write's data by
+        reference), for a sender that nests it in a message."""
         body = Encoder()
 
         def enc_op(e: Encoder, op: tuple) -> None:
@@ -260,9 +262,10 @@ class Transaction:
                 e.str(op[3])
 
         body.list(self.ops, enc_op)
-        e = Encoder()
-        e.section(1, body)
-        return e.getvalue()
+        return Parts(Encoder().section(1, body).getparts())
+
+    def encode(self) -> bytes:
+        return bytes(self.encode_parts())
 
     @classmethod
     def decode(cls, buf: bytes) -> "Transaction":

@@ -14,38 +14,76 @@ from __future__ import annotations
 
 import struct
 
+_U8 = struct.Struct("<B")
+_U16 = struct.Struct("<H")
+_U32 = struct.Struct("<I")
+_U64 = struct.Struct("<Q")
+_I32 = struct.Struct("<i")
+_I64 = struct.Struct("<q")
+_F64 = struct.Struct("<d")
+
+#: a part at least this long stays a buffer of its own in the scatter
+#: list; shorter ones are joined into the run around them. Under it a
+#: copy costs less than what every further part costs the sender (a
+#: list entry, a crc call, a socket write); above it the copy is what
+#: a bulk message pays for.
+SCATTER_MIN = 16 * 1024
+
 
 class Encoder:
+    """Builds an encoding as a scatter list: runs of small pieces,
+    joined when the run closes, and every piece of ``SCATTER_MIN``
+    bytes or more by reference. ``getvalue()`` is the one join."""
+
     def __init__(self) -> None:
-        self._parts: list[bytes] = []
+        self._parts: list[bytes] = []   # closed: joined runs, large pieces
+        self._run: list[bytes] = []     # the open run of small pieces
 
     # primitives (little-endian, like encoding.h)
     def u8(self, v: int) -> "Encoder":
-        self._parts.append(struct.pack("<B", v)); return self
+        self._run.append(_U8.pack(v)); return self
 
     def u16(self, v: int) -> "Encoder":
-        self._parts.append(struct.pack("<H", v)); return self
+        self._run.append(_U16.pack(v)); return self
 
     def u32(self, v: int) -> "Encoder":
-        self._parts.append(struct.pack("<I", v)); return self
+        self._run.append(_U32.pack(v)); return self
 
     def u64(self, v: int) -> "Encoder":
-        self._parts.append(struct.pack("<Q", v)); return self
+        self._run.append(_U64.pack(v)); return self
 
     def i32(self, v: int) -> "Encoder":
-        self._parts.append(struct.pack("<i", v)); return self
+        self._run.append(_I32.pack(v)); return self
 
     def i64(self, v: int) -> "Encoder":
-        self._parts.append(struct.pack("<q", v)); return self
+        self._run.append(_I64.pack(v)); return self
 
     def f64(self, v: float) -> "Encoder":
-        self._parts.append(struct.pack("<d", v)); return self
+        self._run.append(_F64.pack(v)); return self
 
     def bool(self, v: bool) -> "Encoder":
         return self.u8(1 if v else 0)
 
-    def bytes(self, v: bytes) -> "Encoder":
-        self.u32(len(v)); self._parts.append(bytes(v)); return self
+    def _put(self, part: bytes) -> None:
+        if len(part) < SCATTER_MIN:
+            self._run.append(part)
+        else:
+            self._close_run()
+            self._parts.append(part)
+
+    def _close_run(self) -> None:
+        if self._run:
+            self._parts.append(b"".join(self._run))
+            self._run = []
+
+    def bytes(self, v) -> "Encoder":
+        """A length-prefixed value: ``bytes`` (or anything ``bytes()``
+        takes), or a :class:`Parts`, whose buffers go in as they are."""
+        parts = v.parts if isinstance(v, Parts) else (bytes(v),)
+        self._run.append(_U32.pack(sum(map(len, parts))))
+        for p in parts:
+            self._put(p)
+        return self
 
     def str(self, v: str) -> "Encoder":
         return self.bytes(v.encode())
@@ -71,31 +109,37 @@ class Encoder:
         """ENCODE_START(version, compat, ...) ... ENCODE_FINISH:
         version + compat bytes + length-prefixed body. ``compat`` is the
         oldest decoder version able to read this encoding; decoders skip
-        trailing bytes they don't parse."""
-        payload = body.getvalue()
+        trailing bytes they don't parse. The body's parts are taken
+        over as they are: nesting a section copies no large piece."""
         self.u8(version)
         self.u8(compat)
-        self.bytes(payload)
-        return self
-
-    # -- scatter-gather surface (ROADMAP 1c) --------------------------
-    def raw(self, v: bytes) -> "Encoder":
-        """Append pre-encoded bytes as their own part, by reference:
-        ``getparts`` hands it through uncopied (length prefixes are
-        the caller's job — pair with an explicit ``u32``)."""
-        self._parts.append(v)
-        return self
+        return self.bytes(Parts(body.getparts()))
 
     def getparts(self) -> list[bytes]:
         """The encoded buffers WITHOUT the final join — the sendmsg-
         style scatter list whose concatenation == ``getvalue()``."""
+        self._close_run()
         return list(self._parts)
 
-    def nbytes(self) -> int:
-        return sum(len(p) for p in self._parts)
-
     def getvalue(self) -> bytes:
-        return b"".join(self._parts)
+        return b"".join(self.getparts())
+
+
+class Parts:
+    """An encoded value kept as its scatter list, for a sender that
+    nests it in another encoding (``Encoder.bytes`` takes it) and so
+    leaves the one join to whoever needs contiguous bytes."""
+
+    __slots__ = ("parts",)
+
+    def __init__(self, parts: list[bytes]) -> None:
+        self.parts = parts
+
+    def __len__(self) -> int:
+        return sum(map(len, self.parts))
+
+    def __bytes__(self) -> bytes:
+        return b"".join(self.parts)
 
 
 class DecodeError(Exception):
@@ -103,29 +147,41 @@ class DecodeError(Exception):
 
 
 class Decoder:
-    def __init__(self, buf: bytes, off: int = 0) -> None:
+    """Reads ``buf[off:end]`` in place: a section's sub-decoder is a
+    window of the same buffer, and only a decoded ``bytes`` / ``str``
+    value is copied out of it."""
+
+    def __init__(self, buf: bytes, off: int = 0,
+                 end: int | None = None) -> None:
         self._buf = buf
         self._off = off
+        self._end = len(buf) if end is None else end
 
-    def _take(self, n: int) -> bytes:
-        if self._off + n > len(self._buf):
+    def _advance(self, n: int) -> int:
+        """Move past ``n`` bytes; returns where they start."""
+        off = self._off
+        if off + n > self._end:
             raise DecodeError(
-                f"short buffer: need {n} at {self._off}, have {len(self._buf)}")
-        v = self._buf[self._off:self._off + n]
-        self._off += n
-        return v
+                f"short buffer: need {n} at {off}, have {self._end}")
+        self._off = off + n
+        return off
 
-    def u8(self) -> int: return struct.unpack("<B", self._take(1))[0]
-    def u16(self) -> int: return struct.unpack("<H", self._take(2))[0]
-    def u32(self) -> int: return struct.unpack("<I", self._take(4))[0]
-    def u64(self) -> int: return struct.unpack("<Q", self._take(8))[0]
-    def i32(self) -> int: return struct.unpack("<i", self._take(4))[0]
-    def i64(self) -> int: return struct.unpack("<q", self._take(8))[0]
-    def f64(self) -> float: return struct.unpack("<d", self._take(8))[0]
+    def _num(self, st: struct.Struct):
+        return st.unpack_from(self._buf, self._advance(st.size))[0]
+
+    def u8(self) -> int: return self._num(_U8)
+    def u16(self) -> int: return self._num(_U16)
+    def u32(self) -> int: return self._num(_U32)
+    def u64(self) -> int: return self._num(_U64)
+    def i32(self) -> int: return self._num(_I32)
+    def i64(self) -> int: return self._num(_I64)
+    def f64(self) -> float: return self._num(_F64)
     def bool(self) -> bool: return self.u8() != 0
 
     def bytes(self) -> bytes:
-        return self._take(self.u32())
+        n = self.u32()
+        off = self._advance(n)
+        return self._buf[off:off + n]
 
     def str(self) -> str:
         return self.bytes().decode()
@@ -145,18 +201,20 @@ class Decoder:
         body). A newer encoding is readable as long as its ``compat``
         floor is within what this reader supports (the known field
         prefix decodes; unknown trailing bytes are skipped). Raises
-        DecodeError when the encoder declared itself incompatible."""
+        DecodeError when the encoder declared itself incompatible, or
+        when the body is shorter than its length says."""
         version = self.u8()
         compat = self.u8()
-        body = self.bytes()
+        n = self.u32()
+        off = self._advance(n)
         if compat > max_supported:
             raise DecodeError(
                 f"encoding v{version} requires decoder >= v{compat}, "
                 f"this reader supports <= v{max_supported}")
-        return version, Decoder(body)
+        return version, Decoder(self._buf, off, off + n)
 
     def remaining(self) -> int:
-        return len(self._buf) - self._off
+        return self._end - self._off
 
     def eof(self) -> bool:
-        return self._off >= len(self._buf)
+        return self._off >= self._end

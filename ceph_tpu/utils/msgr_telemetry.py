@@ -17,8 +17,9 @@ carries:
   connects, exhausted retries, injected failures, partitions), so the
   flight recorder and the SLOW_OPS health check can see wire trouble;
 - a bounded per-message-type side table (msgs/bytes each way +
-  serialize seconds per type) — the "which message class eats the
-  wire" view ``dump_msgr`` serves.
+  serialize seconds per type; on the loopback the count, payload
+  bytes and serialize + decode seconds a type cost its sender) — the
+  "which message class eats the wire" view ``dump_msgr`` serves.
 
 Counters are in the process PerfCounters collection, so ``perf
 dump``, prometheus, and the flight recorder export them for free.
@@ -45,7 +46,8 @@ class MessengerTelemetry:
             self._declare(perf)
         self.perf = perf
         #: msg type -> {"sent","sent_bytes","recv","recv_bytes",
-        #: "serialize_s","send_errors","dropped"}
+        #: "serialize_s","send_errors","dropped","loopback",
+        #: "loopback_bytes","loopback_codec_s"}
         self._by_type: dict[int, dict] = {}
         self._send_depth = 0
         self._dispatch_depth = 0
@@ -112,7 +114,8 @@ class MessengerTelemetry:
             ent = self._by_type[mtype] = {
                 "sent": 0, "sent_bytes": 0, "recv": 0,
                 "recv_bytes": 0, "serialize_s": 0.0,
-                "send_errors": 0, "dropped": 0}
+                "send_errors": 0, "dropped": 0, "loopback": 0,
+                "loopback_bytes": 0, "loopback_codec_s": 0.0}
         return ent
 
     # -- send path -----------------------------------------------------
@@ -146,6 +149,19 @@ class MessengerTelemetry:
                       max(0, frame_bytes - payload_bytes))
         self.perf.inc("loopback_batch_frames" if loopback
                       else "tcp_batch_frames")
+
+    def note_loopback_codec(self, mtype: int, payload_bytes: int,
+                            codec_s: float) -> None:
+        """One loopback delivery: what the message cost its sender's
+        thread in serialize (parts + the one join) + decode, and the
+        payload it moved. The TCP path never counts here (its decode
+        runs on the receiver's loop)."""
+        with self._lock:
+            ent = self._type_ent(mtype)
+            ent["loopback"] += 1
+            ent["loopback_bytes"] += payload_bytes
+            ent["loopback_codec_s"] = round(
+                ent["loopback_codec_s"] + codec_s, 9)
 
     def framing_brief(self) -> dict:
         """The wire-framing slice of the what-if report: batch frame

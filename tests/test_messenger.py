@@ -185,28 +185,35 @@ def test_unknown_message_type_dropped(pair):
 
 
 def test_scatter_gather_parts_equal_joined_payload():
-    """ISSUE 15 (real-wire bulk framing): a bulk batch message's
-    scatter-gather parts concatenate to EXACTLY encode_payload() —
-    the wire bytes are unchanged, only the copies are gone."""
+    """ISSUE 15 (real-wire bulk framing), every type since ISSUE 31:
+    a message's scatter-gather parts concatenate to EXACTLY
+    encode_payload() — the wire bytes are unchanged, only the copies
+    are gone."""
+    from ceph_tpu.utils.encoding import SCATTER_MIN
+    big, small = b"T" * SCATTER_MIN, b"U" * (SCATTER_MIN - 1)
     batch = M.MECSubWriteBatch(
         tid=3, epoch=7, tids=[1, 2], pools=[0, 0], pss=[1, 2],
         shards=[0, 1], oids=["a", "b"], versions=[5, 6],
-        txns=[b"T" * 4096, b"U" * 9000], traces=["", "t"],
-        stages="s")
+        txns=[big, small], traces=["", "t"], stages="s")
     parts = batch.encode_payload_parts()
-    assert len(parts) > 1                  # really scatter-gathered
     assert b"".join(parts) == batch.encode_payload()
-    # the bulk payloads ride by REFERENCE: no copy of the txn bytes
-    assert any(p is batch.txns[0] for p in parts)
-    assert any(p is batch.txns[1] for p in parts)
-    ob = M.MOSDOpBatch(
-        tid=1, client="c", epoch=2, pool=3, ps=4, tids=[9, 10],
-        oids=["o1", "o2"], ops=[5, 5], offsets=[0, 0],
-        lengths=[8, 8], datas=[b"D" * 8192, b"E" * 100],
-        traces=["", ""], stages=["", ""])
-    assert b"".join(ob.encode_payload_parts()) == ob.encode_payload()
-    # non-bulk messages keep the single-buffer fast path
+    # a large payload rides by REFERENCE, a part of its own; a small
+    # one is joined into the run of fields around it
+    assert any(p is big for p in parts)
+    assert len(parts) == 3 and small in parts[2]
+    for _, cls in sorted(M._REGISTRY.items()):
+        msg = cls(**{name: {"bytes": big, "bytes_list": [small, big],
+                            "bytes_map": {"k": big}}[kind]
+                     for name, kind in cls.FIELDS
+                     if kind.startswith("bytes")})
+        parts = msg.encode_payload_parts()
+        assert b"".join(parts) == msg.encode_payload(), cls.__name__
+        assert sum(p is big for p in parts) == sum(
+            kind.startswith("bytes") for _, kind in cls.FIELDS)
+    # a message of small fields stays ONE part: one crc call, one write
     assert len(M.MPing(osd_id=1).encode_payload_parts()) == 1
+    assert len(M.MOSDOp(tid=1, oid="o", data=small[:4096])
+               .encode_payload_parts()) == 1
 
 
 def test_batch_frames_survive_real_tcp(monkeypatch):
