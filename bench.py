@@ -76,11 +76,6 @@ BUDGETS = {
     # the streaming-objecter batch row, and the real-TCP (multi-
     # process, loopback off) bulk-framing win. Wall-clock-budgeted.
     "commit_path": (45.0, 0.0),
-    # ISSUE 18: the measured run-to-completion arm — the same zipfian
-    # workload as load_gen against a crimson (shard-per-core) cluster,
-    # plus the projection-honesty row against whatif_rtc_MBps.
-    # Wall-clock-budgeted.
-    "crimson": (30.0, 0.0),
     # ISSUE 19 (ROADMAP 3): the planet-scale read path — a zipfian
     # read storm A/B'd primary-pinned vs affine+any-k vs +client
     # cache, plus the microsecond cache-hit p99 row. Cluster-level,
@@ -111,14 +106,12 @@ BUDGETS = {
 #: r20: 390 -> 355 absorbs the commit_path row's reservation (ISSUE
 #: 15; its wire-probe subprocesses are bounded by the row's own
 #: budget, adding no structural term)
-#: r22: 355 -> 320 absorbs the crimson row's reservation (ISSUE 18;
-#: a pure-host cluster burst — no device programs of its own)
-#: r24: 320 -> 285 absorbs the hot_object_read row's reservation
+#: r24: 355 -> 320 absorbs the hot_object_read row's reservation
 #: (ISSUE 19; three short cluster bursts — host-path work, its EC
 #: decodes ride programs the earlier rows already warmed)
-#: r25: 285 -> 250 absorbs the multi_tenant row's reservation (ISSUE
+#: r25: 320 -> 285 absorbs the multi_tenant row's reservation (ISSUE
 #: 20; one host-path cluster burst — no device programs of its own)
-TOTAL_BUDGET = 250.0
+TOTAL_BUDGET = 285.0
 
 #: budgeted worst-case seconds for ONE cold per-signature compile
 #: (not measured on the current chip)
@@ -373,14 +366,6 @@ def main() -> None:
         emit("load_gen_MBps", {"error": repr(exc)})
         for row in ("dispatch_hops_per_op", "whatif_rtc_MBps"):
             if row not in _RESULTS:   # ISSUE-17 rows ride load_gen
-                emit(row, {"error": repr(exc)})
-
-    try:
-        _bench_crimson_load_gen()
-    except Exception as exc:  # both ISSUE-18 rows must still land
-        for row in ("crimson_load_gen_MBps",
-                    "dispatch_hops_per_op@crimson"):
-            if row not in _RESULTS:
                 emit(row, {"error": repr(exc)})
 
     try:
@@ -1228,63 +1213,6 @@ def _emit_commit_path_rows(measured_mbps: float) -> None:
     except Exception as exc:
         emit("dispatch_hops_per_op", {"error": repr(exc)})
         emit("whatif_rtc_MBps", {"error": repr(exc)})
-
-
-def _bench_crimson_load_gen() -> None:
-    """The measured run-to-completion arm (ISSUE 18): the SAME
-    zipfian workload as ``_bench_load_gen`` (spec-identical, healthy
-    phase only) against a crimson shard-per-core cluster. ``value``
-    is the healthy-phase client MB/s; the line also carries the
-    dispatch shape the refactor exists for (hops/op, wq_continuation
-    count, wakeups/frame) and the projection-honesty verdict against
-    the whatif_rtc_MBps row the threaded run just emitted — the
-    ledger's model gets called out here if reality leaves its
-    bracket. The dispatch registry is reset first so the counters
-    attribute this arm only (the threaded rows were already read)."""
-    budget, _ = BUDGETS["crimson"]
-    deadline = min(_deadline(), time.perf_counter() + budget)
-    remaining = max(deadline - time.perf_counter(), 4.0)
-    phase_s = max(0.5, min(2.0, remaining / 6))
-    from ceph_tpu.bench.load_gen import LoadGen, LoadSpec
-    from ceph_tpu.qa.cluster import MiniCluster
-    from ceph_tpu.utils.dispatch_telemetry import SEAMS, telemetry
-    telemetry().reset()   # a fresh registry attributes THIS arm only
-    t0 = time.perf_counter()
-    with MiniCluster(n_osds=3, osd_flavor="crimson") as cluster:
-        cluster.create_ec_pool("lg", k=2, m=1, pg_num=8,
-                               backend="jax")
-        spec = LoadSpec(n_keys=32, obj_size=65536, read_frac=0.5,
-                        concurrency=4, phase_seconds=phase_s,
-                        seed=9)
-        gen = LoadGen(cluster, "lg", spec)
-        out = gen.run_healthy()
-    healthy = out["phases"][0]
-    measured = healthy.get("MBps", 0.0)
-    whatif = (_RESULTS.get("whatif_rtc_MBps") or {}).get("value", 0.0)
-    from ceph_tpu.tools.gap_report import projection_honesty
-    emit("crimson_load_gen_MBps", {
-        "value": measured,
-        "unit": "MB/s",
-        "p99_ms": healthy.get("p99_ms"),
-        "ops": healthy.get("ops"),
-        "phase_seconds": phase_s,
-        "lost_acked": len(out["verify"]["lost_acked"]),
-        "wrong_bytes": len(out["verify"]["wrong_bytes"]),
-        "projection_honesty": projection_honesty(whatif, measured),
-        "wall_s": round(time.perf_counter() - t0, 1),
-    })
-    tel = telemetry()   # reset() swaps the singleton: re-fetch
-    c = tel.perf.dump()
-    chains = c.get("op_chains", 0)
-    hops = sum(c.get(f"ophop_{s}", 0) for s in SEAMS)
-    emit("dispatch_hops_per_op@crimson", {
-        "value": round(hops / chains, 2) if chains else 0.0,
-        "unit": "hops",
-        "op_chains": chains,
-        "wq_continuation_hops": c.get("ophop_wq_continuation", 0),
-        "wakeups_per_frame":
-            tel.wakeup_table().get("wakeups_per_frame"),
-    })
 
 
 #: injected per-shard store read latency for the hot-read arms. The

@@ -38,12 +38,11 @@ the *static twin* of a runtime contract this repo already gates:
    exact blind spot this PR closed; future stores don't get to
    reopen it.
 
-6. **reactor affinity** (ISSUE 18) — shared-nothing discipline for
-   ``ceph_tpu/crimson/``: no module-global mutable state, no blocking
-   ``time.sleep`` inside reactor coroutines, no raw ``threading``
-   sync primitives outside the witnessed ``make_lock`` seam. The
-   static twin of the runtime hop counters (``wq_continuation == 0``)
-   and the lock witness.
+6. **layering** — which package may import which, from one table
+   (``LAYERS``): an import, module-level or inside a function, names
+   its own layer or a lower one; nothing under ``ceph_tpu/`` imports
+   ``benchmarks``, ``bench``, ``chip_smoke`` or ``tests``; a package
+   the table does not place is a finding.
 
 7. **flow context** (ISSUE 20) — every enqueue seam accepting a
    ``qos=`` parameter must thread the per-tenant flow context
@@ -1079,77 +1078,99 @@ def check_fsync_seam(src: SourceFile) -> list[Finding]:
     return findings
 
 
-#: reactor-affinity scope (repo-relative directory prefix): the
-#: shard-per-core subsystem whose run-to-completion discipline the
-#: checker pins statically
-REACTOR_DIR = "ceph_tpu/crimson"
+# ---------------------------------------------------------------------------
+# 6. layering
+# ---------------------------------------------------------------------------
 
-#: sync primitives whose DIRECT construction inside crimson bypasses
-#: the lock witness (cross-shard edges must go through make_lock /
-#: make_condition so contention is attributable)
-_RAW_LOCK_CALLS = frozenset((
-    "threading.Lock", "threading.RLock", "threading.Condition"))
+#: THE layer map: every package under ``ceph_tpu/``, lowest layer
+#: first; packages in one tuple share a layer. A module may import
+#: its own layer or a lower one — module-level or inside a function.
+LAYERS = (
+    ("utils", "analysis"),
+    ("ops", "compressor"),
+    ("models",),
+    ("store", "parallel"),
+    ("osd", "cls", "client"),
+    ("mgr", "services"),
+    ("qa", "tools", "bench"),
+)
+LAYER_OF = {pkg: n for n, pkgs in enumerate(LAYERS) for pkg in pkgs}
+
+#: top-level modules of the repo that are NOT the program: the
+#: driver's benchmark, the old harness, the chip smoke, the tests
+NOT_PROGRAM = frozenset(("benchmarks", "bench", "chip_smoke", "tests"))
 
 
-def check_reactor_affinity(src: SourceFile) -> list[Finding]:
-    """Shared-nothing discipline for ``ceph_tpu/crimson/`` (ISSUE
-    18) — the static twin of the runtime hop counters (``ophop_
-    wq_continuation == 0``) and the lock witness. Three violation
-    classes:
+def _imported_modules(node: ast.AST, here: list[str]) -> list[str]:
+    """Absolute dotted names one import site names (``here`` is the
+    importing file's package path, for relative imports)."""
+    if isinstance(node, ast.Import):
+        return [a.name for a in node.names]
+    if isinstance(node, ast.ImportFrom):
+        base = node.module or ""
+        if node.level:
+            up = here[:len(here) - (node.level - 1)]
+            base = ".".join(up + ([base] if base else []))
+        # ``from ceph_tpu import osd`` / ``from . import x`` name the
+        # package in the alias, not in the module
+        if (node.level and not node.module) or base == "ceph_tpu":
+            return [f"{base}.{a.name}" for a in node.names]
+        return [base]
+    if isinstance(node, ast.Call) and node.args and \
+            isinstance(node.args[0], ast.Constant) and \
+            isinstance(node.args[0].value, str):
+        # __import__("a.b") / importlib.import_module("a.b")
+        func = node.func
+        if (isinstance(func, ast.Name) and func.id == "__import__") or \
+                (isinstance(func, ast.Attribute) and
+                 func.attr == "import_module"):
+            return [node.args[0].value]
+    return []
 
-    * ``global`` statements — module-level mutable state is shared
-      across every reactor thread; crimson state lives on the shard
-      (``Reactor``/``ReactorServices``) or on the OSD control plane,
-      never in module globals.
-    * blocking ``time.sleep`` inside ``async def`` — parks the whole
-      reactor (every PG pinned to it stalls admission-to-commit);
-      coroutines use ``asyncio.sleep`` or an injectable seam.
-    * direct ``threading.Lock/RLock/Condition`` construction — a
-      cross-shard edge the lock witness cannot see; the deliberate
-      edges (map waiters, tid counter, sub-write batch fan-in) go
-      through ``make_lock`` and are witnessed.
-    """
+
+def check_layering(src: SourceFile) -> list[Finding]:
+    """Which package may import which, from the one table above: an
+    import under ``ceph_tpu/`` names its own layer or a lower one, and
+    never the benchmark, the old harness, the smoke or the tests. A
+    package the table does not place is itself a finding, so the map
+    cannot fall behind the tree. The arrows that point up today are
+    debts in ``baseline.json``, each with what would remove it."""
     rel = src.rel.replace(os.sep, "/")
-    if not rel.startswith(REACTOR_DIR + "/"):
+    here = rel.split("/")[:-1]
+    if here[:1] != ["ceph_tpu"]:
         return []
+    # files at the package root sit above every layer
+    pkg = here[1] if len(here) > 1 else None
     findings: list[Finding] = []
 
-    def visit(node: ast.AST, func: str, in_async: bool) -> None:
-        for child in ast.iter_child_nodes(node):
-            name, is_async = func, in_async
-            if isinstance(child, (ast.FunctionDef,
-                                  ast.AsyncFunctionDef)):
-                name = child.name
-                is_async = isinstance(child, ast.AsyncFunctionDef)
-            if isinstance(child, ast.Global):
-                findings.append(Finding(
-                    "reactor_affinity", src.rel, child.lineno,
-                    f"reactor-affinity:{rel}:{func}:global",
-                    f"global {', '.join(child.names)} in {func}(): "
-                    "module-level mutable state is visible to every "
-                    "reactor — shared-nothing state lives on the "
-                    "shard or the OSD control plane"))
-            if isinstance(child, ast.Call):
-                callee = _unparse(child.func)
-                if in_async and callee == "time.sleep":
-                    findings.append(Finding(
-                        "reactor_affinity", src.rel, child.lineno,
-                        f"reactor-affinity:{rel}:{func}:"
-                        "blocking-sleep",
-                        f"time.sleep in async {func}(): blocks the "
-                        "whole reactor (every PG pinned to it) — "
-                        "use asyncio.sleep or an injectable seam"))
-                if callee in _RAW_LOCK_CALLS:
-                    findings.append(Finding(
-                        "reactor_affinity", src.rel, child.lineno,
-                        f"reactor-affinity:{rel}:{func}:raw-lock",
-                        f"{callee}() in {func}(): cross-shard sync "
-                        "primitive invisible to the lock witness — "
-                        "route through analysis.lock_witness."
-                        "make_lock/make_condition"))
-            visit(child, name, is_async)
+    def add(line: int, key: str, message: str) -> None:
+        findings.append(Finding("layering", src.rel, line,
+                                f"layering:{key}", message))
 
-    visit(src.tree, "<module>", False)
+    if pkg is not None and pkg not in LAYER_OF:
+        add(1, f"unmapped:{pkg}",
+            f"package ceph_tpu/{pkg} has no layer: place it in "
+            "analysis.linters.LAYERS")
+    for node in ast.walk(src.tree):
+        for mod in _imported_modules(node, here):
+            parts = mod.split(".")
+            if parts[0] in NOT_PROGRAM:
+                add(node.lineno, f"{rel}:{parts[0]}",
+                    f"imports {mod}: program code may not depend on "
+                    "the benchmark, the old harness, the smoke or "
+                    "the tests")
+            if parts[0] != "ceph_tpu" or len(parts) < 2:
+                continue
+            target = parts[1]
+            if target not in LAYER_OF:
+                add(node.lineno, f"unmapped:{target}",
+                    f"imports {mod}: ceph_tpu/{target} has no layer "
+                    "in analysis.linters.LAYERS")
+            elif LAYER_OF.get(pkg, len(LAYERS)) < LAYER_OF[target]:
+                add(node.lineno, f"{rel}:{target}",
+                    f"imports {mod}: ceph_tpu/{pkg} (layer "
+                    f"{LAYER_OF[pkg]}) may not import ceph_tpu/"
+                    f"{target} (layer {LAYER_OF[target]})")
     return findings
 
 
@@ -1225,7 +1246,7 @@ def run_all(root: str = PKG_ROOT,
         findings.extend(check_lock_discipline(src))
         findings.extend(check_notify_under_lock(src))
         findings.extend(check_fsync_seam(src))
-        findings.extend(check_reactor_affinity(src))
+        findings.extend(check_layering(src))
         findings.extend(check_flow_context(src))
         drift.collect(src)
     findings.extend(drift.findings())

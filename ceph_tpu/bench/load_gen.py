@@ -479,9 +479,9 @@ class LoadGen:
 
     def run_healthy(self, seconds: float | None = None) -> dict:
         """Healthy-phase-only run (no fault ladder): the steady-state
-        throughput probe the crimson-vs-threaded A/B uses. Same
-        workload, same byte-exact verification, same durability
-        sweep in :meth:`report`."""
+        probe gap_report's tenant arm uses. Same workload, same
+        byte-exact verification, same durability sweep in
+        :meth:`report`."""
         self.health.evaluate(self._status(),
                              self.cluster.mon.osdmap)   # arm deltas
         self.preload()

@@ -293,28 +293,6 @@ DEFAULT_RULES = (
          s["p99_ms"] > 1.5 * s["p99_ref"] and
          0 < s["stream_batch_mean"] <= 0.25 *
          float(e.conf.get("objecter_stream_max_ops"))),
-    # crimson levers (ISSUE 18): the run-to-completion flush window
-    # rides the same occupancy/latency sensors as the engine's (the
-    # crimson OSD attaches to the shared engine with its own
-    # threshold); the reactor count steps only for FUTURE boots (the
-    # observer caches it — live reactors never reshard), so its rule
-    # keys off sustained pressure, not transients
-    Rule("crimson_flush_grow", "crimson_flush_bytes", "up",
-         "high flush occupancy at healthy latency on the crimson "
-         "arm: amortize the one async boundary over bigger stripes",
-         lambda s, e: s["occupancy"] >= 4 and
-         (s["p99_ref"] <= 0 or s["p99_ms"] <= 1.2 * s["p99_ref"])),
-    Rule("crimson_flush_shrink", "crimson_flush_bytes", "down",
-         "near-empty crimson flushes: the engine-window wait is "
-         "pure latency nothing amortizes — cut the threshold",
-         lambda s, e: 0 < s["occupancy"] <= 2 and
-         s["p99_ref"] > 0 and s["p99_ms"] > 1.5 * s["p99_ref"]),
-    Rule("crimson_smp_grow", "crimson_smp", "up",
-         "sustained saturation with healthy memory: more shards for "
-         "crimson OSDs started after this step",
-         lambda s, e: s["window"] > 0 and
-         s["inflight"] >= s["window"] and s["hbm_frac"] < 0.5 and
-         s["health_rank"] == 0),
     # read-path levers (ROADMAP 3): the any-k rotation width steps
     # on MEASURED per-object skew — wide only while a storm is
     # actually concentrated (width costs decode-signature reuse, so

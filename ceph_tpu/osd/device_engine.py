@@ -1595,9 +1595,9 @@ def _detach(engine: DeviceEncodeEngine, token: int) -> None:
 
 def _bind(cont, shards, crcs, err):
     # re-install the flow label stamped at stage time: the retire
-    # thread (threaded) / owning reactor (crimson) has no tenant
-    # context of its own, and the continuation's fan-out captures
-    # current_flow() when it defers sub-writes into the flush group
+    # thread has no tenant context of its own, and the continuation's
+    # fan-out captures current_flow() when it defers sub-writes into
+    # the flush group
     flow = getattr(cont, "_flow", "")
 
     def fn():

@@ -22,17 +22,11 @@ log = Dout("qa")
 class MiniCluster:
     def __init__(self, n_osds: int = 3, store: str = "memstore",
                  data_dir: str | None = None, auth: bool = False,
-                 n_mons: int = 1,
-                 osd_flavor: str = "threaded") -> None:
-        assert osd_flavor in ("threaded", "crimson"), osd_flavor
+                 n_mons: int = 1) -> None:
         self.n_osds = n_osds
         self.n_mons = n_mons
         self.store_kind = store
         self.data_dir = data_dir
-        #: "threaded" boots the mainline OSD; "crimson" boots the
-        #: shard-per-core run-to-completion OSD (same wire protocol —
-        #: every helper/client below works unchanged)
-        self.osd_flavor = osd_flavor
         self.mons: dict[int, Monitor] = {}
         self._mon_dbs: dict[int, object] = {}
         self.mon_addr = ""
@@ -81,22 +75,6 @@ class MiniCluster:
         return create_store(self.store_kind, path)
 
     def start_osd(self, osd_id: int) -> OSD:
-        if self.osd_flavor == "crimson":
-            # crimson manages its own per-reactor shard stores (the
-            # shared-nothing discipline: one store per reactor); a
-            # revive hands the killed OSD's shard stores back so its
-            # data survives, mirroring the threaded store cache
-            from ceph_tpu.crimson import CrimsonOSD
-            cached = self._stores.get(osd_id)
-            osd = CrimsonOSD(osd_id, self.mon_addr,
-                             store_kind=self.store_kind,
-                             data_dir=self.data_dir,
-                             shard_stores=cached if
-                             isinstance(cached, list) else None)
-            osd.start()
-            self._stores[osd_id] = [r.store for r in osd.reactors]
-            self.osds[osd_id] = osd
-            return osd
         store = self._stores.get(osd_id) or self._make_store(osd_id)
         self._stores[osd_id] = store
         osd = OSD(osd_id, store, self.mon_addr, keyring=self.keyring)

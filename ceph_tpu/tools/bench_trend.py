@@ -56,12 +56,6 @@ DIRECTIONS = {
     # RTC projection gates UP like the other what-if row
     "dispatch_hops_per_op": "lower",
     "whatif_rtc_MBps": "higher",
-    # ISSUE 18: the measured crimson arm — its throughput gates UP
-    # like the other MBps rows (pinned anyway: the projection-honesty
-    # fields riding the line must never flip it), and its hops/op
-    # gates DOWN (the run-to-completion discipline is the point)
-    "crimson_load_gen_MBps": "higher",
-    "dispatch_hops_per_op@crimson": "lower",
     # ISSUE 19: the planet-scale read path — aggregate hot-read GB/s
     # gates UP (any-k balanced reads are the point) and the client
     # cache-hit p99 gates DOWN (the name heuristic would catch the

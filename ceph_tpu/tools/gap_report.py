@@ -359,23 +359,6 @@ def run_report(seconds: float, n_osds: int, obj_size: int,
                 handoff_ms_per_op=ch)
     except Exception:
         pass
-    # ISSUE 18: the measured crimson arm + the projection-honesty
-    # acceptance row. Runs LAST (it resets the dispatch registry for
-    # its own attribution) and is skippable for quick looks.
-    if not getattr(args, "no_crimson", False):
-        try:
-            arm = _crimson_arm(min(seconds, 2.0), n_osds, obj_size,
-                               threads, k, m, backend)
-            if "load_gen_MBps" in arm:
-                whatif = ((report.get("what_if") or {})
-                          .get("run_to_completion") or {})
-                arm["projection_honesty"] = projection_honesty(
-                    whatif.get("whatif_rtc_MBps") or 0.0,
-                    arm["load_gen_MBps"])
-            report["crimson"] = arm
-        except Exception as exc:  # pragma: no cover - defensive
-            report["crimson"] = {"error":
-                                 f"{type(exc).__name__}: {exc}"}
     # ISSUE 19: the read-path A/B — zipfian storm primary-pinned vs
     # any-k balanced, with the read_balance verdict row. Also LAST
     # (fresh clusters of its own) and skippable for quick looks.
@@ -387,23 +370,25 @@ def run_report(seconds: float, n_osds: int, obj_size: int,
         except Exception as exc:  # pragma: no cover - defensive
             report["read_balance"] = {"error":
                                       f"{type(exc).__name__}: {exc}"}
-    # ISSUE 20: the tenant X-ray arm — per-flow attribution coverage
-    # on BOTH flavors. Opt-in (--tenants); fresh clusters of its own.
+    # ISSUE 20: the tenant X-ray arm — per-flow attribution
+    # coverage. Opt-in (--tenants); a fresh cluster of its own.
     if getattr(args, "tenants", False):
-        report["tenants"] = _tenants_section(
-            min(seconds, 2.0), n_osds, obj_size, threads, k, m,
-            backend)
+        try:
+            report["tenants"] = _tenants_arm(
+                min(seconds, 2.0), n_osds, obj_size, threads, k, m,
+                backend)
+        except Exception as exc:  # pragma: no cover - defensive
+            report["tenants"] = {"error":
+                                 f"{type(exc).__name__}: {exc}"}
     return report
 
 
 def _tenants_arm(seconds: float, n_osds: int, obj_size: int,
-                 threads: int, k: int, m: int, backend: str,
-                 flavor: str) -> dict:
+                 threads: int, k: int, m: int, backend: str) -> dict:
     """One tenant-attributed pass (ISSUE 20): a named-tenant traffic
-    mix against a fresh ``flavor`` cluster with the flow registry
-    reset first, so the attribution/coverage table scores THIS arm
-    only. The acceptance bar: >= 95% of ops AND bytes carry a tenant
-    label, on BOTH the threaded and crimson flavors."""
+    mix against a fresh cluster with the flow registry reset first,
+    so the attribution/coverage table scores THIS arm only. The
+    acceptance bar: >= 95% of ops AND bytes carry a tenant label."""
     from ceph_tpu.bench.load_gen import LoadGen, LoadSpec
     from ceph_tpu.qa.cluster import MiniCluster
     from ceph_tpu.utils import flow_telemetry as _flow_tel
@@ -412,7 +397,7 @@ def _tenants_arm(seconds: float, n_osds: int, obj_size: int,
     tel = _flow_tel.telemetry_if_exists()
     if tel is not None:
         tel.reset()
-    with MiniCluster(n_osds=n_osds, osd_flavor=flavor) as cluster:
+    with MiniCluster(n_osds=n_osds) as cluster:
         cluster.create_ec_pool("tx", k=k, m=m, pg_num=8,
                                backend=backend)
         tenants = ("acme", "globex", "initech")
@@ -428,7 +413,6 @@ def _tenants_arm(seconds: float, n_osds: int, obj_size: int,
     attr = tel.attribution()
     healthy = out["phases"][0]
     return {
-        "flavor": flavor,
         "ops": healthy.get("ops"),
         "MBps": healthy.get("MBps"),
         "tenants": healthy.get("tenants"),
@@ -440,75 +424,29 @@ def _tenants_arm(seconds: float, n_osds: int, obj_size: int,
     }
 
 
-def _tenants_section(seconds: float, n_osds: int, obj_size: int,
-                     threads: int, k: int, m: int,
-                     backend: str) -> dict:
-    out = {}
-    for flavor in ("threaded", "crimson"):
-        try:
-            out[flavor] = _tenants_arm(seconds, n_osds, obj_size,
-                                       threads, k, m, backend, flavor)
-        except Exception as exc:  # pragma: no cover - defensive
-            out[flavor] = {"error": f"{type(exc).__name__}: {exc}"}
-    out["coverage_ok"] = all(
-        arm.get("coverage_ok") for arm in out.values()
-        if isinstance(arm, dict))
-    return out
-
-
 def _print_tenants(report: dict) -> None:
-    sec = report.get("tenants")
-    if not sec:
-        return
-    print()
-    print("--- tenant X-ray (per-flow attribution, both flavors) ---")
-    for flavor in ("threaded", "crimson"):
-        arm = sec.get(flavor) or {}
-        if "error" in arm:
-            print(f"  {flavor}: arm failed: {arm['error']}")
-            continue
-        if "skipped" in arm:
-            print(f"  {flavor}: skipped: {arm['skipped']}")
-            continue
-        attr = arm["attribution"]
-        print(f"  {flavor}: ops {attr['ops_attributed']}/"
-              f"{attr['ops_total']} ({attr['ops_pct']}%)   bytes "
-              f"{attr['bytes_attributed']}/{attr['bytes_total']} "
-              f"({attr['bytes_pct']}%)   "
-              f"{'OK' if arm['coverage_ok'] else 'BELOW 95% BAR'}")
-        for tenant, row in sorted(attr["by_flow"].items()):
-            print(f"    {tenant or '(unlabelled)':<14}"
-                  f"ops {row['ops']:>7} ({100 * row['ops_share']:.1f}%)"
-                  f"   bytes {row['bytes']:>12} "
-                  f"({100 * row['bytes_share']:.1f}%)")
-    print(f"  coverage >= 95% both flavors: "
-          f"{'yes' if sec.get('coverage_ok') else 'NO'}")
-
-
-def _print_crimson(report: dict) -> None:
-    arm = report.get("crimson")
+    arm = report.get("tenants")
     if not arm:
         return
     print()
-    print("--- crimson (run-to-completion, measured) ---")
+    print("--- tenant X-ray (per-flow attribution) ---")
     if "error" in arm:
         print(f"  arm failed: {arm['error']}")
         return
     if "skipped" in arm:
-        print(f"  arm skipped: {arm['skipped']}")
+        print(f"  skipped: {arm['skipped']}")
         return
-    print(f"  load_gen:       {arm['load_gen_MBps']} MB/s   "
-          f"p99 {arm['p99_ms']} ms   ops {arm['ops']}")
-    print(f"  dispatch:       {arm['hops_per_op']} hops/op   "
-          f"wq_continuation {arm['wq_continuation_hops']}   "
-          f"wakeups/frame {arm['wakeups_per_frame']}")
-    print(f"  verify:         lost_acked {arm['lost_acked']}   "
-          f"wrong_bytes {arm['wrong_bytes']}")
-    ph = arm.get("projection_honesty") or {}
-    if ph:
-        lo, hi = ph.get("bracket", [0.5, 2.0])
-        print(f"  honesty:        measured/whatif = {ph['ratio']}  "
-              f"(bracket [{lo}x, {hi}x])  -> {ph['verdict']}")
+    attr = arm["attribution"]
+    print(f"  ops {attr['ops_attributed']}/"
+          f"{attr['ops_total']} ({attr['ops_pct']}%)   bytes "
+          f"{attr['bytes_attributed']}/{attr['bytes_total']} "
+          f"({attr['bytes_pct']}%)   "
+          f"{'OK' if arm['coverage_ok'] else 'BELOW 95% BAR'}")
+    for tenant, row in sorted(attr["by_flow"].items()):
+        print(f"    {tenant or '(unlabelled)':<14}"
+              f"ops {row['ops']:>7} ({100 * row['ops_share']:.1f}%)"
+              f"   bytes {row['bytes']:>12} "
+              f"({100 * row['bytes_share']:.1f}%)")
 
 
 def print_table(report: dict) -> None:
@@ -609,71 +547,6 @@ def _print_commit_path(report: dict) -> None:
               f"coalesces {obj.get('mean_batch')} ops/batch "
               f"(max {obj.get('max_batch')}) -> projected "
               f"{wi.get('projected_MBps')} MB/s")
-
-
-def projection_honesty(whatif_mbps: float, measured_mbps: float,
-                       lo: float = 0.5, hi: float = 2.0) -> dict:
-    """The projection-honesty check (ISSUE 18 acceptance row): a
-    what-if ledger is only worth keeping if reality lands inside its
-    bracket. ``measured_mbps`` (the crimson arm) must fall within
-    [lo x, hi x] of ``whatif_mbps`` (PR 16's run-to-completion
-    projection off the threaded run) — otherwise the verdict says
-    the MODEL needs correcting, loudly, instead of letting a
-    flattering ledger ride along unexamined."""
-    whatif = float(whatif_mbps or 0.0)
-    measured = float(measured_mbps or 0.0)
-    if whatif <= 0.0 or measured <= 0.0:
-        return {"whatif_rtc_MBps": whatif,
-                "measured_crimson_MBps": measured,
-                "ratio": None, "bracket": [lo, hi],
-                "within_bracket": False,
-                "verdict": "no-data"}
-    ratio = round(measured / whatif, 3)
-    within = lo <= ratio <= hi
-    return {"whatif_rtc_MBps": whatif,
-            "measured_crimson_MBps": measured,
-            "ratio": ratio, "bracket": [lo, hi],
-            "within_bracket": within,
-            "verdict": "honest" if within else "model-needs-fix"}
-
-
-def _crimson_arm(seconds: float, n_osds: int, obj_size: int,
-                 threads: int, k: int, m: int, backend: str) -> dict:
-    """The measured crimson side of the A/B: the same zipfian
-    workload against a shard-per-core cluster, with the dispatch
-    registry reset first so hops/op and wakeups/frame attribute THIS
-    arm only. Runs LAST — it must not clobber the threaded run's
-    counters (the report reads them before this resets)."""
-    from ceph_tpu.bench.load_gen import LoadGen, LoadSpec
-    from ceph_tpu.qa.cluster import MiniCluster
-    from ceph_tpu.utils.dispatch_telemetry import SEAMS
-    from ceph_tpu.utils.dispatch_telemetry import telemetry as _dt
-    if n_osds < k + m:
-        return {"skipped": f"n_osds {n_osds} < k+m {k + m}"}
-    _dt().reset()
-    with MiniCluster(n_osds=n_osds, osd_flavor="crimson") as cluster:
-        cluster.create_ec_pool("cr", k=k, m=m, pg_num=8,
-                               backend=backend)
-        spec = LoadSpec(n_keys=32, obj_size=obj_size, read_frac=0.5,
-                        concurrency=threads, phase_seconds=seconds,
-                        seed=9)
-        gen = LoadGen(cluster, "cr", spec)
-        out = gen.run_healthy()
-    healthy = out["phases"][0]
-    c = _dt().perf.dump()
-    chains = c.get("op_chains", 0)
-    hops = sum(c.get(f"ophop_{s}", 0) for s in SEAMS)
-    return {
-        "load_gen_MBps": healthy.get("MBps", 0.0),
-        "p99_ms": healthy.get("p99_ms"),
-        "ops": healthy.get("ops"),
-        "hops_per_op": round(hops / chains, 2) if chains else 0.0,
-        "wq_continuation_hops": c.get("ophop_wq_continuation", 0),
-        "wakeups_per_frame":
-            _dt().wakeup_table().get("wakeups_per_frame"),
-        "lost_acked": len(out["verify"]["lost_acked"]),
-        "wrong_bytes": len(out["verify"]["wrong_bytes"]),
-    }
 
 
 def _read_storm(seconds: float, n_osds: int, k: int, m: int,
@@ -854,7 +727,6 @@ def _print_dispatch(report: dict) -> None:
               f"hops + {rtc.get('wakeups_saved')} wakeups "
               f"({rtc.get('saved_ms_per_op')} ms/op) -> projected "
               f"{rtc.get('whatif_rtc_MBps')} MB/s")
-    _print_crimson(report)
     _print_read_balance(report)
     _print_tenants(report)
 
@@ -886,16 +758,13 @@ def main(argv=None) -> int:
                          "JSON line")
     ap.add_argument("--profile-hz", type=float, default=50.0,
                     help="sampling rate for --profile")
-    ap.add_argument("--no-crimson", action="store_true",
-                    help="skip the measured crimson arm (and its "
-                         "projection-honesty row)")
     ap.add_argument("--no-read-balance", action="store_true",
                     help="skip the primary-vs-any-k read storm "
                          "(and its read_balance verdict row)")
     ap.add_argument("--tenants", action="store_true",
                     help="run the tenant X-ray arm: a named-tenant "
-                         "mix on BOTH flavors with the per-flow "
-                         "attribution-coverage table (>= 95% bar)")
+                         "mix with the per-flow attribution-"
+                         "coverage table (>= 95% bar)")
     args = ap.parse_args(argv)
     if args.full:
         args.osds, args.k, args.m = 12, 8, 3

@@ -364,17 +364,6 @@ for _o in [
            1.0, "advanced", "scrub/best-effort weight"),
     Option("osd_mclock_scheduler_background_best_effort_lim", float,
            0.0, "advanced", "scrub/best-effort limit, ops/s"),
-    Option("crimson_smp", int, 3, "advanced",
-           "crimson reactor count (seastar --smp role): shared-nothing "
-           "event loops an OSD shards its PGs over; applies to OSDs "
-           "started after a change",
-           min=1, max=64),
-    Option("crimson_flush_bytes", int, 1 << 20, "advanced",
-           "crimson engine flush window: bytes staged across the "
-           "reactors before an encode flush launches — the ONLY async "
-           "boundary on the run-to-completion path, so this trades "
-           "stripe-batch amortization directly against commit latency",
-           min=64 << 10, max=256 << 20),
     Option("osd_tracing", bool, False, "advanced",
            "arm the 'osd' static-tracepoint provider at daemon start "
            "(TracepointProvider role, src/ceph_osd.cc:36)"),

@@ -58,7 +58,6 @@ SEAMS = (
     "msgr_send",        # send_message() -> messenger loop pickup
     "msgr_dispatch",    # rx stamp -> dispatcher entry (loopback hop)
     "reply_wakeup",     # completion event.set -> waiter running
-    "reactor_submit",   # cross-thread submit onto an owning reactor
 )
 
 #: one-line glossary served by ``dump_dispatch`` and BASELINE.md
@@ -73,10 +72,6 @@ GLOSSARY = {
                      "loopback cross-thread hop)",
     "reply_wakeup": "completion event.set -> waiting client thread "
                     "running again",
-    "reactor_submit": "cross-thread submit onto the PG's owning "
-                      "crimson reactor (seastar submit_to role: "
-                      "admission, engine continuation, and reply "
-                      "routing each cross it at most once)",
     "hops_per_op": "cross-thread handoffs one completed client op "
                    "crossed (admission -> N hops -> commit reply)",
     "wakeups_per_frame": "client threads woken per reply frame "
@@ -207,22 +202,6 @@ class DispatchTelemetry:
                 "total_us": dump.get("total_us", 0.0),
                 "hops": chain,
             })
-
-    def note_op_hops(self, seams: list[str]) -> None:
-        """Server-side chain accounting for run-to-completion paths:
-        a crimson op never re-enters a wq, so there is no merged stage
-        timeline to derive a chain from — the owning reactor counted
-        each cross-thread hop as it happened and reports the seam
-        list at commit-reply time. Feeds the same ``op_chains`` /
-        ``hops_per_op`` / ``ophop_*`` counters as
-        :meth:`note_op_chain`, so gap_report's hops-per-op mean is
-        comparable across OSD flavors. Zero-hop chains count too
-        (they pull the mean DOWN, which is the whole point)."""
-        known = [s for s in seams if s in SEAMS]
-        self.perf.inc("op_chains")
-        self.perf.hinc("hops_per_op", float(len(known)))
-        for seam in known:
-            self.perf.inc(f"ophop_{seam}")
 
     # -- plane 2a: completion wakeups ---------------------------------
     def note_reply_frame(self, conn: str, n_ops: int) -> None:

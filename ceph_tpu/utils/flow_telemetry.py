@@ -664,8 +664,8 @@ def reset_for_tests() -> None:
 # -- the thread-local flow context --------------------------------------
 
 def set_current_flow(label: str | None) -> None:
-    """Install the flow label on this thread (daemon admission /
-    crimson inline continuation). NOOP when flows are disabled."""
+    """Install the flow label on this thread (daemon admission).
+    NOOP when flows are disabled."""
     if not enabled():
         return
     _tls.flow = label or None

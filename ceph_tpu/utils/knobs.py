@@ -155,17 +155,6 @@ TUNER_KNOBS = KnobRegistry([
     Knob("mesh_flush_bytes", lo=128 << 10, hi=64 << 20, step=2.0,
          kind="mul", cooldown_s=3.0, subsystem="osd/device_engine",
          desc="dense->mesh crossover: single-chip vs sharded step"),
-    Knob("crimson_smp", lo=1, hi=16, step=1, kind="add",
-         cooldown_s=6.0, subsystem="crimson/osd",
-         desc="shared-nothing reactor count (seastar --smp role); a "
-              "step applies to crimson OSDs started afterwards — the "
-              "observer caches it for the next boot, live reactors "
-              "never reshard"),
-    Knob("crimson_flush_bytes", lo=256 << 10, hi=64 << 20, step=2.0,
-         kind="mul", cooldown_s=3.0, subsystem="crimson/osd",
-         desc="crimson engine flush window: stripe-batch amortization "
-              "vs run-to-completion commit latency (the only async "
-              "boundary on the RTC path)"),
     Knob("objecter_stream_max_ops", lo=1, hi=256, step=2.0,
          kind="mul", cooldown_s=3.0, subsystem="client/objecter",
          desc="streaming-objecter batch window: writes coalesced "

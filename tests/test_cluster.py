@@ -6,6 +6,7 @@ the real client stack, kill OSDs and verify degraded reads and
 recovery (thrash-lite).
 """
 
+import concurrent.futures
 import os
 
 import pytest
@@ -97,3 +98,42 @@ def test_ec_isa_and_shec_pools(cluster, rados):
         payload = os.urandom(50_000)
         io.write_full("obj", payload)
         assert io.read("obj") == payload
+
+
+@pytest.mark.parametrize("kind", ["ec", "replicated"])
+def test_per_pg_ordering_under_concurrent_connections(cluster, kind):
+    """Several independent client connections hammer ONE PG
+    (pg_num=1) with appends: the PG must keep every append atomic
+    (uniform runs in the object) and each connection's own ops in
+    issue order."""
+    pool_name = f"ord_{kind}"
+    if kind == "ec":
+        cluster.create_ec_pool(pool_name, k=2, m=1, pg_num=1)
+    else:
+        cluster.create_pool(pool_name, pg_num=1, size=3)
+    setup_io = cluster.client().open_ioctx(pool_name)
+    setup_io.op_timeout = 30.0
+    setup_io.write_full("log", b"")
+    n_conns, per_conn = 4, 6
+
+    def hammer(c):
+        client = cluster.client()
+        io = client.open_ioctx(pool_name)
+        io.op_timeout = 30.0
+        for s in range(per_conn):
+            io.append("log", bytes([16 * c + s]) * 5)
+        client.shutdown()
+
+    with concurrent.futures.ThreadPoolExecutor(n_conns) as pool:
+        list(pool.map(hammer, range(n_conns)))
+    data = setup_io.read("log")
+    assert len(data) == n_conns * per_conn * 5
+    runs = []
+    for off in range(0, len(data), 5):
+        run = data[off:off + 5]
+        assert run == run[:1] * 5, (off, run)   # atomic append
+        runs.append(run[0])
+    for c in range(n_conns):
+        seq = [b % 16 for b in runs if b // 16 == c]
+        assert seq == sorted(seq), (c, seq)
+        assert len(seq) == per_conn

@@ -19,6 +19,7 @@ instruments PR 14 built.
 from __future__ import annotations
 
 import concurrent.futures
+import contextlib
 import os
 import threading
 
@@ -194,6 +195,32 @@ def test_streaming_objecter_forms_batches_and_all_ops_ack():
         assert snap["store_group_commits"] >= 1
 
 
+def _payload_of(i: int) -> bytes:
+    return bytes(((i * 13 + j) & 0xFF) for j in range(4096))
+
+
+@contextlib.contextmanager
+def _fast_resend_pool(pool: str, seed: int):
+    """A 3-OSD cluster with the fault registry reseeded, a device-
+    backed EC pool and a client whose resend ladder runs at 0.3 s:
+    yields (fault registry, ioctx)."""
+    from ceph_tpu.qa.cluster import MiniCluster
+    conf = g_conf()
+    old_resend = conf["objecter_resend_interval"]
+    conf.set("objecter_resend_interval", 0.3)
+    try:
+        with MiniCluster(n_osds=3) as cluster:
+            cluster.faults.reseed(seed)
+            cluster.create_ec_pool(pool, k=2, m=1, pg_num=4,
+                                   backend="jax")
+            io = cluster.client().open_ioctx(pool)
+            io.op_timeout = 60.0
+            io.write_full("warm", b"w")     # admission warm-up
+            yield cluster.faults, io
+    finally:
+        conf.set("objecter_resend_interval", old_resend)
+
+
 def test_dropped_batched_submit_zero_lost_acked_writes():
     """Degraded-serving parity for the new client leg: a drop rule
     written against the SINGLETON MOSDOp type fires on the batched
@@ -201,36 +228,62 @@ def test_dropped_batched_submit_zero_lost_acked_writes():
     re-drives every affected write — zero lost acked writes, every
     readback byte-exact."""
     from ceph_tpu.parallel import messages as M
-    from ceph_tpu.qa.cluster import MiniCluster
-    conf = g_conf()
-    old_resend = conf["objecter_resend_interval"]
-    conf.set("objecter_resend_interval", 0.3)
-    try:
-        with MiniCluster(n_osds=3) as cluster:
-            reg = cluster.faults
-            reg.reseed(7)
-            cluster.create_ec_pool("dz", k=2, m=1, pg_num=4,
-                                   backend="jax")
-            io = cluster.client().open_ioctx("dz")
-            io.op_timeout = 60.0
-            payload_of = (lambda i: bytes(((i * 13 + j) & 0xFF)
-                                          for j in range(4096)))
-            io.write_full("warm", b"w")     # admission warm-up
-            rule = reg.add("msgr_drop", entity="client.*",
-                           msg_type=M.MOSDOp.MSG_TYPE,
-                           every=5, max_fires=3)
-            _write_burst(io, 32, payload_of, concurrency=8)
-            rule.remove()
-            for i in range(32):
-                assert io.read(f"s{i}") == payload_of(i), \
-                    f"s{i} lost or wrong"
-            assert rule.fires >= 1
-            # the chaos path forced the real wire; batching still
-            # happened during the faulted burst
-            assert telemetry().perf.dump()[
-                "objecter_stream_batches"] >= 1
-    finally:
-        conf.set("objecter_resend_interval", old_resend)
+    with _fast_resend_pool("dz", seed=7) as (reg, io):
+        rule = reg.add("msgr_drop", entity="client.*",
+                       msg_type=M.MOSDOp.MSG_TYPE,
+                       every=5, max_fires=3)
+        _write_burst(io, 32, _payload_of, concurrency=8)
+        rule.remove()
+        for i in range(32):
+            assert io.read(f"s{i}") == _payload_of(i), \
+                f"s{i} lost or wrong"
+        assert rule.fires >= 1
+        # the chaos path forced the real wire; batching still
+        # happened during the faulted burst
+        assert telemetry().perf.dump()[
+            "objecter_stream_batches"] >= 1
+
+
+def test_dropped_op_and_reply_frames_zero_lost_acked_writes():
+    """Both directions of the client leg under the msgr fault family:
+    op frames (singleton + batch) AND reply-batch frames are dropped
+    mid-burst. A write whose REPLY was lost is already applied when
+    the resend arrives; the OSD answers it from its completed-op
+    cache — every acked write reads back byte-exact and an append is
+    applied once, not once per resend."""
+    from ceph_tpu.parallel import messages as M
+    with _fast_resend_pool("dr", seed=11) as (reg, io):
+        io.write_full("log", b"")
+        rules = [
+            reg.add("msgr_drop", entity="client.*",
+                    msg_type=M.MOSDOp.MSG_TYPE,
+                    every=4, max_fires=3),
+            reg.add("msgr_drop", entity="client.*",
+                    msg_type=M.MOSDOpBatch.MSG_TYPE,
+                    every=3, max_fires=3),
+            reg.add("msgr_drop", entity="osd.*",
+                    msg_type=M.MOSDOpReplyBatch.MSG_TYPE,
+                    every=5, max_fires=2),
+        ]
+        _write_burst(io, 24, _payload_of, concurrency=8)
+        for r in rules:
+            r.remove()
+        assert sum(r.fires for r in rules) >= 1
+        # the family map carries the singleton reply type onto the
+        # batched one: whichever frame acks an append, one in three
+        # is lost AFTER the append was applied
+        replies = reg.add("msgr_drop", entity="osd.*",
+                          msg_type=M.MOSDOpReply.MSG_TYPE,
+                          every=3, max_fires=4)
+        for i in range(12):
+            io.append("log", bytes([i]) * 7)
+        replies.remove()
+        assert replies.fires >= 1
+        for i in range(24):
+            assert io.read(f"s{i}") == _payload_of(i), \
+                f"s{i} lost or wrong"
+        assert io.read("log") == b"".join(
+            bytes([i]) * 7 for i in range(12))
 
 
 def test_group_commit_fsync_reduction_end_to_end(tmp_path):

@@ -6,6 +6,8 @@ and the flows-off literal-NOOP contract (the kill switch must cost
 one cached-bool read, materialize nothing, and tag nothing).
 """
 
+import concurrent.futures
+import json
 import threading
 
 import pytest
@@ -288,3 +290,69 @@ def test_flows_off_client_ops_carry_no_label(monkeypatch):
     finally:
         monkeypatch.delenv("CEPH_TPU_FLOWS", raising=False)
         FT.reset_for_tests()
+
+
+def test_multi_tenant_cluster_burst_attributes_flows(flows):
+    """The labels survive the whole served path: a multi-tenant burst
+    against a MiniCluster attributes per-tenant ops, bytes both ways
+    and the store-txn bytes of its EC sub-writes, with >= 95 %
+    coverage of ops and bytes — witness-armed, since the attribution
+    seams run inside the op-wq workers and the engine's continuations
+    and must not add a blocking edge the lock discipline forbids."""
+    from ceph_tpu.analysis import lock_witness as lw
+    from ceph_tpu.qa.cluster import MiniCluster
+
+    env_armed = lw.env_enabled()
+    if not env_armed:
+        lw.enable()
+    try:
+        with MiniCluster(n_osds=3) as cluster:
+            cluster.create_ec_pool("mt", k=2, m=1, pg_num=4,
+                                   backend="jax")
+            client = cluster.client()
+            warm = client.open_ioctx("mt")
+            warm.op_timeout = 30.0
+            warm.set_flow("warmup")
+            warm.write_full("warm", b"w" * 1024)
+            flows.reset()
+            tenants = ("acme", "globex", "initech")
+            ios = []
+            for t in tenants:
+                tio = client.open_ioctx("mt")
+                tio.op_timeout = 30.0
+                tio.set_flow(t)
+                ios.append(tio)
+
+            def burst(i):
+                tio = ios[i % len(ios)]
+                tio.write_full(f"{tenants[i % 3]}_{i}", b"x" * 4096)
+                assert tio.read(f"{tenants[i % 3]}_{i}") \
+                    == b"x" * 4096
+
+            with concurrent.futures.ThreadPoolExecutor(4) as pool:
+                list(pool.map(burst, range(18)))
+
+            tel = FT.telemetry()
+            table = tel.flow_table()["flows"]
+            for t in tenants:
+                row = table.get(t)
+                assert row is not None, (t, sorted(table))
+                # each tenant: 6 writes + 6 reads attributed, bytes
+                # both directions, and its EC sub-writes' store txn
+                # bytes charged back to it on the serving OSDs
+                assert row["ops"] >= 12, (t, row)
+                assert row["bytes_in"] >= 6 * 4096, (t, row)
+                assert row["bytes_out"] >= 6 * 4096, (t, row)
+                assert row["store_txn_bytes"] > 0, (t, row)
+            att = tel.attribution()
+            assert att["ops_pct"] >= 95.0, att
+            assert att["bytes_pct"] >= 95.0, att
+    finally:
+        if not env_armed:
+            rep = lw.report()
+            bad = lw.unacknowledged(rep)
+            lw.disable()
+            lw.reset()
+            assert not bad, (
+                "unacknowledged witness findings on the multi-tenant "
+                "burst: " + json.dumps(bad, indent=1)[:2000])

@@ -397,40 +397,6 @@ def test_minicluster_durable_group_commit_burst_clean(witness,
         + json.dumps(bad, indent=1)[:2000])
 
 
-def test_crimson_write_burst_clean(witness):
-    """ISSUE 18 satellite: the witness armed over the crimson
-    shard-per-core data path — boot, EC pool, concurrent write burst
-    across connections, reads, teardown. The few deliberate
-    cross-shard edges (map waiters, tid counter, sub-write batch
-    fan-in) are witnessed ``make_lock`` sites; the gate pins that
-    they stay cycle-free and never block under a lock the op path
-    also takes: zero unacknowledged findings."""
-    import concurrent.futures
-
-    from ceph_tpu.qa.cluster import MiniCluster
-
-    def scenario():
-        with MiniCluster(n_osds=3, osd_flavor="crimson") as c:
-            c.create_ec_pool("cwit", k=2, m=1, pg_num=4)
-            ioctx = c.client().open_ioctx("cwit")
-            payload = bytes(range(256)) * 8
-            with concurrent.futures.ThreadPoolExecutor(8) as pool:
-                list(pool.map(
-                    lambda i: ioctx.write_full(f"c-{i}", payload),
-                    range(32)))
-            for i in range(32):
-                assert ioctx.read(f"c-{i}") == payload
-            c.wait_for_clean(timeout=30)
-
-    _run_bounded(scenario, timeout=120.0)
-    rep = lw.report()
-    assert rep["edges"] > 0
-    bad = lw.unacknowledged(rep)
-    assert not bad, (
-        "unacknowledged witness findings on the crimson data path: "
-        + json.dumps(bad, indent=1)[:2000])
-
-
 def test_witness_baseline_entries_are_justified():
     """No silent allowlisting: every acknowledged witness finding
     carries a written justification."""

@@ -152,7 +152,7 @@ def test_bench_budget_sum_bounded():
     assert 0 < tb and tb + eb <= 100, (tb, eb)
     # ISSUE 20: the multi-tenant fairness row is budgeted like every
     # other metric and the deadline identity absorbed it (TOTAL_BUDGET
-    # 285 -> 250 covers the extra warmup reservation its BUDGETS entry
+    # 320 -> 285 covers the extra warmup reservation its BUDGETS entry
     # adds, so the fully-cold 870 s worst case is preserved)
     assert "multi_tenant" in bench.BUDGETS
     tb, eb = bench.BUDGETS["multi_tenant"]

@@ -691,99 +691,149 @@ def test_real_store_files_have_no_untimed_fsyncs():
         assert linters.check_fsync_seam(src) == [], src.rel
 
 # ---------------------------------------------------------------------------
-# family 6: reactor affinity (ISSUE 18) — seeded violations
+# family 6: layering — seeded violations, then the live tree by package
 # ---------------------------------------------------------------------------
 
-def _affinity_keys(text: str,
-                   rel: str = "ceph_tpu/crimson/synth.py") -> set[str]:
-    fs = linters.check_reactor_affinity(_src(text, rel=rel))
+def _layer_keys(text: str,
+                rel: str = "ceph_tpu/store/synth.py") -> set[str]:
+    fs = linters.check_layering(_src(text, rel=rel))
     return {f.key for f in fs}
 
 
-def test_reactor_affinity_global_state_caught():
-    keys = _affinity_keys('''
-_EPOCH = 0
-
-def bump():
-    global _EPOCH
-    _EPOCH += 1
-''')
-    assert ("reactor-affinity:ceph_tpu/crimson/synth.py:bump:global"
-            in keys)
+def test_layering_upward_import_caught():
+    assert _layer_keys('''
+from ceph_tpu.osd.osd import OSD
+import ceph_tpu.mgr.tuner as tuner
+''') == {"layering:ceph_tpu/store/synth.py:osd",
+          "layering:ceph_tpu/store/synth.py:mgr"}
 
 
-def test_reactor_affinity_blocking_sleep_in_coroutine_caught():
-    keys = _affinity_keys('''
-import time
+def test_layering_function_local_upward_import_caught():
+    """Deferring the import into a function hides the cycle from the
+    interpreter, not the dependency from the reader."""
+    assert _layer_keys('''
+def tail():
+    try:
+        from ceph_tpu.qa import cluster
+    except Exception:
+        return None
+''') == {"layering:ceph_tpu/store/synth.py:qa"}
 
-async def beacon_loop(self):
-    while True:
-        time.sleep(1.0)
-''')
-    assert ("reactor-affinity:ceph_tpu/crimson/synth.py:"
-            "beacon_loop:blocking-sleep" in keys)
 
-
-def test_reactor_affinity_sync_sleep_outside_coroutine_clean():
-    """time.sleep in a plain (control-plane) function is not a
-    reactor stall — only coroutines run on the reactor."""
-    assert _affinity_keys('''
-import time
-
-def wait_for_boot(self):
-    time.sleep(0.1)
+def test_layering_same_layer_import_clean():
+    assert _layer_keys('''
+from ceph_tpu.parallel import messages
+from ceph_tpu.store.kv import KV
 ''') == set()
 
 
-def test_reactor_affinity_raw_lock_caught():
-    keys = _affinity_keys('''
-import threading
+def test_layering_downward_and_foreign_imports_clean():
+    assert _layer_keys('''
+import numpy as np
+from ceph_tpu.utils import config
+from ceph_tpu.ops import gf
+from ceph_tpu import models
 
-class Shard:
-    def __init__(self):
-        self._lock = threading.Lock()
-''')
-    assert ("reactor-affinity:ceph_tpu/crimson/synth.py:"
-            "__init__:raw-lock" in keys)
-
-
-def test_reactor_affinity_witnessed_lock_and_asyncio_clean():
-    assert _affinity_keys('''
-import asyncio
-from ceph_tpu.analysis.lock_witness import make_lock
-
-class Shard:
-    def __init__(self):
-        self._lock = make_lock("crimson.synth")
-
-    async def tick(self):
-        await asyncio.sleep(0.1)
+def f():
+    import jax
+    from ceph_tpu.analysis.lock_witness import make_lock
 ''') == set()
 
 
-def test_reactor_affinity_scoped_to_crimson():
-    """The discipline scopes to ceph_tpu/crimson/ — threaded daemons
-    may use module state and raw primitives (their own lints apply)."""
-    assert _affinity_keys('''
-import threading
+def test_layering_benchmark_import_from_program_caught():
+    """The benchmark, the old harness, the smoke and the tests read
+    the program; the program reads none of them — from any layer."""
+    assert _layer_keys('''
+from benchmarks import harness
+import bench
 
-_STATE = {}
+def probe():
+    import chip_smoke
+    from tests.conftest import anything
+''', rel="ceph_tpu/tools/synth.py") == {
+        "layering:ceph_tpu/tools/synth.py:benchmarks",
+        "layering:ceph_tpu/tools/synth.py:bench",
+        "layering:ceph_tpu/tools/synth.py:chip_smoke",
+        "layering:ceph_tpu/tools/synth.py:tests"}
+    # the package of the same name is not the old harness
+    assert _layer_keys("from ceph_tpu.bench import load_gen\n",
+                       rel="ceph_tpu/tools/synth.py") == set()
 
-def anywhere():
-    global _STATE
-    _STATE = {"lock": threading.Lock()}
-''', rel="ceph_tpu/osd/synth.py") == set()
+
+def test_layering_relative_and_dynamic_imports_resolved():
+    """``from ..osd import x`` and ``__import__("ceph_tpu.osd.x")``
+    name a package as surely as the absolute statement does."""
+    assert _layer_keys('''
+import importlib
+from ..osd import ec_util
+from . import kv
+from .kv import KV
+
+def late():
+    a = __import__("ceph_tpu.client.rados", fromlist=["RadosClient"])
+    b = importlib.import_module("ceph_tpu.services.rgw")
+    return importlib.import_module(a.name)
+''') == {"layering:ceph_tpu/store/synth.py:osd",
+          "layering:ceph_tpu/store/synth.py:client",
+          "layering:ceph_tpu/store/synth.py:services"}
 
 
-def test_reactor_affinity_live_crimson_tree_clean():
-    """The live contract: the shipped crimson subsystem satisfies its
-    own discipline TODAY."""
-    crimson_srcs = [s for s in linters.iter_sources()
-                    if s.rel.replace(os.sep, "/").startswith(
-                        "ceph_tpu/crimson/")]
-    assert crimson_srcs
-    for src in crimson_srcs:
-        assert linters.check_reactor_affinity(src) == [], src.rel
+def test_layering_package_missing_from_table_is_a_finding():
+    """The map cannot fall behind the tree: a new package must be
+    placed before it can be imported or can import."""
+    assert _layer_keys("X = 1\n", rel="ceph_tpu/newpkg/mod.py") == {
+        "layering:unmapped:newpkg"}
+    assert _layer_keys("from ceph_tpu.newpkg import mod\n") == {
+        "layering:unmapped:newpkg"}
+
+
+def test_layering_baseline_arrow_accepted_and_stale_reported():
+    findings = linters.check_layering(_src(
+        "from ceph_tpu.osd import ec_util\n",
+        rel="ceph_tpu/store/synth.py"))
+    baseline = {"lint": [
+        {"key": "layering:ceph_tpu/store/synth.py:osd",
+         "justification": "debt: moves down with ec_util"},
+        {"key": "layering:ceph_tpu/store/synth.py:mgr",
+         "justification": "debt: paid, nobody pruned it"}]}
+    new, stale = linters.diff_baseline(findings, baseline)
+    assert new == []
+    assert [e["key"] for e in stale] == [
+        "layering:ceph_tpu/store/synth.py:mgr"]
+    new, _ = linters.diff_baseline(findings, {"lint": []})
+    assert [f.key for f in new] == [
+        "layering:ceph_tpu/store/synth.py:osd"]
+
+
+def test_layering_outside_the_package_not_flagged():
+    """The benchmark and the tests import the whole program."""
+    assert _layer_keys("from ceph_tpu.qa.cluster import MiniCluster\n",
+                       rel="benchmarks/synth.py") == set()
+
+
+_PACKAGES = sorted(
+    d for d in os.listdir(linters.PKG_ROOT)
+    if os.path.isfile(os.path.join(linters.PKG_ROOT, d, "__init__.py")))
+
+
+@pytest.mark.parametrize("pkg", _PACKAGES)
+def test_layering_live_package_has_no_arrow_outside_baseline(pkg):
+    """The live contract, one case a package so a failure names it:
+    every import in the package names its own layer or a lower one,
+    or is a justified debt in analysis/baseline.json."""
+    srcs = linters.iter_sources(os.path.join(linters.PKG_ROOT, pkg))
+    assert srcs
+    findings = [f for src in srcs
+                for f in linters.check_layering(src)]
+    new, _stale = linters.diff_baseline(findings)
+    assert not new, "\n".join(f.format() for f in new)
+
+
+def test_layering_table_places_every_live_package_once():
+    placed = [p for layer in linters.LAYERS for p in layer]
+    assert sorted(placed) == _PACKAGES
+    assert len(placed) == len(set(placed))
+
 
 # ---------------------------------------------------------------------------
 # family 7: flow context (ISSUE 20) — seeded violations
@@ -838,6 +888,6 @@ def capture_flow(qos="client"):
 def test_flow_context_live_tree_clean():
     """The live contract: every shipped qos= seam threads the flow
     context TODAY (ShardedOpWQ.enqueue captures it into the work
-    item; crimson has no cross-thread queue to lose it on)."""
+    item)."""
     for src in linters.iter_sources():
         assert linters.check_flow_context(src) == [], src.rel
