@@ -805,7 +805,11 @@ class DeviceEncodeEngine:
         surviving ``shards``; ``cont(decoded, err)`` runs INLINE on
         the engine thread (must be cheap and lock-free — the typical
         continuation publishes the result and sets an event for a
-        blocked decode_sync caller)."""
+        blocked decode_sync caller). ``ECBackend.read_object_async``
+        violates this: its continuation reassembles the object and
+        sends the reply here, one op of a flush after the other.
+        Dispatching it on ``key`` instead was measured and earned no
+        rate (PERF.md section 6, PR 33)."""
         import time as _time
         _telemetry().note_hbm(staged_delta=_shards_nbytes(shards))
         self._note_staged_flow(cont, _shards_nbytes(shards))

@@ -253,12 +253,17 @@ class ECBackend(PGBackend):
 
     def _chunks_to_logical(self, shards: dict[int, np.ndarray],
                            size: int) -> bytes:
+        # stripe-major by one strided copy a chunk stream, array to
+        # array, then a contiguous ``tobytes``: ``tobytes`` of a
+        # strided view walks it element by element HOLDING the
+        # interpreter lock
         cs = self.sinfo.chunk_size
-        arr = np.stack([np.asarray(shards[i], dtype=np.uint8)
-                        for i in range(self.k)])
-        s = arr.shape[1] // cs
-        out = arr.reshape(self.k, s, cs).transpose(1, 0, 2).tobytes()
-        return out[:size]
+        s = np.asarray(shards[0]).size // cs
+        out = np.empty((s, self.k, cs), dtype=np.uint8)
+        for i in range(self.k):
+            out[:, i, :] = np.asarray(
+                shards[i], dtype=np.uint8).reshape(s, cs)
+        return out.tobytes()[:size]
 
     # -- writes -------------------------------------------------------
     def _fan_out(self, pg: PG, oid: str, version: int, op: int,
@@ -1202,7 +1207,10 @@ class ECBackend(PGBackend):
         signature-grouped decode flush instead of N serial
         ``decode_sync`` launches. ``cont(data, err)`` then runs on
         the engine thread; a device fault falls back to the host twin
-        inline (counted, never silent).
+        inline (counted, never silent). That continuation reassembles
+        and replies, which is more than ``stage_decode`` allows its
+        ``cont`` (cheap and lock-free): known, measured and left so
+        (PERF.md section 6, PR 33).
 
         Hot objects (read_heat past osd_hot_read_threshold) rotate
         their shard read set (any-k balanced reads, ROADMAP 3): a
