@@ -14,9 +14,17 @@ so the daemon's encode work is decoupled from the op path:
   flush program depends on) into ONE device kernel launch
   via :class:`ceph_tpu.osd.ec_util.StripeBatcher`, then dispatches
   each op's continuation (hinfo + shard-txn build + fan-out) back
-  onto the OSD's sharded op queue.
-- ``stage_barrier`` queues a NON-encode mutation (remove, RMW
-  partial write). A barrier flushes everything staged before it and
+  onto the OSD's sharded op queue. A range overwrite's spliced stripe
+  window stages the same way (``overwrite=True``): overwrites of every
+  PG meet in an overwrite flush of their own, one GF encode on the
+  device with no crc pass and no host route, held to one bucket
+  (``ec_util.OVERWRITE_BUCKET``) so that one program serves every
+  batch, whatever the queue depth. A PG whose ops would sit in both
+  kinds of group flushes first: groups flush one after the other,
+  and its ops must ship in submission order.
+- ``stage_barrier`` queues a NON-encode mutation (remove, truncate,
+  setattrs, the partial write of a codec without the overwrite
+  route). A barrier flushes everything staged before it and
   is dispatched after those continuations — on the same per-PG FIFO
   wq shard — so per-PG commit order is exactly submission order (the
   check_ops pipeline-ordering invariant, ECBackend.cc:2107-2112).
@@ -553,7 +561,12 @@ class DeviceEncodeEngine:
                       # builds when it peers are not among them)
                       "layered_encode_ops": 0,
                       "layered_decode_ops": 0,
-                      "signature_builds": 0}
+                      "signature_builds": 0,
+                      # range overwrites' spliced stripe windows
+                      # encoded by the overwrite route (never
+                      # host-routed, no crc pass); also counted in
+                      # ops / flushes
+                      "overwrite_ops": 0, "overwrite_flushes": 0}
         _telemetry().note_engine_window(self._window)
         #: launch pipeline: deque of (items, finalize, kspans,
         #: nbytes) batches whose device programs are queued
@@ -749,7 +762,8 @@ class DeviceEncodeEngine:
                      data: np.ndarray,
                      cont: Callable[[dict | None, dict | None,
                                      Exception | None], None],
-                     span=NOOP, clock=_stage_clock.NOOP) -> None:
+                     span=NOOP, clock=_stage_clock.NOOP,
+                     overwrite: bool = False) -> None:
         """Queue one op's stripe-aligned payload for batched device
         encode; ``cont(shards, crcs, err)`` is dispatched on ``key``
         (crcs = per-shard LINEAR crc parts computed on device from the
@@ -759,7 +773,12 @@ class DeviceEncodeEngine:
         dispatch, crc pass events); ``clock``: the op's StageClock —
         the engine marks engine_stage_wait / device_window_wait /
         device_finalize on it, so the per-op timeline survives the
-        engine boundary. Both defaults are free no-ops."""
+        engine boundary. Both defaults are free no-ops.
+        ``overwrite``: the payload is a range overwrite's spliced
+        stripe window; it meets only other overwrites in a flush,
+        which encodes on the device without a crc pass (crcs None)
+        whatever its size (``ec_util._flush_device_fused_async``
+        without crcs)."""
         import time as _time
         # HBM ledger: bytes enter the staged bucket here and leave it
         # at launch (-> in-window) or on a launch fault (-> retired)
@@ -775,7 +794,7 @@ class DeviceEncodeEngine:
         # the key under which this op meets others in a flush,
         # computed HERE and carried on the queue: the stager's buffer
         # and the engine's batch are found by the same value
-        gkey = (program_key(codec, sinfo), pslot)
+        gkey = (program_key(codec, sinfo), pslot, overwrite)
         if self._stager is not None:
             # zero-copy staging: the payload lands in its program
             # key's concat buffer NOW, on this producer thread; the
@@ -961,6 +980,10 @@ class DeviceEncodeEngine:
                     # thread pickup, one cross-thread hop per stage
                     _dsp.telemetry().note_handoff(
                         "engine_stage", _time.monotonic() - ts)
+                    if self._flush_first(pending, gkey, key, data):
+                        self._flush(pending)
+                        self._flush_decodes(dec_pending)
+                        pending, dec_pending, nbytes = {}, {}, 0
                     _, _, _, items = pending.setdefault(
                         gkey, (codec, sinfo, gkey[1], []))
                     items.append((key, data, cont, span, clock, ts))
@@ -1036,6 +1059,25 @@ class DeviceEncodeEngine:
             # staged before stop() must still flush (checking the
             # flag here raced the idle drain and dropped them)
 
+    @staticmethod
+    def _flush_first(pending: dict, gkey, key, data) -> bool:
+        """Whether what is pending flushes before an op of ``key``
+        joins group ``gkey``: the PG has ops pending under the other
+        kind of group (full write / overwrite; the groups of one flush
+        launch one after the other, so the PG's ops would ship out of
+        submission order), or the op would take an overwrite group
+        past one bucket (``ec_util.OVERWRITE_BUCKET`` a shard)."""
+        other = pending.get(gkey[:2] + (not gkey[2],))
+        if other is not None and any(it[0] == key for it in other[3]):
+            return True
+        group = pending.get(gkey)
+        if not gkey[2] or group is None:
+            return False
+        sinfo, items = group[1], group[3]
+        held = sum(it[1].nbytes for it in items) + data.nbytes
+        return (held // sinfo.stripe_width * sinfo.chunk_size
+                > ec_util.OVERWRITE_BUCKET)
+
     def _flush(self, pending: dict) -> None:
         for gkey, (codec, sinfo, pslot, items) in pending.items():
             # profiler join: while the engine thread stages/launches,
@@ -1075,8 +1117,12 @@ class DeviceEncodeEngine:
         # collective/placement overhead; small flushes stay on
         # the single-chip kernel (the dense-vs-sharded threshold,
         # BASELINE.md "Pipelined engine")
+        # an overwrite flush (range overwrites' stripe windows) takes
+        # neither the mesh nor the host route: one device program
+        overwrite = gkey[2]
         mesh = mesh_mod.get_default_mesh()
-        if mesh is not None and nbytes < self._mesh_flush_bytes:
+        if overwrite or (mesh is not None
+                         and nbytes < self._mesh_flush_bytes):
             mesh = None
         placed = False
         if mesh is not None:
@@ -1097,12 +1143,12 @@ class DeviceEncodeEngine:
         # calibration below it. The encode runs at finalize time
         # on the RETIRE thread, riding the same FIFO as device
         # batches, so ordering is identical.
-        host = (self._bulk and mesh is None
+        host = (self._bulk and mesh is None and not overwrite
                 and nbytes < self._host_flush_bytes
                 and ec_util.host_flushable(codec))
         if batch is not None:
             _telemetry().note_staging_copies_avoided(nbytes)
-        if not host:
+        if not host and not overwrite:
             batcher = ec_util.StripeBatcher(
                 sinfo, codec, mesh=mesh,
                 on_fallback=self._note_fused_fallback)
@@ -1139,6 +1185,10 @@ class DeviceEncodeEngine:
                     sinfo, codec, list(range(len(views))),
                     views, batch=batch)
                 self.stats["host_flushes"] += 1
+            elif overwrite:
+                finalize = ec_util._flush_device_fused_async(
+                    sinfo, codec, list(range(len(views))),
+                    views, batch=batch, with_crcs=False)
             else:
                 finalize = batcher.flush_async(
                     with_crcs=ec_util.fuse_crc_policy(codec))
@@ -1271,6 +1321,9 @@ class DeviceEncodeEngine:
             self.stats["ops"] += len(items)
             if getattr(finalize, "layered", False):
                 self.stats["layered_encode_ops"] += len(items)
+            if getattr(finalize, "overwrite", False):
+                self.stats["overwrite_ops"] += len(items)
+                self.stats["overwrite_flushes"] += 1
             if _spans_keys(items):
                 self.stats["cross_pg_ops"] += len(items)
             self.stats["bytes"] += nbytes

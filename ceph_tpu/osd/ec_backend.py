@@ -150,6 +150,9 @@ class ECBackend(PGBackend):
         self._shard_cache: OrderedDict[tuple, tuple[int, np.ndarray]] \
             = OrderedDict()
         self._shard_cache_lock = threading.Lock()
+        #: pgid -> engine barriers staged and not yet run
+        self._barriers: dict[tuple, int] = {}
+        self._barriers_lock = threading.Lock()
 
     #: hot-shard cache entry cap — entries are single chunks of hot
     #: objects only, so this bounds worst-case memory at cap × chunk
@@ -532,6 +535,33 @@ class ECBackend(PGBackend):
             self._unpin_on_commit(pg, oid, version, on_commit),
             "ec_sub_write", supersedes_recovery=True)
 
+    def _stage_barrier(self, pg: PG, run: Callable[[], None]) -> None:
+        """Run ``run`` under ``pg.lock`` on the PG's op queue behind an
+        engine barrier (a staged-but-unflushed write of this PG fans
+        out first), with the op's span and stage clock current: the
+        barrier runs on the engine's dispatch, where both are NOOP.
+        Until it has run, a range overwrite of the PG takes this path
+        too (``_overwrite_on_device``): its read has to see what the
+        barrier's op writes."""
+        op_span = tracing.current()
+        op_clock = stage_clock.current()
+        op_clock.mark("pg_process")
+        with self._barriers_lock:
+            self._barriers[pg.pgid] = self._barriers.get(pg.pgid, 0) + 1
+
+        def barrier() -> None:
+            with pg.lock:
+                tracing.set_current(op_span)
+                stage_clock.set_current(op_clock)
+                try:
+                    run()
+                finally:
+                    with self._barriers_lock:
+                        self._barriers[pg.pgid] -= 1
+                    tracing.set_current(tracing.NOOP)
+                    stage_clock.set_current(stage_clock.NOOP)
+        self.device.stage_barrier(pg.pgid, barrier)
+
     def submit_remove(self, pg: PG, oid: str, version: int,
                       on_commit: Callable[[int], None]) -> None:
         pg.extent_cache.pin(oid, version, 0, b"", 0, full=True,
@@ -547,25 +577,8 @@ class ECBackend(PGBackend):
         if self.device is not None:
             # ordering barrier: a staged-but-unflushed write to this
             # object must fan out BEFORE the remove, or the remove
-            # would be resurrected by the older write's txn (the op
-            # span rides along — barriers run on the engine's
-            # dispatch, where current() is NOOP)
-            op_span = tracing.current()
-
-            op_clock = stage_clock.current()
-            op_clock.mark("pg_process")
-
-            def barrier(pg=pg, op_span=op_span,
-                        op_clock=op_clock) -> None:
-                with pg.lock:
-                    tracing.set_current(op_span)
-                    stage_clock.set_current(op_clock)
-                    try:
-                        run()
-                    finally:
-                        tracing.set_current(tracing.NOOP)
-                        stage_clock.set_current(stage_clock.NOOP)
-            self.device.stage_barrier(pg.pgid, barrier)
+            # would be resurrected by the older write's txn
+            self._stage_barrier(pg, run)
             return
         run()
 
@@ -574,9 +587,9 @@ class ECBackend(PGBackend):
                         on_commit: Callable[[int], None]) -> None:
         """Truncate = ordered read + full rewrite. On the device path
         the read DEFERS behind an engine barrier, exactly like
-        submit_remove/partial-write: a pipelined in-flight write of
-        this object fans out first, and the version-agreement retry
-        in _read_shards then sees its bytes — no lost update."""
+        submit_remove: a pipelined in-flight write of this object fans
+        out first, and the version-agreement retry in _read_shards
+        then sees its bytes — no lost update."""
         def run() -> None:
             try:
                 cur = self.read_object(pg, oid)
@@ -592,22 +605,7 @@ class ECBackend(PGBackend):
             self.submit_write(pg, oid, data, version, on_commit)
 
         if self.device is not None:
-            op_span = tracing.current()
-
-            op_clock = stage_clock.current()
-            op_clock.mark("pg_process")
-
-            def barrier(pg=pg, op_span=op_span,
-                        op_clock=op_clock) -> None:
-                with pg.lock:
-                    tracing.set_current(op_span)
-                    stage_clock.set_current(op_clock)
-                    try:
-                        run()
-                    finally:
-                        tracing.set_current(tracing.NOOP)
-                        stage_clock.set_current(stage_clock.NOOP)
-            self.device.stage_barrier(pg.pgid, barrier)
+            self._stage_barrier(pg, run)
             return
         run()
 
@@ -654,22 +652,7 @@ class ECBackend(PGBackend):
             # object must fan out first, or its (deferred) txn would
             # land after ours with an OLDER "v" — shard versions would
             # regress against the log
-            op_span = tracing.current()
-
-            op_clock = stage_clock.current()
-            op_clock.mark("pg_process")
-
-            def barrier(pg=pg, op_span=op_span,
-                        op_clock=op_clock) -> None:
-                with pg.lock:
-                    tracing.set_current(op_span)
-                    stage_clock.set_current(op_clock)
-                    try:
-                        run()
-                    finally:
-                        tracing.set_current(tracing.NOOP)
-                        stage_clock.set_current(stage_clock.NOOP)
-            self.device.stage_barrier(pg.pgid, barrier)
+            self._stage_barrier(pg, run)
             return
         run()
 
@@ -696,6 +679,14 @@ class ECBackend(PGBackend):
         _, attrs = self._read_shards(pg, oid, [0])
         return user_xattrs(attrs)
 
+    def _overwrite_on_device(self, pg: PG) -> bool:
+        """Whether a range overwrite of ``pg`` takes the engine's
+        overwrite route now: a matrix codec on a device backend, and
+        no barrier of the PG still waiting to run."""
+        return (self.device is not None
+                and ec_util.device_fusable(self.device_codec)
+                and not self._barriers.get(pg.pgid))
+
     def submit_partial_write(self, pg: PG, oid: str, offset: int,
                              data: bytes, version: int,
                              on_commit: Callable[[int], None],
@@ -711,11 +702,54 @@ class ECBackend(PGBackend):
         store's own blob checksums, exactly as the reference requires
         bluestore for EC-overwrite pools (ecbackend.rst:7-12).
 
+        On a matrix codec's device backend the read and splice run NOW,
+        at the op's place in the PG's queue (the extent cache overlays
+        every in-flight write of the object, a staged full write
+        among them), and the window stages on the engine as an
+        overwrite encode: in submission order with the PG's other ops,
+        so its fan-out runs after every earlier op's of the PG, and
+        overwrites of every PG share a flush. Other codecs, and any
+        overwrite while a barrier of the PG waits, defer the whole RMW
+        behind an engine barrier and encode inline.
+
         Raises StoreError when the object's current state cannot be
         read (degraded beyond reach): a transient read failure must
         fail the op, never silently truncate to old_size=0.
         """
         data = bytes(data)
+        if self._overwrite_on_device(pg):
+            stage_clock.current().mark("pg_process")
+            a, window, new_size = self._rmw_window(
+                pg, oid, offset, data, version, old_size)
+            op_span = tracing.current()
+            op_clock = stage_clock.current()
+            done = self._unpin_on_commit(pg, oid, version, on_commit)
+
+            def cont(shards, _crcs, err, pg=pg, oid=oid, version=version,
+                     window=window, a=a, new_size=new_size,
+                     op_span=op_span, op_clock=op_clock):
+                if shards is None:
+                    log(0, f"device overwrite encode failed for {oid} "
+                        f"({err!r}); host fallback")
+                    op_span.set_error(f"engine_fallback: {err!r}")
+                    shards = ec_util.encode(self.sinfo, self.codec,
+                                            window)
+                with pg.lock:
+                    tracing.set_current(op_span)
+                    stage_clock.set_current(op_clock)
+                    try:
+                        self._range_write(pg, oid, version, a, shards,
+                                          new_size, done)
+                    finally:
+                        tracing.set_current(tracing.NOOP)
+                        stage_clock.set_current(stage_clock.NOOP)
+
+            self.device.stage_encode(
+                pg.pgid, self.device_codec, self.sinfo,
+                np.frombuffer(window, dtype=np.uint8), cont,
+                span=op_span.child("engine_flush"), clock=op_clock,
+                overwrite=True)
+            return
         if self.device is not None:
             # defer behind the engine as an ordering barrier: a staged
             # full write of this object must fan out first, or its
@@ -731,32 +765,20 @@ class ECBackend(PGBackend):
             pg.extent_cache.pin(oid, version, offset, data,
                                 max(base, end), full=False)
 
-            op_span = tracing.current()
-            op_clock = stage_clock.current()
-            op_clock.mark("pg_process")
+            def run() -> None:
+                try:
+                    self._submit_partial_write_sync(
+                        pg, oid, offset, data, version, on_commit,
+                        old_size)
+                except StoreError as exc:
+                    log(1, f"deferred partial write {oid} "
+                        f"v{version} failed: {exc}")
+                    pg.extent_cache.unpin(oid, version)
+                    on_commit(-5)
 
-            def barrier(pg=pg, oid=oid, offset=offset, data=data,
-                        version=version, on_commit=on_commit,
-                        old_size=old_size, op_span=op_span,
-                        op_clock=op_clock) -> None:
-                with pg.lock:
-                    tracing.set_current(op_span)
-                    stage_clock.set_current(op_clock)
-                    try:
-                        self._submit_partial_write_sync(
-                            pg, oid, offset, data, version, on_commit,
-                            old_size)
-                    except StoreError as exc:
-                        log(1, f"deferred partial write {oid} "
-                            f"v{version} failed: {exc}")
-                        pg.extent_cache.unpin(oid, version)
-                        on_commit(-5)
-                    finally:
-                        tracing.set_current(tracing.NOOP)
-                        stage_clock.set_current(stage_clock.NOOP)
-
-            self.device.stage_barrier(pg.pgid, barrier)
+            self._stage_barrier(pg, run)
             return
+        stage_clock.current().mark("pg_process")
         self._submit_partial_write_sync(pg, oid, offset, data, version,
                                         on_commit, old_size)
 
@@ -764,6 +786,23 @@ class ECBackend(PGBackend):
                                    data: bytes, version: int,
                                    on_commit: Callable[[int], None],
                                    old_size: int | None = None) -> None:
+        a, window, new_size = self._rmw_window(pg, oid, offset, data,
+                                               version, old_size)
+        shards = ec_util.encode(self.sinfo, self.codec, window)
+        self._range_write(pg, oid, version, a, shards, new_size,
+                          self._unpin_on_commit(pg, oid, version,
+                                                on_commit))
+
+    def _rmw_window(self, pg: PG, oid: str, offset: int, data: bytes,
+                    version: int, old_size: int | None
+                    ) -> tuple[int, bytes, int]:
+        """The whole stripes ``data`` at ``offset`` touches, as they
+        read once it is spliced in: ``(window start, window bytes,
+        object size after the write)``; the window is pinned in the
+        extent cache at ``version``. The ranged read of the stripes'
+        k data chunks is the profiler state ``rmw_read`` (role
+        ``osd_wq``), and the op's stage clock marks ``rmw_read`` when
+        it returns."""
         sw, cs = self.sinfo.stripe_width, self.sinfo.chunk_size
         end = offset + len(data)
         if old_size is None:
@@ -802,6 +841,7 @@ class ECBackend(PGBackend):
                 # no shard read at all (the pure pipelined case)
                 chunks = None
             else:
+                mark = _prof.push_stage("pg_process", span="rmw_read")
                 try:
                     chunks, rattrs = self._read_shards(
                         pg, oid, want,
@@ -812,6 +852,9 @@ class ECBackend(PGBackend):
                     # committed state doesn't exist yet: the whole
                     # object is in flight — the overlay reconstructs it
                     chunks, rattrs = None, {}
+                finally:
+                    _prof.pop_stage(mark)
+                stage_clock.current().mark("rmw_read")
             if chunks is not None:
                 base_ver = int.from_bytes(rattrs.get("v", b""),
                                           "little")
@@ -822,13 +865,20 @@ class ECBackend(PGBackend):
                 window[:len(old_win)] = old_win
             snap.overlay(window, a, base_ver)
         window[offset - a:end - a] = data
+        window = bytes(window)
         # pin the WHOLE spliced window, not just the written bytes: a
         # later overlapping RMW that reads a mixed-version shard set
         # must be able to replace every stripe this write re-encodes
-        pg.extent_cache.pin(oid, version, a, bytes(window), new_size,
+        pg.extent_cache.pin(oid, version, a, window, new_size,
                             full=False)
-        shards = ec_util.encode(self.sinfo, self.codec, bytes(window))
-        chunk_off = (a // sw) * cs
+        return a, window, new_size
+
+    def _range_write(self, pg: PG, oid: str, version: int, a: int,
+                     shards: dict[int, np.ndarray], new_size: int,
+                     on_commit: Callable[[int], None]) -> None:
+        """Range-write each shard's chunks of the window that starts at
+        logical offset ``a``, drop ``hinfo``, and fan out."""
+        chunk_off = (a // self.sinfo.stripe_width) * self.sinfo.chunk_size
         size_raw = new_size.to_bytes(8, "little")
 
         def build(pos: int, cid: str) -> Transaction:
@@ -841,8 +891,7 @@ class ECBackend(PGBackend):
             txn.rmattr(cid, oid, "hinfo")
             return txn
 
-        self._fan_out(pg, oid, version, LOG_WRITE, build,
-                      self._unpin_on_commit(pg, oid, version, on_commit),
+        self._fan_out(pg, oid, version, LOG_WRITE, build, on_commit,
                       "ec_sub_rmw", supersedes_recovery=False)
 
     # -- shard read fan-out -------------------------------------------

@@ -369,7 +369,7 @@ class StripeBatcher:
             preconcat = None       # caller's contract broken: re-copy
         self._pending, self._pending_bytes = [], 0
         self._preconcat = None
-        if self.mesh is not None and _device_fusable(self.codec):
+        if self.mesh is not None and device_fusable(self.codec):
             try:
                 # ASYNC since ISSUE 12: the mesh step launches here
                 # (jax async dispatch) and the returned finalize
@@ -391,7 +391,7 @@ class StripeBatcher:
                 # the plain path below re-encodes the whole batch
                 # with one call of the codec (counted)
                 self._note_fallback("layered", exc)
-        if with_crcs and _device_fusable(self.codec):
+        if with_crcs and device_fusable(self.codec):
             try:
                 return _flush_device_fused_async(
                     self.sinfo, self.codec, ops, bufs,
@@ -451,7 +451,11 @@ def flush_kind(codec) -> str | None:
     return getattr(codec, "device_flush", None)
 
 
-def _device_fusable(codec) -> bool:
+def device_fusable(codec) -> bool:
+    """A plain matrix codec on a device backend: its flushes are one
+    fused program (:func:`_flush_device_fused_async`), and a range
+    overwrite's re-encode rides the engine's overwrite flush. Layered
+    and chunk-mapped codecs (clay, lrc) encode an overwrite inline."""
     return (flush_kind(codec) == "matrix"
             and getattr(codec, "backend", "") in _DEVICE_MATVEC)
 
@@ -547,7 +551,7 @@ def device_decodable(codec) -> bool:
     codec (clay) with one layered program whose signature table is an
     operand (:func:`decode_layered`); mapped codecs (lrc) keep their
     host machinery."""
-    return _device_fusable(codec) or device_layered(codec)
+    return device_fusable(codec) or device_layered(codec)
 
 
 def fuse_crc_policy(codec) -> bool:
@@ -711,17 +715,20 @@ def flush_decode_mesh(mesh, sinfo: StripeInfo, codec,
     return out
 
 
-def fused_program(codec, n_b: int, lmax_b: int, nops_b: int):
+def fused_program(codec, n_b: int, lmax_b: int, nops_b: int,
+                  with_crcs: bool = True):
     """The jitted encode+crc program of one bucketed batch signature:
     ``fn(data [k, n_b], offs [nops_b], seg_lens [nops_b]) -> (parity
-    [m, n_b], crc linear parts [nops_b * (k+m)])``. Returns ``(fn,
+    [m, n_b], crc linear parts [nops_b * (k+m)] | None)``. Without
+    crcs (an overwrite flush: a range overwrite drops the shards'
+    ``hinfo``) the program is the GF encode alone. Returns ``(fn,
     is_new)``; cached per (backend, matrix, buckets), so the flush
     path and anything that compiles the program ahead of time
     (tests/test_chip_compile.py) build the SAME function."""
     import jax
     import jax.numpy as jnp
     key = (codec.backend, codec.coding_matrix.tobytes(),
-           n_b, lmax_b, nops_b)
+           n_b, lmax_b, nops_b, with_crcs)
     fn = _fused_cache.get(key)
     if fn is not None:
         return fn, False
@@ -738,6 +745,8 @@ def fused_program(codec, n_b: int, lmax_b: int, nops_b: int):
         # program appear under ``encode`` and ``crc_windows``
         with jax.named_scope("encode"):
             parity = dev.matvec_device(mat, data)
+            if not with_crcs:
+                return parity, None
             shards = jnp.concatenate(
                 [data, parity.astype(jnp.uint8)], axis=0)
         return parity, _crc_windows(shards, offs, seg_lens, lmax_b)
@@ -954,8 +963,16 @@ def _per_op(ops, lens, data_shards, parity, lin) -> list:
     return results
 
 
+#: an overwrite flush's per-shard bucket: the engine flushes its
+#: overwrite group before an op would take it past this many bytes a
+#: shard (16 stripes of 4 KiB chunks), so every batch of overwrites up
+#: to it runs ONE compiled program whatever the queue depth; only an
+#: op larger than a bucket alone meets a larger one
+OVERWRITE_BUCKET = 1 << 16
+
+
 def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
-                              batch=None):
+                              batch=None, with_crcs: bool = True):
     """One device program per bucketed batch signature: upload the
     stripe batch once, encode parity, and take every op's per-shard
     crc linear part from the SAME device-resident shards (one download
@@ -963,6 +980,11 @@ def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
     boundaries are DYNAMIC inputs (offsets/lengths arrays), with
     front-zero padding — free under crc linearity — masking the
     neighbour bytes a fixed-width window drags in.
+
+    Without crcs (the engine's overwrite flush, never host-routed,
+    whatever its size) the program encodes alone, padded to at least
+    :data:`OVERWRITE_BUCKET`; ``finalize.overwrite`` marks its ops for
+    the engine's ``overwrite_ops``.
 
     Returns ``finalize() -> results``: the jit call here only QUEUES
     the program (jax async dispatch); finalize downloads — callers
@@ -978,20 +1000,27 @@ def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
     data_shards = np.ascontiguousarray(
         batch.reshape(s, k, cs).transpose(1, 0, 2).reshape(k, n_bytes))
 
-    n_b, lmax_b, nops_b = fused_buckets(n_bytes, max(lens), len(ops))
+    if with_crcs:
+        n_b, lmax_b, nops_b = fused_buckets(n_bytes, max(lens),
+                                            len(ops))
+    else:
+        # the segment operands are not read: one program a bucket
+        n_b, lmax_b, nops_b = (_pow2_bucket(n_bytes, OVERWRITE_BUCKET),
+                               1, 1)
     if nops_b * n_chunks * lmax_b > _FUSE_CRC_MAX_SEG_BYTES:
         raise ValueError("fused crc working set too large; "
                          "plain flush")
-    fn, fn_is_new = fused_program(codec, n_b, lmax_b, nops_b)
+    fn, fn_is_new = fused_program(codec, n_b, lmax_b, nops_b,
+                                  with_crcs)
     if n_b != n_bytes:
         data_dev = np.zeros((k, n_b), dtype=np.uint8)
         data_dev[:, :n_bytes] = data_shards
     else:
         data_dev = data_shards
-    offs_arr, lens_arr = _segments(lens, nops_b)
+    offs_arr, lens_arr = _segments(lens if with_crcs else [0], nops_b)
     from ceph_tpu.utils.device_telemetry import telemetry
-    signature = (f"fused_crc[{codec.backend}"
-                 f"{list(codec.coding_matrix.shape)}]"
+    signature = (f"{'fused_crc' if with_crcs else 'overwrite'}"
+                 f"[{codec.backend}{list(codec.coding_matrix.shape)}]"
                  f"N{n_b}L{lmax_b}ops{nops_b}")
     if fn_is_new:
         import os as _os
@@ -1011,15 +1040,17 @@ def _flush_device_fused_async(sinfo: StripeInfo, codec, ops, bufs,
     def finalize():
         parity, lin = _phase(
             "flush_download", len(ops), batch.nbytes,
-            lambda: (np.asarray(parity_dev), np.asarray(lin_dev)))
-        return _per_op(ops, lens, data_shards, parity,
-                       lin.reshape(nops_b, n_chunks))
+            lambda: (np.asarray(parity_dev),
+                     None if lin_dev is None else
+                     np.asarray(lin_dev).reshape(nops_b, n_chunks)))
+        return _per_op(ops, lens, data_shards, parity, lin)
 
     # expose the compiled program + staged host inputs for harnesses
     # (bench/engine_loop.py measures THIS exact program — reaching
     # into the cache with a hand-copied key would silently drift)
     finalize.fused_fn = fn
     finalize.staged = (data_dev, offs_arr, lens_arr)
+    finalize.overwrite = not with_crcs
     return finalize
 
 

@@ -7,6 +7,14 @@ directory object, per-image header (size + layout), striped data via
 ceph_tpu.client.striper — and the core API: create/open/list/remove,
 byte-addressed read/write, resize, and snapshots.
 
+Data pool (``rbd create --data-pool``; upstream
+doc/rados/operations/erasure-code.rst, "Erasure Coding with
+Overwrites"): an image created with ``data_pool`` keeps its header and
+directory in the pool it was created in (an EC pool has no omap) and
+its data objects ``rbd_data.<image>.<objno:016x>`` in the data pool,
+written as librbd writes them: one RADOS op per extent of an I/O, the
+image's size from its header (:class:`DataObjects`).
+
 Snapshots are copy-on-write at data-object granularity (the
 reference's object-clone model, reduced): ``snap_create`` is O(1) —
 it records a layer; the FIRST head write touching a data object after
@@ -76,6 +84,78 @@ def _dir_call(io, method: str, **args) -> None:
         raise
 
 
+class DataObjects:
+    """The data objects of an image with a data pool:
+    ``<prefix>.<objno:016x>`` in that pool, written and read one RADOS
+    op per extent (librbd's ObjectRequest role); the image's size is
+    its header's. Nothing per I/O beyond the data: no stream meta
+    object, no generation xattr, no state an I/O changes, so threads
+    writing disjoint extents share one handle. Takes no object cache
+    (``rbd_cache`` attaches to striped images only)."""
+
+    def __init__(self, io, prefix: str, layout: FileLayout,
+                 size: int) -> None:
+        layout.validate()
+        self.io = io
+        self.prefix = prefix
+        self.layout = layout
+        self.size = size
+
+    def _piece(self, objno: int) -> str:
+        return f"{self.prefix}.{objno:016x}"
+
+    def refresh(self) -> None:
+        """Nothing to reload: the size is the header's."""
+
+    def write(self, data: bytes, offset: int = 0) -> None:
+        pos = 0
+        for objno, obj_off, n in file_to_extents(self.layout, offset,
+                                                 len(data)):
+            self.io.write(self._piece(objno), data[pos:pos + n],
+                          offset=obj_off)
+            pos += n
+
+    def read(self, length: int, offset: int = 0) -> bytes:
+        out = bytearray(length)
+        pos = 0
+        for objno, obj_off, n in file_to_extents(self.layout, offset,
+                                                 length):
+            try:
+                piece = self.io.read(self._piece(objno), n, obj_off)
+            except Exception as exc:
+                if getattr(exc, "code", None) != -2:
+                    raise
+                piece = b""          # never written: reads as zeros
+            out[pos:pos + len(piece)] = piece
+            pos += n
+        return bytes(out)
+
+    def resize(self, new_size: int) -> None:
+        """A shrink discards the data past ``new_size`` (an object that
+        starts past it is removed, the rest zeroed), so a later grow
+        reads zeros there."""
+        for objno, obj_off, n in file_to_extents(
+                self.layout, new_size, max(self.size - new_size, 0)):
+            try:
+                if obj_off == 0:
+                    self.io.remove(self._piece(objno))
+                else:
+                    self.io.zero(self._piece(objno), obj_off, n)
+            except Exception as exc:
+                if getattr(exc, "code", None) != -2:
+                    raise
+        self.size = new_size
+
+    def remove(self) -> None:
+        objnos = {e[0] for e in file_to_extents(self.layout, 0,
+                                                self.size)}
+        for objno in sorted(objnos):
+            try:
+                self.io.remove(self._piece(objno))
+            except Exception:
+                pass
+
+
 class RBD:
     """Pool-level image management (librbd::RBD role)."""
 
@@ -86,7 +166,11 @@ class RBD:
                layout: FileLayout | None = None,
                journaling: bool = False,
                primary: bool = True,
-               exclusive: bool = False) -> "Image":
+               exclusive: bool = False,
+               data_pool: str | None = None) -> "Image":
+        """``data_pool``: the pool the data objects live in (an EC pool
+        that takes overwrites); header, directory and journal stay in
+        this one."""
         # reserve the directory entry FIRST (atomic in-OSD -EEXIST):
         # a racing create of the same name loses cleanly. A failure
         # AFTER the reservation rolls it back, so a half-created
@@ -102,6 +186,8 @@ class RBD:
                       "os": layout.object_size,
                       "snaps": {}, "journaling": journaling,
                       "primary": primary, "exclusive": exclusive}
+            if data_pool is not None:
+                header["data_pool"] = data_pool
             if journaling:
                 Journaler(self.io, f"rbd.{name}").create()
             self.io.write_full(f"rbd_header.{name}",
@@ -127,7 +213,7 @@ class RBD:
                 for key, marker in meta.get("objects", {}).items():
                     if marker == "data":
                         try:
-                            self.io.remove(
+                            img.data_io.remove(
                                 img._snap_piece(snap, int(key, 16)))
                         except Exception:
                             pass
@@ -183,6 +269,11 @@ class Image:
             raise RBDError(f"no such image {name!r}")
         layout = FileLayout(self._header["su"], self._header["sc"],
                             self._header["os"])
+        #: where the data objects (and snapshot layers) live
+        self.data_io = self.io
+        if self._header.get("data_pool"):
+            self.data_io = self.io.client.open_ioctx(
+                self._header["data_pool"])
         if cache is None:
             cache = bool(g_conf()["rbd_cache"])
         self.cache = None
@@ -191,8 +282,7 @@ class Image:
         if cache:
             from ceph_tpu.client.object_cacher import ObjectCacher
             self.cache = ObjectCacher(g_conf()["rbd_cache_size"])
-        self._data = StripedObject(self.io, f"rbd_data.{name}", layout,
-                                   cache=self.cache)
+        self._data = self._open_data(layout)
         self.journal = Journaler(self.io, f"rbd.{name}") \
             if self._header.get("journaling") else None
         if cache:
@@ -214,6 +304,13 @@ class Image:
         if replay and self.journal is not None and \
                 self._header.get("primary", True):
             self._replay_local_tail()
+
+    def _open_data(self, layout: FileLayout):
+        if self._header.get("data_pool"):
+            return DataObjects(self.data_io, f"rbd_data.{self.name}",
+                               layout, self._header["size"])
+        return StripedObject(self.io, f"rbd_data.{self.name}", layout,
+                             cache=self.cache)
 
     # -- header --------------------------------------------------------
     def _on_header_notify(self, payload: bytes) -> None:
@@ -465,7 +562,13 @@ class Image:
         old = self._header["size"]
         self._header["size"] = new_size
         self._save_header()
-        if new_size < old:
+        if isinstance(self._data, DataObjects):
+            # the shrink removes or zeroes head objects: a snapshot
+            # that shares them keeps their content first
+            self._cow_protect(self._touched_objnos(
+                new_size, max(self._data.size - new_size, 0)))
+            self._data.resize(new_size)
+        elif new_size < old:
             # shrink: zero the dropped tail so a later grow reads zeros
             # (object-level trim left as future work)
             self._data.size = min(self._data.size, new_size)
@@ -558,7 +661,8 @@ class Image:
             content = None
             if limit > 0:
                 try:
-                    content = self.io.read(self._data._piece(objno))
+                    content = self.data_io.read(
+                        self._data._piece(objno))
                 except Exception as exc:
                     # ONLY absence is shareable-as-hole; a real I/O
                     # error (EIO etc.) must fail the write, or an
@@ -571,8 +675,8 @@ class Image:
             else:
                 # clamp to the snapshot-time valid prefix: bytes past
                 # a shrink are logically zeros, not stale data
-                self.io.write_full(self._snap_piece(snap, objno),
-                                   content[:limit])
+                self.data_io.write_full(self._snap_piece(snap, objno),
+                                        content[:limit])
                 meta["objects"][key] = "data"
             dirty = True
         if dirty:
@@ -598,14 +702,14 @@ class Image:
             if marker == "absent":
                 return b""
             if marker == "data":
-                return self.io.read(self._snap_piece(s, objno))
+                return self.data_io.read(self._snap_piece(s, objno))
         meta = self._header["snaps"][snap]
         limit = self._piece_limit(objno,
                                   meta.get("data_size", meta["size"]))
         if limit <= 0:
             return b""
         try:
-            return self.io.read(self._data._piece(objno))[:limit]
+            return self.data_io.read(self._data._piece(objno))[:limit]
         except Exception as exc:
             if getattr(exc, "code", None) != -2:
                 raise
@@ -658,8 +762,8 @@ class Image:
             buf[obj_off:obj_off + n] = content[pos:pos + n]
             pos += n
         for objno, buf in pieces.items():
-            self.io.write_full(self._snap_piece(snap, objno),
-                               bytes(buf))
+            self.data_io.write_full(self._snap_piece(snap, objno),
+                                    bytes(buf))
             meta["objects"][f"{objno:x}"] = "data"
         self._header["snaps"][snap] = meta
         self._snap_order().insert(insert_at, snap)
@@ -698,11 +802,10 @@ class Image:
         self._cow_protect(self._objnos(
             max(self._header["size"], len(content))))
         self._data.remove()
-        self._data = StripedObject(self.io, f"rbd_data.{self.name}",
-                                   self._data.layout)
+        self._header["size"] = self._header["snaps"][snap]["size"]
+        self._data = self._open_data(self._data.layout)
         if content:
             self._data.write(content)
-        self._header["size"] = self._header["snaps"][snap]["size"]
         self._save_header()
 
     def snap_remove(self, snap: str) -> None:
@@ -732,14 +835,14 @@ class Image:
                     # the older snapshot shared this object THROUGH
                     # this layer: the content moves down a level
                     if marker == "data":
-                        self.io.write_full(
+                        self.data_io.write_full(
                             self._snap_piece(older, objno),
-                            self.io.read(self._snap_piece(snap,
-                                                          objno)))
+                            self.data_io.read(self._snap_piece(snap,
+                                                               objno)))
                     ometa["objects"][key] = marker
             if marker == "data":
                 try:
-                    self.io.remove(self._snap_piece(snap, objno))
+                    self.data_io.remove(self._snap_piece(snap, objno))
                 except Exception:
                     pass
         order.remove(snap)

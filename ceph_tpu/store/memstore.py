@@ -6,6 +6,7 @@ the same as the durable store so EIO-path tests can run against either.
 
 from __future__ import annotations
 
+import threading
 from typing import Callable
 
 from ceph_tpu.store import object_store as osr
@@ -31,6 +32,12 @@ class MemStore(ObjectStore):
     def __init__(self) -> None:
         self._colls: dict[str, dict[str, _Obj]] = {}
         self._eio: set[tuple[str, str]] = set()
+        #: one transaction validates and applies at a time: op-wq
+        #: workers and the engine's ship thread commit to one store, and
+        #: ``_validate`` walks every collection (a walk racing another
+        #: txn's object creation raised "dictionary changed size during
+        #: iteration" and lost a flush's local shards, PERF.md section 7)
+        self._apply_lock = threading.Lock()
 
     # -- helpers ------------------------------------------------------
     def _coll(self, cid: str) -> dict[str, _Obj]:
@@ -85,7 +92,7 @@ class MemStore(ObjectStore):
             "memstore", id(self))
         tmr.n_ops = len(txn)
         with tmr:
-            with tmr.stage("apply"):
+            with tmr.stage("apply"), self._apply_lock:
                 self._apply(txn)
             tmr.run_on_commit(on_commit)
 
@@ -106,7 +113,7 @@ class MemStore(ObjectStore):
         tmr.n_ops = sum(len(txn) for txn, _ in pairs)
         tmr.n_txns = len(pairs)
         with tmr:
-            with tmr.stage("apply"):
+            with tmr.stage("apply"), self._apply_lock:
                 merged = Transaction()
                 for txn, _ in pairs:
                     merged.ops.extend(txn.ops)

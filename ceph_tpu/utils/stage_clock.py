@@ -56,8 +56,10 @@ EC_WRITE_STAGES = (
 #: what a read marks besides (``ec_backend.read_object_async``):
 #: client_submit .. pg_process, then shard_read_wait, then for a
 #: reconstruct engine_stage_wait and device_finalize, then
-#: commit_wait (reassembly + reply build) and commit_reply
-READ_STAGES = ("shard_read_wait",)
+#: commit_wait (reassembly + reply build) and commit_reply; a range
+#: overwrite marks rmw_read between pg_process and engine_stage_wait
+#: when it reads the stripes it touches (``ec_backend._rmw_window``)
+READ_STAGES = ("shard_read_wait", "rmw_read")
 
 #: a shard sub-write's child timeline (primary -> shard OSD -> commit)
 SUBOP_STAGES = ("subop_send", "subop_wire", "subop_dispatch_wait",
@@ -83,6 +85,9 @@ GLOSSARY = {
                   "(reads: -> shard sub-read fan-out)",
     "shard_read_wait": "reads: shard sub-read fan-out + gather, "
                        "intact or degraded",
+    "rmw_read": "range overwrite: the ranged read of the k data "
+                "chunks of the stripes it touches (behind an engine "
+                "barrier, the wait for the barrier too)",
     "engine_stage_wait": "staged -> batch flush launch (batching)",
     "device_window_wait": "launch -> harvest begin (pipeline window)",
     "device_finalize": "blocking device compute + parity download",

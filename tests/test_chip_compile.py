@@ -193,6 +193,21 @@ def test_fused_buckets_of_the_smoke_traffic():
                    (8 << 20, 512 << 10, 16)}
 
 
+@pytest.mark.parametrize("k,m", [(8, 3), (4, 2)])
+def test_overwrite_flush_program(one_chip, k, m):
+    """The overwrite route's program (the fused program without crcs:
+    the GF encode alone) at the one bucket every overwrite flush of
+    up to 16 stripes of 4 KiB chunks takes."""
+    from ceph_tpu.osd import ec_util
+    codec = _codec(k=k, m=m, backend="pallas")
+    n_b = ec_util._pow2_bucket(16 * 4096, ec_util.OVERWRITE_BUCKET)
+    assert n_b == ec_util._pow2_bucket(4096, ec_util.OVERWRITE_BUCKET)
+    fn, _new = ec_util.fused_program(codec, n_b, 1, 1, with_crcs=False)
+    idx = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    _, text = _compile(fn, _u8((k, n_b), one_chip), idx, idx)
+    assert text.count("tpu_custom_call") == 1
+
+
 # -- block-sparse and Clay: interpret= steered to Mosaic ----------------
 
 def _clay():

@@ -294,3 +294,49 @@ def test_kstore_slash_oids_do_not_cross(tmp_path):
     assert s.getattrs(CID, "b/k/s") == {"tag": b"b/k/s"}
     assert s.omap_get(CID, "b/k/s") == {"m": b"b/k/s"}
     s.umount()
+
+
+def test_memstore_commits_from_many_threads_create_objects_safely():
+    """Op-wq workers and the engine's ship thread commit to one store:
+    a txn's validation walks every collection while another creates
+    objects. Many threads, a short switch interval, a time bound; every
+    txn applies and none raises."""
+    import sys
+    import threading
+
+    store = MemStore()
+    store.mount()
+    setup = Transaction()
+    for c in range(4):
+        setup.create_collection(f"c{c}")
+    store.queue_transaction(setup)
+    errors, done = [], []
+
+    def writer(t):
+        try:
+            for i in range(300):
+                txn = Transaction()
+                txn.write(f"c{i % 4}", f"t{t}_{i}", 0, b"x")
+                if i % 2:
+                    store.queue_transaction(txn)
+                else:
+                    store.queue_transaction_group([(txn, None)])
+            done.append(t)
+        except Exception as exc:            # pragma: no cover
+            errors.append(exc)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=writer, args=(t,))
+                   for t in range(16)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and not any(th.is_alive() for th in threads)
+    assert len(done) == 16
+    assert sum(len(store.list_objects(f"c{c}")) for c in range(4)) \
+        == 16 * 300
