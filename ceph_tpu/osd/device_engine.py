@@ -92,9 +92,11 @@ coupled changes that move work across every boundary in batches:
   cross-PG work — the per-peer MECSubWriteBatch fan-out and the
   merged local txn group ECBackend registers via
   :func:`current_group`. Groups ship in strict flush order, one
-  after the other on that one thread (never on an op-wq worker).
-  A barrier is dispatched behind the last group's ship, and its
-  key's continuations retired while it waits are dispatched behind
+  after the other on that one thread (never on an op-wq worker);
+  overwrite groups already ready behind one another ship as one
+  (:func:`ship_groups`: one batch a peer for all of them). A barrier
+  is dispatched behind the last group's ship, and its key's
+  continuations retired while it waits are dispatched behind
   the barrier, so per-PG commit order is exactly the pre-batching
   order however far behind the ship thread is.
 - **Shared engine service**: co-located OSDs attach to one
@@ -342,9 +344,13 @@ class FlushGroup:
     waits — that was the 4 MiB write's tail (PERF.md section 6,
     PR 27)."""
 
-    def __init__(self, nkeys: int) -> None:
+    def __init__(self, nkeys: int, overwrite: bool = False) -> None:
         self._lock = make_lock("engine.flush_group")
         self._pending = max(1, nkeys)
+        #: the flush encoded range overwrites' stripe windows: its
+        #: deferred items are small, so the ship thread may ship it
+        #: with the ready overwrite groups queued behind it
+        self.overwrite = overwrite
         #: bucket -> (ship_fn, [items]); insertion-ordered
         self._deferred: dict = {}
         self._after: list = []
@@ -384,18 +390,13 @@ class FlushGroup:
                 return
         self.ready.set()
 
-    def ship(self) -> None:
-        """Ship everything deferred, then run the after-flush
-        callbacks (the engine's ship thread, once the group is
-        ready and its predecessor shipped)."""
+    def _take_deferred(self) -> dict:
         with self._lock:
-            deferred = list(self._deferred.values())
-            self._deferred = {}
-        for ship_fn, items in deferred:
-            try:
-                ship_fn(items)
-            except Exception as exc:
-                log(0, f"flush-group ship failed: {exc!r}")
+            deferred, self._deferred = self._deferred, {}
+        return deferred
+
+    def _shipped(self) -> None:
+        """Mark the group shipped and run its after-flush callbacks."""
         self.event.set()
         while True:
             # a callback registered while these run queues behind
@@ -411,6 +412,29 @@ class FlushGroup:
                 except Exception as exc:
                     log(0, "flush-group after-flush cb failed: "
                         f"{exc!r}")
+
+
+def ship_groups(groups: list) -> None:
+    """Ship consecutive ready flush groups as one (group commit at the
+    ship thread): the deferred items of equal buckets concatenate in
+    flush order and each bucket's ``ship_fn`` is called once, so a peer
+    gets one ``MECSubWriteBatch`` and a primary's local shards one txn
+    group for all of them. Then, group by group in flush order, each is
+    marked shipped and runs its after-flush callbacks (the engine's
+    ship thread, once every one of them is ready and every earlier
+    group has shipped)."""
+    merged: dict = {}
+    for group in groups:
+        for bucket, (ship_fn, items) in group._take_deferred().items():
+            ent = merged.setdefault(bucket, (ship_fn, []))
+            ent[1].extend(items)
+    for ship_fn, items in merged.values():
+        try:
+            ship_fn(items)
+        except Exception as exc:
+            log(0, f"flush-group ship failed: {exc!r}")
+    for group in groups:
+        group._shipped()
 
 
 _group_tls = threading.local()
@@ -566,7 +590,11 @@ class DeviceEncodeEngine:
                       # encoded by the overwrite route (never
                       # host-routed, no crc pass); also counted in
                       # ops / flushes
-                      "overwrite_ops": 0, "overwrite_flushes": 0}
+                      "overwrite_ops": 0, "overwrite_flushes": 0,
+                      # the ship thread's ships and the flush groups
+                      # they carried (overwrite groups queued ready
+                      # behind one another ship as one)
+                      "ships": 0, "ship_groups": 0}
         _telemetry().note_engine_window(self._window)
         #: launch pipeline: deque of (items, finalize, kspans,
         #: nbytes) batches whose device programs are queued
@@ -655,11 +683,12 @@ class DeviceEncodeEngine:
         _telemetry().note_attached_osds(len(self._dispatchers))
 
     # -- batched continuation dispatch (ISSUE 9) ----------------------
-    def _dispatch_entries(self, entries) -> None:
+    def _dispatch_entries(self, entries, overwrite: bool = False) -> None:
         """Dispatch a retired flush's continuations: one wrapper per
         distinct key (batched mode) sharing a FlushGroup, or the
         legacy one-callable-per-op dispatch. ``entries`` is ordered
-        [(key, fn)]."""
+        [(key, fn)]; ``overwrite``: the flush was an overwrite
+        flush."""
         if not self._bulk:
             for key, fn in entries:
                 self._dispatch(key, fn)
@@ -667,7 +696,7 @@ class DeviceEncodeEngine:
         by_key: dict = {}
         for key, fn in entries:
             by_key.setdefault(key, []).append(fn)
-        group = FlushGroup(len(by_key))
+        group = FlushGroup(len(by_key), overwrite=overwrite)
         self._last_group = group
         # queued BEFORE its wrappers run: creation order (the retire
         # thread alone makes groups) is ship order
@@ -703,22 +732,42 @@ class DeviceEncodeEngine:
     def _ship_run(self) -> None:
         """Ship retired flushes' groups strictly in flush order, on
         this thread alone: a group ships when its last wrapper has
-        finished and every earlier group has shipped. On a profiler
-        trace a ship is ``flush_ship``; waiting for a group, or for
-        its wrappers, is ``ship_idle``."""
+        finished and every earlier group has shipped. An overwrite
+        group takes along every overwrite group queued behind it that
+        is already ready (:func:`ship_groups`), up to the first that
+        is not ready or not an overwrite group, which heads the next
+        ship; nothing is waited for to fill a ship, and full-write
+        groups, whose items are chunks of whole objects, ship alone.
+        On a profiler trace a ship is ``flush_ship`` (stat ``groups``);
+        waiting for a group, or for its wrappers, is ``ship_idle``."""
         _prof.thread_role("engine_ship")
+        carried: list = []
         while True:
             mark = _prof.push_stage("idle", span="ship_idle")
             try:
-                group = self._ship_q.get()
+                group = carried.pop() if carried else self._ship_q.get()
                 if group is None:
                     return
                 group.ready.wait()
             finally:
                 _prof.pop_stage(mark)
-            mark = _prof.push_stage("commit_wait", span="flush_ship")
+            groups = [group]
+            while group.overwrite:
+                try:
+                    group = self._ship_q.get_nowait()
+                except queue.Empty:
+                    break
+                if group is None or not group.overwrite or \
+                        not group.ready.is_set():
+                    carried.append(group)
+                    break
+                groups.append(group)
+            self.stats["ships"] += 1
+            self.stats["ship_groups"] += len(groups)
+            mark = _prof.push_stage("commit_wait", span="flush_ship",
+                                    groups=len(groups))
             try:
-                group.ship()
+                ship_groups(groups)
             finally:
                 _prof.pop_stage(mark)
 
@@ -1321,7 +1370,8 @@ class DeviceEncodeEngine:
             self.stats["ops"] += len(items)
             if getattr(finalize, "layered", False):
                 self.stats["layered_encode_ops"] += len(items)
-            if getattr(finalize, "overwrite", False):
+            overwrite = getattr(finalize, "overwrite", False)
+            if overwrite:
                 self.stats["overwrite_ops"] += len(items)
                 self.stats["overwrite_flushes"] += 1
             if _spans_keys(items):
@@ -1360,8 +1410,9 @@ class DeviceEncodeEngine:
             # ONE wrapper per distinct key instead of one callable
             # per op: the flush's continuations share a FlushGroup
             # whose last member ships the per-peer sub-write batches
-            # and the merged local txn groups (ISSUE 9)
-            self._dispatch_entries(entries)
+            # and the merged local txn groups; an overwrite flush's
+            # group may ship with the next ones
+            self._dispatch_entries(entries, overwrite=overwrite)
             _telemetry().note_encode_flush(
                 len(items), nbytes,
                 trace_id=_first_trace_id(items, span_idx=3))

@@ -427,3 +427,115 @@ def test_a_flush_holds_one_pgs_ops_in_one_kind_and_one_bucket():
     # a full-write group is bounded by the engine's byte cap alone
     assert not first({full: group(["pgB"] * per_bucket)}, full, "pgA",
                      stripe)
+
+
+class _ShipHeld:
+    """The engine's ship thread held inside a ship of its own (a ready
+    group of no op whose one item waits for a gate): the flush groups
+    retired meanwhile queue behind it, ready, as under load."""
+
+    def __init__(self, cluster) -> None:
+        handle = _engine(cluster)
+        self.engine = getattr(handle, "engine", handle)
+        self.gate, self.entered = threading.Event(), threading.Event()
+
+    def _hold(self, _items) -> None:
+        self.entered.set()
+        self.gate.wait(60)
+
+    def __enter__(self):
+        from ceph_tpu.osd.device_engine import FlushGroup
+        plug = FlushGroup(1)
+        plug.defer("hold", self._hold, None)
+        plug.done()
+        self.engine._ship_q.put(plug)
+        assert self.entered.wait(30)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.gate.set()
+
+
+def _until_grown(cluster, before: dict, key: str, n: int,
+                 limit: float = 60.0) -> None:
+    t0 = time.monotonic()
+    while _grown(before, _stats(cluster))[key] < n:
+        assert time.monotonic() - t0 < limit, f"{key} never grew by {n}"
+        time.sleep(0.02)
+
+
+def test_overwrites_queued_at_the_ship_thread_ship_as_one(cluster,
+                                                          monkeypatch):
+    """16 writers' 4 KiB overwrites of objects in several PGs retire
+    while the ship thread is held: the overwrite groups queued ready
+    ship in fewer ships than there are groups, a write_full of one of
+    the objects, staged between two waves of them, ships alone, and
+    every object equals the reference with the overwrites laid over it
+    in the order its PG committed them."""
+    from ceph_tpu.osd import device_engine
+    kinds: list = []
+    ship = device_engine.ship_groups
+
+    def spy(groups):
+        kinds.append([g.overwrite for g in groups])
+        ship(groups)
+    monkeypatch.setattr(device_engine, "ship_groups", spy)
+    pool = "rs83"
+    io = cluster.rados.open_ioctx(pool)
+    osdmap = cluster.mon.osdmap
+    pool_id = osdmap.pool_by_name[pool]
+    names, pgs, i = [], set(), 0
+    while len(names) < 4:
+        name = f"merge_{i}"
+        i += 1
+        ps = osdmap.object_to_pg(pool_id, name)
+        if ps not in pgs:
+            pgs.add(ps)
+            names.append(name)
+    expected = _write_full(io, names, pool, seed=370)
+    k = POOLS[pool]["k"]
+    full = _seeded(371, _object_bytes(pool))
+
+    def wave(stripes, seed):
+        """Two writers an object, each a 4 KiB block of its own stripe
+        (waves never share a stripe)."""
+        return [(name, (s * k + j % k) * UNIT, _seeded(seed + j, UNIT))
+                for j, (name, s) in enumerate(
+                    (n, s) for n in names for s in stripes)]
+
+    first, second = wave((0, 2), 380), wave((1, 3), 390)
+
+    def writes(ops):
+        return [(lambda n=n, o=o, d=d: io.write(n, d, o))
+                for n, o, d in ops]
+    before = _stats(cluster)
+    threads, errors, staged = [], [], 0
+    with _ShipHeld(cluster):
+        for fns in (writes(first),
+                    [lambda: io.write_full(names[0], full)],
+                    writes(second)):
+            more, errs = _concurrently(fns)
+            threads += more
+            errors += errs
+            staged += len(fns)
+            _until_grown(cluster, before, "ops", staged)
+        time.sleep(0.5)                 # the last wrappers' done()
+    for th in threads:
+        th.join(60)
+    assert not errors and not any(th.is_alive() for th in threads)
+    grown = _grown(before, _stats(cluster))
+    assert grown["overwrite_ops"] == len(first) + len(second)
+    assert grown["ship_groups"] > grown["ships"], grown
+    assert any(len(ks) > 1 for ks in kinds), kinds
+    assert all(all(ks) for ks in kinds if len(ks) > 1), kinds
+    assert kinds.count([False]) >= 2, kinds     # the plug, the write_full
+
+    def lay(ops):
+        for name, off, data in ops:
+            buf = bytearray(expected[name])
+            buf[off:off + UNIT] = data
+            expected[name] = bytes(buf)
+    lay(first)
+    expected[names[0]] = full
+    lay(second)
+    _assert_exact(cluster, pool, expected, set(names))

@@ -21,7 +21,8 @@ import pytest
 
 from ceph_tpu.models import registry as ec_registry
 from ceph_tpu.osd import device_engine
-from ceph_tpu.osd.device_engine import DeviceEncodeEngine, FlushGroup
+from ceph_tpu.osd.device_engine import (DeviceEncodeEngine, FlushGroup,
+                                        ship_groups)
 from ceph_tpu.osd.ec_util import StripeInfo
 
 OP_BYTES = 2048
@@ -210,7 +211,7 @@ def test_after_flush_callbacks_run_in_registration_order():
     group.after_flush(first)
     group.after_flush(lambda: out.append("second"))
     group.done()
-    group.ship()
+    ship_groups([group])
     assert out == ["first", "second", "registered by first"]
 
 
@@ -237,7 +238,7 @@ def test_a_group_is_ready_after_its_last_wrapper_and_ships_once(nkeys):
     group.done()
     assert group.ready.is_set() and not group.event.is_set()
     assert out == []                    # done() never ships
-    group.ship()
+    ship_groups([group])
     assert out == [1, 2, 3, "after"] and group.event.is_set()
     group.after_flush(lambda: out.append("late"))
     assert out[-1] == "late"            # already shipped: runs now

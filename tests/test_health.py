@@ -270,10 +270,12 @@ def test_prometheus_escapes_hostile_daemon_names():
         # every non-comment line still parses as one sample; an
         # OpenMetrics exemplar clause (`... # {trace_id="..."} v ts`,
         # ISSUE 10) may trail a histogram bucket sample — strip it
-        # the way an exemplar-aware scraper does before matching
+        # the way an exemplar-aware scraper does before matching. Other
+        # families label by more than the daemon (the flow accounting's
+        # `tenant`, once a test of the same process has served one)
+        label = r'[a-zA-Z_][a-zA-Z0-9_]*="(\\.|[^"\\])*"'
         sample = re.compile(
-            r'^[a-zA-Z_][a-zA-Z0-9_]*(\{daemon="(\\.|[^"\\])*"'
-            r'(,le="[^"]*")?\})? \S+$')
+            rf'^[a-zA-Z_][a-zA-Z0-9_]*(\{{{label}(,{label})*\}})? \S+$')
         for line in text.splitlines():
             if line and not line.startswith("#"):
                 assert sample.match(line.split(" # ")[0]), line
