@@ -31,6 +31,7 @@ from windows.base import WindowBase
 class Window(WindowBase):
     KEYS = {
         "osds_down": int,         # killed during set-up, chosen by seed
+                                  # unless the mix names its victims
         "degraded_share": float,  # with OSDs down: the share of reads
                                   # sent to objects that lack a data
                                   # shard
@@ -38,6 +39,9 @@ class Window(WindowBase):
                                   # loudly
     }
     OPS = ("write_full", "read")
+    #: optional: the OSD ids set-up kills, the same in every run; a
+    #: mix without it kills ``osds_down`` OSDs drawn from the seed
+    VICTIMS = "victims"
 
     @classmethod
     def check(cls, mix: dict) -> str | None:
@@ -47,6 +51,14 @@ class Window(WindowBase):
             return "reads need preload_objects"
         if mix["osds_down"] < 0 or not 0 <= mix["degraded_share"] <= 1:
             return "a size is out of range"
+        victims = mix.get(cls.VICTIMS)
+        if victims is not None and (
+                not isinstance(victims, list)
+                or not all(isinstance(v, int) and not isinstance(v, bool)
+                           and v >= 0 for v in victims)
+                or len(set(victims)) != len(victims)
+                or len(victims) != mix["osds_down"]):
+            return "victims has to name osds_down distinct OSD ids"
         return None
 
     def __init__(self, served, mix: dict, seed: int) -> None:
@@ -63,7 +75,8 @@ class Window(WindowBase):
         served = self.served
         if not self.degraded:
             return
-        served.kill_osds(self.mix["osds_down"])
+        served.kill_osds(self.mix["osds_down"],
+                         victims=self.mix.get(self.VICTIMS))
         served.settle()
         note(phase="osds_down_and_settled", victims=served.victims)
         served.warm_degraded_reads()

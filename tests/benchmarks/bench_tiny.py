@@ -48,8 +48,8 @@ def _dump(obj: dict, path: str) -> None:
         json.dump(obj, f, indent=1)
 
 
-def make_root(tmp: str) -> str:
-    """Build the tiny checkout below ``tmp`` and return its root."""
+def _copy_data(tmp: str) -> str:
+    """``DATA`` and every reference module into ``tmp/benchmarks``."""
     bench = os.path.join(tmp, "benchmarks")
     os.makedirs(bench)
     for item in DATA:
@@ -62,6 +62,12 @@ def make_root(tmp: str) -> str:
             shutil.copy(src, os.path.join(bench, item))
     for src in glob.glob(os.path.join(BENCH_DIR, "*reference.py")):
         shutil.copy(src, bench)
+    return bench
+
+
+def make_root(tmp: str) -> str:
+    """Build the tiny checkout below ``tmp`` and return its root."""
+    bench = _copy_data(tmp)
     before = _snapshot(bench)
 
     conf = _load(os.path.join(BENCH_DIR, "configs",
@@ -105,14 +111,6 @@ def make_root(tmp: str) -> str:
         mix.update(extra)
         _dump(mix, os.path.join(bench, "traffic", name + ".json"))
 
-    # a per-layer metric over a counter nothing committed reads,
-    # through a reader that is already there
-    _dump({"layer": "engine", "unit": "bytes/flush",
-           "moves": "write_MBps", "reader": "stat_ratio",
-           "args": {"num": "bytes", "den": "flushes"}},
-          os.path.join(bench, "layer_metrics",
-                       "tiny_bytes_per_flush.json"))
-
     table = _load(os.path.join(bench, "peaks.json"))
     bm = _load(os.path.join(ROOT, "BENCHMARK.json"))
     bm["configs"] += [
@@ -124,8 +122,8 @@ def make_root(tmp: str) -> str:
     # it: by its entries alone (here with a bound that nothing judges)
     pending = _load(os.path.join(bench, "pending", PENDING + ".json"))
     bm["workloads"].append(pending["workload"])
-    setup = bm["end_to_end"].pop()
-    assert setup["name"] == "setup_s"
+    setup = next(m for m in bm["end_to_end"] if m["name"] == "setup_s")
+    bm["end_to_end"].remove(setup)
     bm["end_to_end"] += [dict(met, bound=0.25)
                          for met in pending["end_to_end"]] + [setup]
     bm["per_layer"] += pending["per_layer"]
@@ -137,19 +135,12 @@ def make_root(tmp: str) -> str:
                                 "k8m3_write_4m"),
             "tiny.shec_by_rs": ("tiny_shec_by_rs", "tiny_write",
                                 "k8m3_write_4m")}
-    bm["workloads"] += [
-        {"name": cell, "config": config, "traffic": mix, "chips": 1,
-         "why": "CPU test"} for cell, (config, mix, _) in like.items()]
-    for metric in bm["end_to_end"] + bm["per_layer"]:
-        cells = metric.get("workloads")
-        if cells:
-            cells += [cell for cell, (_, _, real) in like.items()
-                      if real in cells]
-    bm["per_layer"].append(
-        {"name": "tiny_bytes_per_flush", "unit": "bytes/flush",
-         "better": "higher", "source": "program_counter",
-         "layer": "engine", "moves": "write_MBps",
-         "workloads": ["tiny.write"]})
+    for cell, (config, mix, real) in like.items():
+        _register_like(bm, {"name": cell, "config": config,
+                            "traffic": mix, "chips": 1,
+                            "why": "CPU test"}, real)
+    bm["per_layer"].append(_bytes_per_flush(bench, "tiny_bytes_per_flush",
+                                            ["tiny.write"]))
     _dump(bm, os.path.join(tmp, "BENCHMARK.json"))
 
     # the one exception: the test machine's "device" has to have peaks
@@ -161,6 +152,86 @@ def make_root(tmp: str) -> str:
         table["device_kinds"]["TPU v5 lite"]
     _dump(table, os.path.join(bench, "peaks.json"))
     return tmp
+
+
+def copy_root(tmp: str) -> str:
+    """A copy below ``tmp`` of ``BENCHMARK.json`` and of the data files
+    and reference modules ``make_root`` copies, unchanged; its root."""
+    _copy_data(tmp)
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp)
+    return tmp
+
+
+def _register_like(bm: dict, cell: dict, like: str) -> None:
+    """Register the workload entry ``cell`` as a later PR does: by
+    appended names alone, on every list the cell ``like`` is on."""
+    bm["workloads"].append(cell)
+    for metric in bm["end_to_end"] + bm["per_layer"]:
+        cells = metric.get("workloads")
+        if cells and like in cells:
+            cells.append(cell["name"])
+
+
+def _bytes_per_flush(bench: str, name: str, cells: list) -> dict:
+    """A per-layer metric over a counter nothing committed reads,
+    through a reader that is already there: its data file written
+    below ``bench``, and its ``per_layer`` entry on ``cells``."""
+    _dump({"layer": "engine", "unit": "bytes/flush",
+           "moves": "write_MBps", "reader": "stat_ratio",
+           "args": {"num": "bytes", "den": "flushes"}},
+          os.path.join(bench, "layer_metrics", name + ".json"))
+    return {"name": name, "unit": "bytes/flush", "better": "higher",
+            "source": "program_counter", "layer": "engine",
+            "moves": "write_MBps", "workloads": list(cells)}
+
+
+#: what ``with_an_addition`` brings, every name new: a cell of each
+#: kind a later PR is named to add (PERF.md section 7), each on a
+#: configuration of its own copied from one that is there, on a
+#: traffic mix that is there; cell -> (configuration, copied from,
+#: traffic, the cell it is like)
+ADDED = {
+    "added.write_4m": ("added_k8m3_11osd", "rs_k8m3_12osd", "write_4m",
+                       "k8m3_write_4m"),
+    "added.rbd_randwrite_4k_1down": (
+        "added_rbd_ec_k8m3_11osd", "rbd_ec_k8m3_12osd",
+        "rbd_randwrite_4k", "rbd_k8m3_randwrite_4k"),
+    "added.clay_degraded_read_4m": (
+        "added_clay_k8m4d11_12osd", "clay_k8m4d11_13osd",
+        "degraded_read_4m", "clay_k8m4d11_degraded_read_4m")}
+#: and two per-layer metrics: one on every cell that reports
+#: ``write_MBps`` (a kind's metric), one on one cell and not on the
+#: cell of the same traffic on another configuration (a cell's own)
+ADDED_METRICS = {"added_bytes_per_flush.write": None,
+                 "added_bytes_per_flush.clay": ["clay_k8m4d11_write_4m"]}
+
+
+def with_an_addition(tmp: str) -> str:
+    """``copy_root`` with ``ADDED`` and ``ADDED_METRICS`` added as a
+    later PR adds them: new files, and names appended to the lists of
+    ``BENCHMARK.json`` alone."""
+    root = copy_root(tmp)
+    bench = os.path.join(root, "benchmarks")
+    bm = _load(os.path.join(root, "BENCHMARK.json"))
+    for cell, (config, like_config, mix, like) in ADDED.items():
+        conf = _load(os.path.join(BENCH_DIR, "configs",
+                                  like_config + ".json"))
+        conf.update(name=config, source="tests/benchmarks: an addition")
+        _dump(conf, os.path.join(bench, "configs", config + ".json"))
+        bm["configs"].append(
+            {"name": config, "source": conf["source"],
+             "file": f"benchmarks/configs/{config}.json",
+             "reduced": [], "why": "an addition"})
+        _register_like(bm, {"name": cell, "config": config,
+                            "traffic": mix, "chips": 1,
+                            "why": "an addition"}, like)
+    writes = next(m for m in bm["end_to_end"]
+                  if m["name"] == "write_MBps")["workloads"]
+    for name, cells in ADDED_METRICS.items():
+        bm["per_layer"].append(_bytes_per_flush(bench, name,
+                                                cells or writes))
+    _dump(bm, os.path.join(root, "BENCHMARK.json"))
+    return root
 
 
 def _snapshot(bench: str) -> dict:

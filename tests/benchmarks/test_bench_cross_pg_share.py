@@ -3,6 +3,8 @@
 an engine-stats window, so a typo in the file fails here and not in
 the driver's run."""
 
+import os
+
 import pytest
 
 from bench_tiny import BENCH_DIR  # noqa: F401  (sets sys.path)
@@ -35,11 +37,13 @@ def test_encode_cross_pg_share_reads_the_engine_counters(window, want):
     assert got == (None if want is None else pytest.approx(want))
 
 
-def test_encode_cross_pg_share_is_declared_as_the_file_says():
-    entry = [m for m in spec.benchmark()["per_layer"]
-             if m["name"] == NAME]
+def test_encode_cross_pg_share_is_declared_as_the_file_says(
+        registry_root):
+    bm = spec.benchmark(registry_root)
+    bench = os.path.join(registry_root, "benchmarks")
+    entry = [m for m in bm["per_layer"] if m["name"] == NAME]
     assert len(entry) == 1
-    entry, met = entry[0], spec.layer_metric(NAME)
+    entry, met = entry[0], spec.layer_metric(NAME, bench)
     assert met["reader"] == "stat_ratio"
     assert met["args"] == {"num": "cross_pg_ops", "den": "ops"}
     for key in ("layer", "unit", "moves"):
@@ -49,9 +53,9 @@ def test_encode_cross_pg_share_is_declared_as_the_file_says():
     assert entry["source"] == "program_counter"
     # the two RS write cells report it, whoever else does
     assert {"k8m3_write_4m", "k4m2_write_1m"} <= set(entry["workloads"])
-    cells = {w["name"]: w for w in spec.benchmark()["workloads"]}
+    cells = {w["name"]: w for w in bm["workloads"]}
     for cell in entry["workloads"]:
-        assert spec.traffic(cells[cell]["traffic"])["op"] == \
+        assert spec.traffic(cells[cell]["traffic"], bench)["op"] == \
             "write_full", cell
     # the counter the file names is one the engine starts at 0
     from ceph_tpu.osd.device_engine import DeviceEncodeEngine

@@ -108,7 +108,21 @@ def test_overwrite_cell_is_correct_through_the_commands_main(
 
 
 def test_overwrite_cell_traced_reads_what_there_is_to_read(
-        overwrite_root, cpu_env, capfd):
+        overwrite_root, cpu_env, capfd, monkeypatch):
+    # what the roofline's reader is given, as the harness gives it
+    asked = []
+    real_reader = spec.reader
+
+    def reader(name, bench_dir=spec.BENCH_DIR):
+        read = real_reader(name, bench_dir)
+        if name != "roofline_pct":
+            return read
+
+        def recorded(ctx, **args):
+            asked.append((ctx, args))
+            return read(ctx, **args)
+        return recorded
+    monkeypatch.setattr(spec, "reader", reader)
     last = _run(capfd, overwrite_root, trace=1, seconds=2.5)
     assert last["correct"] is True, last["compared"]
     metrics = last["metrics"]
@@ -116,11 +130,23 @@ def test_overwrite_cell_traced_reads_what_there_is_to_read(
     for name in ("client_wire_ms.write", "osd_queue_ms.write",
                  "commit_wait_ms.write"):
         assert metrics[name]["value"] > 0, name
-    # ... the engine's counters do not, on this tree: no reading,
-    # never 0 (the next deployment's kind holds the program to it)
-    for name in ("encode_ops_per_flush", "encode_cross_pg_share",
-                 "encode_roofline"):
-        assert name not in metrics, name
+    # ... and so do the engine's counters: an overwrite is encoded on
+    # the engine's overwrite route
+    assert metrics["encode_ops_per_flush"]["value"] >= 1
+    # the roofline reads the engine's traced overwrites at one
+    # stripe's bytes each; a CPU's trace has no device plane, so the
+    # reading is left out here for that alone, and with a device's
+    # busy time the same window reads a share of the roofline
+    (ctx, args), = asked
+    assert args == {"work": "encode_hbm_bytes", "ops_counter": "ops"}
+    assert ctx["engine_traced"]["ops"] >= 1
+    pool = ctx["config"]["pool"]
+    assert ctx["op_bytes"] == pool["k"] * pool["stripe_unit"]
+    assert last["device"]["busy_s"] == 0.0
+    assert "encode_roofline" not in metrics
+    busy = dict(ctx, trace=dict(ctx["trace"],
+                                busy_s=last["device"]["window_s"]))
+    assert 0 < real_reader("roofline_pct")(busy, **args) <= 100
 
 
 # -- upper readings: a seam fed a wrong answer is not correct -----------

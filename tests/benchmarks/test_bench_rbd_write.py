@@ -33,26 +33,34 @@ WRITE_LISTS = (
 NEW = ("rmw_read_ms.overwrite", "overwrite_encode_share")
 
 
-def test_the_cell_is_registered_as_the_issue_asks():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def check_registered(root: str) -> None:
+    """The cell as ``BENCHMARK.json`` at ``root`` registers it, read by
+    name: whatever a later PR appends, and wherever, leaves it so."""
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         bm = json.load(f)
-    entry = next(w for w in bm["workloads"] if w["name"] == CELL)
+    entries = [w for w in bm["workloads"] if w["name"] == CELL]
+    assert len(entries) == 1, CELL
+    entry = entries[0]
     assert entry == dict(entry, config=CONFIG,
                          traffic="rbd_randwrite_4k", chips=1)
-    assert bm["workloads"][-1] is entry     # appended
-    lists = {m["name"]: m.get("workloads")
+    lists = {m["name"]: m.get("workloads", [])
              for m in bm["end_to_end"] + bm["per_layer"]}
     for name in WRITE_LISTS:
-        assert lists[name][-1] == CELL, name
+        assert CELL in lists.get(name, []), name
+    # the overwrite route's own metrics: on the cell, and on no cell
+    # whose traffic is not a sub-object overwrite
     for name in NEW:
-        assert lists[name] == [CELL], name
-    assert [m["name"] for m in bm["per_layer"][-2:]] == list(NEW)
+        assert CELL in lists.get(name, []), name
+        for other in lists[name]:
+            mix = spec.Cell(other, root).traffic
+            assert mix.get("extent_bytes", mix["object_bytes"]) < \
+                mix["object_bytes"], (name, other)
     # the cross-PG share stays a write_full cells' metric
     assert CELL not in lists["encode_cross_pg_share"]
-    cell = spec.Cell(CELL, ROOT)
+    cell = spec.Cell(CELL, root)
     assert {m["name"] for m in cell.end_to_end} == {
         "write_MBps", "write_p95_ms", "setup_s"}
-    assert {m["name"] for m in cell.per_layer} == set(
+    assert {m["name"] for m in cell.per_layer} >= set(
         WRITE_LISTS[2:] + NEW)
     assert set(cell.window.KEYS) == {"extent_bytes"}
     assert cell.traffic["extent_bytes"] == 4 * KIB
@@ -60,10 +68,69 @@ def test_the_cell_is_registered_as_the_issue_asks():
     assert dep["image"]["size_bytes"] == 1 << 30
     assert 1 << dep["image"]["order"] == cell.traffic["object_bytes"]
     assert spec.ec_profile(cell.config["pool"]) == spec.ec_profile(
-        spec.Cell("k8m3_write_4m", ROOT).config["pool"])
+        spec.Cell("k8m3_write_4m", root).config["pool"])
     for seam in ("expected", "keeps_hinfo", "op_bytes"):
         assert getattr(cell.window, seam) is not getattr(WindowBase,
                                                          seam), seam
+
+
+def test_the_cell_is_registered_as_the_issue_asks(registry_root):
+    check_registered(registry_root)
+
+
+def _removed(bm: dict) -> None:
+    bm["workloads"] = [w for w in bm["workloads"] if w["name"] != CELL]
+    for met in bm["end_to_end"] + bm["per_layer"]:
+        if CELL in met.get("workloads", []):
+            met["workloads"].remove(CELL)
+
+
+def _renamed(bm: dict) -> None:
+    for ent in bm["workloads"]:
+        if ent["name"] == CELL:
+            ent["name"] = CELL + "_v2"
+    for met in bm["end_to_end"] + bm["per_layer"]:
+        cells = met.get("workloads", [])
+        if CELL in cells:
+            cells[cells.index(CELL)] = CELL + "_v2"
+
+
+def _moved(**to):
+    def move(bm: dict) -> None:
+        next(w for w in bm["workloads"] if w["name"] == CELL).update(to)
+    return move
+
+
+def _dropped(name: str):
+    def drop(bm: dict) -> None:
+        met = next(m for m in bm["end_to_end"] + bm["per_layer"]
+                   if m["name"] == name)
+        met["workloads"].remove(CELL)
+    return drop
+
+
+UNREGISTERED = {
+    "removed": _removed, "renamed": _renamed,
+    "other_config": _moved(config="rs_k8m3_12osd"),
+    "other_traffic": _moved(traffic="write_4m"),
+    **{"dropped_" + name: _dropped(name) for name in WRITE_LISTS + NEW}}
+
+
+@pytest.mark.parametrize("how", sorted(UNREGISTERED))
+def test_the_registration_check_fails_where_the_cell_is_not_registered(
+        tmp_path, how):
+    """The upper reading of ``check_registered``: on a copy whose cell
+    was removed, renamed, moved to another configuration or traffic,
+    or dropped from one of its metrics' lists, it fails."""
+    root = bench_tiny.copy_root(str(tmp_path))
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bm = json.load(f)
+    UNREGISTERED[how](bm)
+    with open(path, "w") as f:
+        json.dump(bm, f, indent=1)
+    with pytest.raises((AssertionError, spec.SpecError)):
+        check_registered(root)
 
 
 @pytest.fixture(scope="module")

@@ -178,6 +178,52 @@ def test_traffic_files_are_valid_and_refuse_nonsense(scratch_bench):
         spec.traffic("absent", str(scratch_bench))
 
 
+class _KillingSetup:
+    """What a closed loop's ``prepare`` asks of set-up, recording the
+    kills it is asked for."""
+
+    victims: list = []
+    degraded_objects: dict = {}
+
+    def __init__(self) -> None:
+        self.killed: list = []
+
+    def kill_osds(self, n, victims=None) -> None:
+        self.killed.append((n, victims))
+
+    def settle(self) -> None:
+        pass
+
+    def warm_degraded_reads(self) -> None:
+        pass
+
+    def compiles(self) -> int:
+        return 0
+
+    def compile_seconds(self) -> float:
+        return 0.0
+
+
+def test_a_closed_loop_mix_may_name_the_osds_it_kills(scratch_bench):
+    """``victims``: the same OSDs down in every run, whatever the seed;
+    a mix without it kills ``osds_down`` OSDs drawn from the seed."""
+    fixed = spec.traffic("degraded_read_4m_fixed_down")
+    drawn = spec.traffic("degraded_read_4m")
+    assert fixed["victims"] == [6, 9] and "victims" not in drawn
+    # the failure is the one difference between the two mixes
+    assert {k: v for k, v in fixed.items() if k not in ("what", "victims")
+            } == {k: v for k, v in drawn.items() if k != "what"}
+    assert not _refused(scratch_bench, fixed)
+    for bad in ([6], [6, 6], [6, "9"], [6, -1], 6, [True, 9], [6, 9, 1]):
+        assert _refused(scratch_bench, dict(fixed, victims=bad)), bad
+    kind = spec.window_kind("read")
+    for mix, want in ((fixed, [6, 9]), (drawn, None)):
+        for seed in (1, 3000000001):
+            setup = _KillingSetup()
+            kind(setup, mix, seed).prepare(lambda **kw: None)
+            assert setup.killed == [(2, want)], (seed, setup.killed)
+
+
 def test_a_window_kind_declares_and_checks_the_keys_of_its_own(
         scratch_bench):
     """``op`` names the kind; the kind, not one fixed table, says which

@@ -17,8 +17,7 @@ in ack order (``expected``); a range overwrite drops the shards'
 whole-shard crc, so an object that was overwritten keeps no ``hinfo``
 and one that was not keeps it (``keeps_hinfo``); an op encodes the one
 stripe its extent lies in (``op_bytes``). It asks nothing of the
-engine's counters: on this tree an overwrite is encoded outside the
-engine.
+engine's counters.
 """
 
 from __future__ import annotations
