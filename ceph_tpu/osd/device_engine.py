@@ -591,6 +591,10 @@ class DeviceEncodeEngine:
                       # host-routed, no crc pass); also counted in
                       # ops / flushes
                       "overwrite_ops": 0, "overwrite_flushes": 0,
+                      # decode ops an overwrite's ranged read staged
+                      # (its stripe lacked a data chunk); also
+                      # counted in decode_ops
+                      "rmw_decodes": 0,
                       # the ship thread's ships and the flush groups
                       # they carried (overwrite groups queued ready
                       # behind one another ship as one)
@@ -868,7 +872,8 @@ class DeviceEncodeEngine:
                      shards: dict[int, np.ndarray], want: list[int],
                      cont: Callable[[dict | None, Exception | None],
                                     None], span=NOOP,
-                     clock=_stage_clock.NOOP) -> None:
+                     clock=_stage_clock.NOOP,
+                     overwrite: bool = False) -> None:
         """Queue a reconstruct of ``want`` chunk streams from the
         surviving ``shards``; ``cont(decoded, err)`` runs INLINE on
         the engine thread (must be cheap and lock-free — the typical
@@ -877,7 +882,9 @@ class DeviceEncodeEngine:
         violates this: its continuation reassembles the object and
         sends the reply here, one op of a flush after the other.
         Dispatching it on ``key`` instead was measured and earned no
-        rate (PERF.md section 6, PR 33)."""
+        rate (PERF.md section 6).
+        ``overwrite``: a range overwrite's ranged read staged it
+        (counted in ``rmw_decodes``)."""
         import time as _time
         _telemetry().note_hbm(staged_delta=_shards_nbytes(shards))
         self._note_staged_flow(cont, _shards_nbytes(shards))
@@ -886,18 +893,25 @@ class DeviceEncodeEngine:
         sig = (program_key(codec, sinfo), tuple(sorted(shards)),
                tuple(sorted(want)), pslot)
         self._q.put(("dec", key, codec, sinfo, shards, want, cont,
-                     span, clock, _time.monotonic(), sig))
+                     span, clock, _time.monotonic(), sig, overwrite))
 
     def decode_sync(self, key, codec, sinfo: ec_util.StripeInfo,
                     shards: dict[int, np.ndarray], want: list[int],
                     timeout: float = 60.0,
                     span=NOOP,
-                    clock=_stage_clock.NOOP) -> dict[int, np.ndarray] | None:
+                    clock=_stage_clock.NOOP,
+                    overwrite: bool = False
+                    ) -> dict[int, np.ndarray] | None:
         """Blocking decode through the batched engine; returns the
         decoded {chunk: bytes} map or None on device fault/timeout
-        (the caller falls back to its host twin). Safe to call from
-        op-worker threads: the continuation runs on the engine
-        thread, not the caller's wq shard."""
+        (the caller falls back to its host twin). ``overwrite``: a
+        range overwrite's ranged read asks (``rmw_decodes``). An
+        op-wq worker may block here on one condition: the decode's
+        continuation is never dispatched to a wq shard. It runs
+        inline in the engine thread's decode flush, and that thread
+        never waits for a wq worker (a barrier or a retired flush's
+        continuations are queued, not run), so the caller's own
+        blocked shard cannot hold its decode back."""
         ev = threading.Event()
         box: list = [None, None]
 
@@ -906,7 +920,7 @@ class DeviceEncodeEngine:
             ev.set()
 
         self.stage_decode(key, codec, sinfo, shards, want, cont,
-                          span=span, clock=clock)
+                          span=span, clock=clock, overwrite=overwrite)
         if not ev.wait(timeout):
             log(0, f"device decode timed out after {timeout}s; "
                 "host fallback")
@@ -1047,7 +1061,9 @@ class DeviceEncodeEngine:
                         pending, dec_pending, nbytes = {}, {}, 0
                 elif item[0] == "dec":
                     (_, key, codec, sinfo, shards, want, cont, span,
-                     clock, ts, sig) = item
+                     clock, ts, sig, overwrite) = item
+                    if overwrite:
+                        self.stats["rmw_decodes"] += 1
                     _dsp.telemetry().note_handoff(
                         "engine_stage", _time.monotonic() - ts)
                     _, _, _, items = dec_pending.setdefault(

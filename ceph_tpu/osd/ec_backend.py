@@ -200,24 +200,30 @@ class ECBackend(PGBackend):
         return data + b"\x00" * (sw - rem if rem else sw)
 
     def _decode(self, pg: PG, shards: dict[int, np.ndarray],
-                want: list[int]) -> dict[int, np.ndarray]:
+                want: list[int], overwrite: bool = False
+                ) -> dict[int, np.ndarray]:
         """Reconstruct ``want`` chunk streams — on the DEVICE when the
         pool runs a device backend (the round-3 seam: degraded reads
         and recovery decode batch through the engine grouped by
         erasure signature, objects_read_and_reconstruct /
         continue_recovery_op roles, src/osd/ECBackend.cc:2301,537),
-        host twin otherwise or on device fault."""
+        host twin otherwise or on device fault. ``overwrite``: a range
+        overwrite's ranged read asks (``_rmw_window``)."""
         missing = [i for i in want if i not in shards]
         if missing and self.device is not None and \
                 self.device_codec is not None and \
                 ec_util.device_decodable(self.device_codec):
             # the op's dataflow trace continues into the engine's
             # signature-batched decode flush (NOOP when tracing off),
-            # and so does its stage timeline
+            # and so does its stage timeline; an overwrite's rebuild
+            # is one stage of its own (rmw_decode), and its op's
+            # engine stages are those of its encode
             out = self.device.decode_sync(
                 pg.pgid, self.device_codec, self.sinfo, shards, want,
                 span=tracing.current().child("engine_decode"),
-                clock=stage_clock.current())
+                clock=stage_clock.NOOP if overwrite
+                else stage_clock.current(),
+                overwrite=overwrite)
             if out is not None:
                 return out
             _telemetry().note_decode_fallback()
@@ -802,7 +808,8 @@ class ECBackend(PGBackend):
         extent cache at ``version``. The ranged read of the stripes'
         k data chunks is the profiler state ``rmw_read`` (role
         ``osd_wq``), and the op's stage clock marks ``rmw_read`` when
-        it returns."""
+        it returns; where a data chunk is lost, its rebuild is the
+        state and the stage ``rmw_decode``, marked only then."""
         sw, cs = self.sinfo.stripe_width, self.sinfo.chunk_size
         end = offset + len(data)
         if old_size is None:
@@ -859,7 +866,17 @@ class ECBackend(PGBackend):
                 base_ver = int.from_bytes(rattrs.get("v", b""),
                                           "little")
                 if not all(i in chunks for i in want):
-                    chunks = self._decode(pg, chunks, want)
+                    # a data chunk of the stripes is lost (an
+                    # undersized PG): rebuilt on the engine's decode
+                    # flush while this worker waits
+                    mark = _prof.push_stage("pg_process",
+                                            span="rmw_decode")
+                    try:
+                        chunks = self._decode(pg, chunks, want,
+                                              overwrite=True)
+                    finally:
+                        _prof.pop_stage(mark)
+                    stage_clock.current().mark("rmw_decode")
                 old_win = self._chunks_to_logical(
                     {i: chunks[i] for i in want}, read_to - a)
                 window[:len(old_win)] = old_win

@@ -58,8 +58,9 @@ EC_WRITE_STAGES = (
 #: reconstruct engine_stage_wait and device_finalize, then
 #: commit_wait (reassembly + reply build) and commit_reply; a range
 #: overwrite marks rmw_read between pg_process and engine_stage_wait
-#: when it reads the stripes it touches (``ec_backend._rmw_window``)
-READ_STAGES = ("shard_read_wait", "rmw_read")
+#: when it reads the stripes it touches (``ec_backend._rmw_window``),
+#: and rmw_decode after it when that read lacked a data chunk
+READ_STAGES = ("shard_read_wait", "rmw_read", "rmw_decode")
 
 #: a shard sub-write's child timeline (primary -> shard OSD -> commit)
 SUBOP_STAGES = ("subop_send", "subop_wire", "subop_dispatch_wait",
@@ -88,6 +89,9 @@ GLOSSARY = {
     "rmw_read": "range overwrite: the ranged read of the k data "
                 "chunks of the stripes it touches (behind an engine "
                 "barrier, the wait for the barrier too)",
+    "rmw_decode": "range overwrite whose stripes lack a data chunk: "
+                  "its rebuild on the engine's decode flush, the "
+                  "op-wq worker waiting",
     "engine_stage_wait": "staged -> batch flush launch (batching)",
     "device_window_wait": "launch -> harvest begin (pipeline window)",
     "device_finalize": "blocking device compute + parity download",
